@@ -29,5 +29,7 @@ def run(refresh: bool = False):
 
 if __name__ == "__main__":
     from benchmarks.common import emit_csv
+    from repro.common.compilation import enable_compile_cache
 
+    enable_compile_cache()
     emit_csv(run())

@@ -76,6 +76,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import cached
+from repro.common.compilation import enable_compile_cache
 from repro.core.baselines import (
     EmpiricalGreedy,
     FixedActionPolicy,
@@ -606,12 +607,18 @@ def bench_offline_pretrain(henv: RouterBenchSim, denv: DeviceReplayEnv,
     return {"offline_pretrain": out}
 
 
-def _bench_subprocess(args, n_seeds: int) -> Dict:
-    """Run a bench section in a subprocess with the host's CPU cores
-    exposed as XLA host-platform devices (sweeps shard their lane axis
-    across them, DESIGN.md §8.4 — same mechanism as the 512-device
-    dry-run). Isolating the flag in a child process keeps this process,
-    and every other benchmark section, on the default single device."""
+def _bench_sharded(fn, fn_args, flag_args, n_seeds: int) -> Dict:
+    """Run a sweep section with its lane axis sharded over devices.
+
+    On an accelerator this process already sees the real local devices
+    (sweeps shard their lanes over them, DESIGN.md §8.4), and it holds
+    the chip: a child process could not get it. So the section runs
+    here. On CPU it runs in a child with the host's cores exposed as
+    XLA host-platform devices (same mechanism as the 512-device dry-run);
+    isolating the flag in the child keeps this process, and every other
+    section, on the default single device."""
+    if jax.default_backend() != "cpu":
+        return fn(*fn_args)
     env = dict(os.environ)
     flags = env.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -625,7 +632,7 @@ def _bench_subprocess(args, n_seeds: int) -> Dict:
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)
     out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_protocol", *args],
+        [sys.executable, "-m", "benchmarks.bench_protocol", *flag_args],
         cwd=REPO_ROOT, env=env, capture_output=True, text=True)
     if out.returncode != 0:
         raise RuntimeError("bench subprocess failed:\n"
@@ -633,9 +640,11 @@ def _bench_subprocess(args, n_seeds: int) -> Dict:
     return json.loads(out.stdout)
 
 
-def bench_neuralucb_subprocess(n_samples: int, n_slices: int, n_seeds: int,
-                               train_steps: int, batch_size: int) -> Dict:
-    return _bench_subprocess(
+def bench_neuralucb_sharded(n_samples: int, n_slices: int, n_seeds: int,
+                            train_steps: int, batch_size: int) -> Dict:
+    return _bench_sharded(
+        bench_neuralucb_runs,
+        (n_samples, n_slices, n_seeds, train_steps, batch_size),
         ["--nucb-only",
          "--nucb-samples", str(n_samples), "--nucb-slices", str(n_slices),
          "--nucb-seeds", str(n_seeds),
@@ -643,10 +652,11 @@ def bench_neuralucb_subprocess(n_samples: int, n_slices: int, n_seeds: int,
          "--nucb-batch", str(batch_size)], n_seeds)
 
 
-def bench_scenarios_subprocess(n_samples: int, n_slices: int,
-                               n_seeds: int, train_steps: int,
-                               batch_size: int) -> Dict:
-    return _bench_subprocess(
+def bench_scenarios_sharded(n_samples: int, n_slices: int, n_seeds: int,
+                            train_steps: int, batch_size: int) -> Dict:
+    return _bench_sharded(
+        bench_scenarios,
+        (n_samples, n_slices, n_seeds, train_steps, batch_size),
         ["--scen-only",
          "--scen-samples", str(n_samples), "--scen-slices", str(n_slices),
          "--scen-seeds", str(n_seeds),
@@ -654,10 +664,11 @@ def bench_scenarios_subprocess(n_samples: int, n_slices: int,
          "--nucb-batch", str(batch_size)], n_seeds)
 
 
-def bench_policy_zoo_subprocess(n_samples: int, n_slices: int,
-                                n_seeds: int, train_steps: int,
-                                batch_size: int) -> Dict:
-    return _bench_subprocess(
+def bench_policy_zoo_sharded(n_samples: int, n_slices: int, n_seeds: int,
+                             train_steps: int, batch_size: int) -> Dict:
+    return _bench_sharded(
+        bench_policy_zoo,
+        (n_samples, n_slices, n_seeds, train_steps, batch_size),
         ["--zoo-only",
          "--zoo-samples", str(n_samples), "--zoo-slices", str(n_slices),
          "--zoo-seeds", str(n_seeds),
@@ -753,12 +764,12 @@ def bench_protocol(n_samples: int = 36_497, n_slices: int = 20,
     dev_step_s = (time.perf_counter() - t0) / 5
     nucb.ainv, nucb.bufs = ainv, bufs
 
-    nucb_runs = bench_neuralucb_subprocess(
+    nucb_runs = bench_neuralucb_sharded(
         nucb_samples, nucb_slices, nucb_seeds, nucb_train_steps, nucb_batch)
-    scen_runs = bench_scenarios_subprocess(
+    scen_runs = bench_scenarios_sharded(
         scen_samples, scen_slices, scen_seeds, nucb_train_steps,
         nucb_batch)
-    zoo_runs = bench_policy_zoo_subprocess(
+    zoo_runs = bench_policy_zoo_sharded(
         zoo_samples, zoo_slices, zoo_seeds, nucb_train_steps, nucb_batch)
     kernel_runs = bench_nucb_kernels()
     compile_runs = bench_experiment_compile()
@@ -889,13 +900,13 @@ def main() -> None:
     ap.add_argument("--zoo-seeds", type=int, default=4)
     ap.add_argument("--nucb-only", action="store_true",
                     help="internal: run only the NeuralUCB sections and "
-                         "print their JSON (the subprocess entry point)")
+                         "print their JSON (the CPU child entry point)")
     ap.add_argument("--scen-only", action="store_true",
                     help="internal: run only the scenario sections and "
-                         "print their JSON (the subprocess entry point)")
+                         "print their JSON (the CPU child entry point)")
     ap.add_argument("--zoo-only", action="store_true",
                     help="internal: run only the policy-zoo sweep section "
-                         "and print its JSON (the subprocess entry point)")
+                         "and print its JSON (the CPU child entry point)")
     ap.add_argument("--pool-only", action="store_true",
                     help="run only the physical_pool calibration section "
                          "and print its JSON")
@@ -907,6 +918,7 @@ def main() -> None:
     ap.add_argument("--pool-steps", type=int, default=6)
     ap.add_argument("--out", default=ROOT_OUT)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.pool_only:
         out = bench_physical_pool(batch=args.pool_batch,
                                   steps=args.pool_steps,

@@ -41,6 +41,7 @@ from typing import Dict
 import jax
 
 from benchmarks.common import cached
+from repro.common.compilation import enable_compile_cache
 from repro.core.utilitynet import UtilityNetConfig
 from repro.data.routerbench import RouterBenchSim
 from repro.serving import DevicePolicyRouter, run_storm
@@ -170,6 +171,7 @@ def main() -> None:
     ap.add_argument("--n-samples", type=int, default=36_497)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     global ROOT_OUT
     if args.out:
         ROOT_OUT = args.out

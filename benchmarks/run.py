@@ -18,6 +18,7 @@ from benchmarks import (
     bench_serving,
 )
 from benchmarks.common import emit_csv
+from repro.common.compilation import enable_compile_cache
 
 SECTIONS = {
     "rewards": bench_rewards.run,        # paper Fig. 2
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--only", choices=list(SECTIONS), default=None)
     ap.add_argument("--refresh", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(SECTIONS)
     for name in names:
         print(f"# --- {name} ---", flush=True)

@@ -35,7 +35,7 @@ def main():
                         decode_batch=8, context=2048,
                         max_new=args.max_new)
     env, pool = build_pool_env(aspec, DataSpec(n_samples=2000, n_slices=4))
-    engines, info = build_arm_engines(pool, aspec)
+    engines, info = build_arm_engines(pool, aspec, max_batch=8)
 
     print(f"physical pool on {pool.hardware} "
           f"(real decode: {info['real_decode_arms']}):")

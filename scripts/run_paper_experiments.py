@@ -37,6 +37,7 @@ import json
 import sys
 from typing import Dict, List, Optional, Tuple
 
+from repro.common.compilation import enable_compile_cache
 from repro.experiments import (
     DataSpec,
     ExperimentResult,
@@ -273,6 +274,7 @@ def main(argv=None) -> int:
             print(f"{name:<18} {desc}")
         return 0
 
+    enable_compile_cache()
     if args.preset is not None:
         # --preset takes its configuration from --set alone; a legacy
         # flag next to it would be silently shadowed by the spec (all

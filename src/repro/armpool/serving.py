@@ -55,14 +55,21 @@ class DecodeArmEngine:
     """A real pool member: greedy decode through the jitted serving
     engine. ``decode_steps`` counts REAL decode-step dispatches (the
     acceptance criterion's ">= 1 arm executes real jitted decode
-    steps" evidence, surfaced in the storm metrics)."""
+    steps" evidence, surfaced in the storm metrics).
 
-    def __init__(self, name: str, cfg, *, max_seq: int = 64,
+    Every call runs at one pinned batch width, ``max_batch`` (the serve
+    stage's batch cap): rows are padded up to it, so the decode step
+    compiles once, in the warm-up, whatever batch the batcher forms —
+    not once per batch size on the request path. Rows decode
+    independently, so padding never changes a real row's tokens."""
+
+    def __init__(self, name: str, cfg, *, max_batch: int, max_seq: int = 64,
                  seed: int = 0, warm: bool = True):
         from repro.serving.engine import ServingEngine
 
         self.name = name
         self.cfg = cfg
+        self.max_batch = int(max_batch)
         self.max_seq = max_seq
         self.engine = ServingEngine(cfg, seed=seed, max_seq=max_seq)
         self.decode_steps = 0
@@ -72,24 +79,31 @@ class DecodeArmEngine:
             self.decode_steps = 0
 
     def generate(self, tokens, max_new: int = 8) -> Tuple[np.ndarray, int]:
-        import jax.numpy as jnp
-
         toks = np.asarray(tokens, np.int64)
+        B = toks.shape[0]
+        if B > self.max_batch:
+            raise ValueError(f"{self.name}: batch of {B} rows exceeds the "
+                             f"engine's pinned width {self.max_batch}")
         # clamp into the (possibly reduced) vocab and cache budget
         toks = np.clip(toks, 0, self.cfg.vocab_size - 1)
         keep = max(1, self.max_seq - max_new - 1)
         toks = toks[:, -keep:]
-        new, steps = self.engine.generate(jnp.asarray(toks, jnp.int32),
-                                          max_new=max_new)
+        padded = np.zeros((self.max_batch, toks.shape[1]), np.int32)
+        padded[:B] = toks
+        # host tokens: prefill slices them per step on the host, so no
+        # eager device slice compiles per prompt position
+        new, steps = self.engine.generate(padded, max_new=max_new)
         # prefill replays the prompt through width-1 decode steps, so
         # the real dispatch count per call is prompt + (max_new - 1)
         self.decode_steps += toks.shape[1] + steps
-        return np.asarray(new), steps
+        return np.asarray(new)[:B], steps
 
 
-def build_arm_engines(pool: CompiledArmPool, aspec
+def build_arm_engines(pool: CompiledArmPool, aspec, *, max_batch: int
                       ) -> Tuple[List, Dict[str, object]]:
     """Pool -> per-arm engine list (+ an info block for the artifact).
+    ``max_batch`` is the largest batch the serve stage hands an engine
+    (the real-decode engines pin their batch width to it).
 
     Raises on a pool/spec K disagreement (the engines MUST line up
     with the pool's arm order — the router's arm ids index this list).
@@ -103,7 +117,7 @@ def build_arm_engines(pool: CompiledArmPool, aspec
             cfg = get_config(name)
             if aspec.reduced_decode:
                 cfg = cfg.reduced()
-            engines.append(DecodeArmEngine(name, cfg,
+            engines.append(DecodeArmEngine(name, cfg, max_batch=max_batch,
                                            seed=int(pool.checksum % 997)))
             real.append(name)
         else:
@@ -115,6 +129,13 @@ def build_arm_engines(pool: CompiledArmPool, aspec
         raise ValueError("arm pool produced no engines")
     info = {"real_decode_arms": real, "roofline_clocked_arms": clocked,
             "reduced_decode": bool(aspec.reduced_decode),
+            "real_decode_widths": {
+                e.name: {"num_layers": e.cfg.num_layers,
+                         "d_model": e.cfg.d_model,
+                         "vocab_size": e.cfg.vocab_size,
+                         "ssm_state": e.cfg.ssm_state,
+                         "max_batch": e.max_batch}
+                for e in engines if e.real_decode},
             "latency_scale": float(aspec.latency_scale)}
     return engines, info
 

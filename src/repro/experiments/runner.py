@@ -115,7 +115,8 @@ def _run_serving_cell(plan: ExperimentPlan, *,
         # semi-real serve stage: small arms run REAL jitted decode
         # steps, large arms sleep their roofline step time
         from repro.armpool import build_arm_engines, engine_decode_steps
-        engines, engine_info = build_arm_engines(plan.pool, spec.armpool)
+        engines, engine_info = build_arm_engines(
+            plan.pool, spec.armpool, max_batch=sv.serve_batch)
         max_new = spec.armpool.max_new
     metrics = run_storm(
         plan.env, router, requests=sv.requests, waves=sv.waves,

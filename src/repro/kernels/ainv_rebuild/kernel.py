@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 _OUTER = (((1,), (1,)), ((), ()))   # (n,1) x (n,1) -> outer product (n,n)
 _GRAM = (((0,), (0,)), ((), ()))    # (m,n) x (m,k) -> X^T Y
 
@@ -119,10 +117,10 @@ def _rebuild_kernel(g_ref, w_ref, lam_ref, out_ref, acc_ref, *,
     def _():
         rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-        acc_ref[...] = jnp.where(rows == cols, lam_ref[0], 0.0)
+        acc_ref[...] = jnp.where(rows == cols, lam_ref[0, 0], 0.0)
 
-    sw = jnp.sqrt(jnp.maximum(w_ref[...], 0.0))              # (Br,)
-    gw = g_ref[...].astype(jnp.float32) * sw[:, None]
+    sw = jnp.sqrt(jnp.maximum(w_ref[...], 0.0))              # (Br, 1)
+    gw = g_ref[...].astype(jnp.float32) * sw
     acc_ref[...] += jax.lax.dot_general(
         gw, gw, _GRAM, preferred_element_type=jnp.float32)
 
@@ -136,8 +134,13 @@ def _rebuild_kernel(g_ref, w_ref, lam_ref, out_ref, acc_ref, *,
 def ainv_rebuild_padded(g, w, lam, *, block_r: int = 1024,
                         block_s: int = 128, interpret: bool = False):
     """g: (N, Fp) with N % block_r == 0 and Fp % 128 == 0 (zero-padded
-    feature columns); w: (N,) row weights (padded rows carry 0);
-    lam: (1,) f32. Returns A_pad^-1 (Fp, Fp) f32."""
+    feature columns); w: (N, 1) row weights (padded rows carry 0);
+    lam: (1, 1) f32 SMEM. Returns A_pad^-1 (Fp, Fp) f32.
+
+    ``w`` is a column and ``lam`` a row so that a ``vmap``-ed call (one
+    lane per sweep point) still tiles: the batched block's last two
+    dims must span the array's or be (8, 128)-aligned, which a 1-D
+    block under a squeezed lane dim never is."""
     N, Fp = g.shape
     nr = N // block_r
     kern = functools.partial(_rebuild_kernel, block_s=block_s)
@@ -146,13 +149,13 @@ def ainv_rebuild_padded(g, w, lam, *, block_r: int = 1024,
         grid=(nr,),
         in_specs=[
             pl.BlockSpec((block_r, Fp), lambda i: (i, 0)),
-            pl.BlockSpec((block_r,), lambda i: (i,)),
+            pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((Fp, Fp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((Fp, Fp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((Fp, Fp), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(g, w, lam)

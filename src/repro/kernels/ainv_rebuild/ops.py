@@ -29,7 +29,7 @@ def ainv_rebuild(gs, ridge_lambda0=1.0, weights=None, *,
     if weights is None:
         weights = jnp.ones((gs.shape[0],), jnp.float32)
     return _ainv_rebuild_pallas(
-        gs, weights, jnp.asarray(ridge_lambda0, jnp.float32).reshape(1),
+        gs, weights, jnp.asarray(ridge_lambda0, jnp.float32).reshape(1, 1),
         block_r=block_r, interpret=backend == INTERPRET)
 
 
@@ -48,6 +48,6 @@ def _ainv_rebuild_pallas(gs, weights, lam, *, block_r: int,
     if pad_n:
         gs = jnp.pad(gs, ((0, pad_n), (0, 0)))
         weights = jnp.pad(weights, (0, pad_n))   # w=0: inert rows
-    out = ainv_rebuild_padded(gs, weights.astype(jnp.float32), lam,
+    out = ainv_rebuild_padded(gs, weights.astype(jnp.float32)[:, None], lam,
                               block_r=br, interpret=interpret)
     return out[:F, :F]

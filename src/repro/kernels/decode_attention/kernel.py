@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -86,7 +84,7 @@ def decode_attention_padded(q, k, v, pos, *, window: int = 0,
             jax.ShapeDtypeStruct((B, KV, ns, G), jnp.float32),
             jax.ShapeDtypeStruct((B, KV, ns, G, D), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(pos, q, k, v)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
                 ydiag_ref, state_ref, expacum_ref, decay_ref):
@@ -106,7 +104,7 @@ def ssd_chunk_kernel(x, dt, A, Bm, Cm, *, interpret: bool = True):
             jax.ShapeDtypeStruct((B, nh, NC, cs, HB), jnp.float32),
             jax.ShapeDtypeStruct((B, nh, NC, HB), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xg, dtg, Ag, Bm, Cm)

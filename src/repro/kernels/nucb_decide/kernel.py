@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 _NEG = float("-inf")
 
 
@@ -49,9 +47,9 @@ def _decide_kernel(ctx_ref, w1ctx_ref, act1_ref, w2_ref, b2_ref, wu_ref,
                    num_actions: int, d_last: int, compute_dtype):
     f32 = jnp.float32
     cd = compute_dtype
-    beta = scal_ref[0]
-    tau_g = scal_ref[1]
-    bu = scal_ref[2]
+    beta = scal_ref[0, 0]
+    tau_g = scal_ref[0, 1]
+    bu = scal_ref[0, 2]
 
     # one context GEMM, shared by all K actions
     base = jax.lax.dot(ctx_ref[...].astype(cd), w1ctx_ref[...].astype(cd),
@@ -64,12 +62,14 @@ def _decide_kernel(ctx_ref, w1ctx_ref, act1_ref, w2_ref, b2_ref, wu_ref,
     a12 = ainv[:d_last, d_last]                               # (D,)
     a21 = ainv[d_last, :d_last]                               # (D,)
     a22 = ainv[d_last, d_last]
-    use_ucb = gate_ref[...] >= tau_g                          # (Bb,)
+    # per-row values are (Bb, 1) columns throughout: Mosaic cannot lay out
+    # a 1-D (Bb,) vector against a (Bb, D) tile, nor reshape an i1 vector
+    use_ucb = gate_ref[...] >= tau_g                          # (Bb, 1)
 
     nb = base.shape[0]
-    best_sel = jnp.full((nb,), _NEG, f32)
-    best_mu = jnp.full((nb,), _NEG, f32)
-    best_a = jnp.zeros((nb,), jnp.int32)
+    best_sel = jnp.full((nb, 1), _NEG, f32)
+    best_mu = jnp.full((nb, 1), _NEG, f32)
+    best_a = jnp.zeros((nb, 1), jnp.int32)
     h_best = jnp.zeros((nb, d_last), f32)
     inv_s2 = f32(1.0) / jnp.sqrt(f32(2.0))
 
@@ -78,26 +78,27 @@ def _decide_kernel(ctx_ref, w1ctx_ref, act1_ref, w2_ref, b2_ref, wu_ref,
         h = jax.nn.gelu(
             jax.lax.dot(h1.astype(cd), w2,
                         preferred_element_type=f32) + b2)     # (Bb, D)
-        mu_k = jnp.sum(h * wu, axis=1) + bu                   # (Bb,)
+        mu_k = jnp.sum(h * wu, axis=1, keepdims=True) + bu    # (Bb, 1)
         # augment (core.neuralucb.augment): L2-normalize h, append 1,
         # scale by 1/sqrt(2); the quadratic form expands blockwise so g
         # is never materialized per action:
         #   2 quad = hn^T A11 hn + hn . (a12 + a21) + a22
         hn = h / jnp.maximum(
-            jnp.sqrt(jnp.sum(h * h, axis=1)), 1e-6)[:, None]
+            jnp.sqrt(jnp.sum(h * h, axis=1, keepdims=True)), 1e-6)
         v = jax.lax.dot(hn, a11, preferred_element_type=f32)
-        quad = 0.5 * (jnp.sum(v * hn, axis=1)
-                      + jnp.sum(hn * (a12 + a21)[None, :], axis=1)
+        quad = 0.5 * (jnp.sum(v * hn, axis=1, keepdims=True)
+                      + jnp.sum(hn * (a12 + a21)[None, :], axis=1,
+                                keepdims=True)
                       + a22)
         score = mu_k + beta * jnp.sqrt(jnp.maximum(quad, 0.0))
-        ok = avail_ref[k] > 0.0
+        ok = avail_ref[0, k] > 0.0
         score_m = jnp.where(ok, score, _NEG)
         mu_m = jnp.where(ok, mu_k, _NEG)
         sel = jnp.where(use_ucb, score_m, mu_m)
         upd = sel > best_sel                 # strict: first max wins,
         best_sel = jnp.where(upd, sel, best_sel)  # matching jnp.argmax
         best_a = jnp.where(upd, k, best_a)
-        h_best = jnp.where(upd[:, None], hn, h_best)
+        h_best = jnp.where(upd, hn, h_best)
         best_mu = jnp.maximum(best_mu, mu_m)
 
     a_ref[...] = best_a
@@ -117,9 +118,14 @@ def nucb_decide_padded(ctx, w1ctx, act1, w2, b2, wu, ainv, gate_p, avail,
                        compute_dtype=jnp.float32):
     """Padded entry: ctx (B, Cp) with B % block_b == 0, Cp % 128 == 0;
     w1ctx (Cp, H); act1 (Kp, H); w2 (H, D); b2, wu (1, D); ainv (Fp, Fp)
-    with Fp % 128 == 0 and d_last == D % 128 == 0; gate_p (B,);
-    avail (Kp,) f32 SMEM; scal (3,) f32 SMEM = [beta, tau_g, bu].
-    Returns (a (B,) i32, g (B, Fp) f32, mu_safe (B,) f32)."""
+    with Fp % 128 == 0 and d_last == D % 128 == 0; gate_p (B, 1);
+    avail (1, Kp) f32 SMEM; scal (1, 3) f32 SMEM = [[beta, tau_g, bu]].
+    Returns (a (B, 1) i32, g (B, Fp) f32, mu_safe (B, 1) f32).
+
+    The SMEM operands are rows, not 1-D vectors: under ``vmap`` (one
+    lane per sweep point) each gains a squeezed leading block dim, and
+    Mosaic accepts that only when the block's last two dims span the
+    array's — (1, n) of (L, 1, n) does, (n,) of (L, n) does not."""
     B, Cp = ctx.shape
     H = w1ctx.shape[1]
     D = w2.shape[1]
@@ -139,21 +145,21 @@ def nucb_decide_padded(ctx, w1ctx, act1, w2, b2, wu, ainv, gate_p, avail,
             pl.BlockSpec((1, D), lambda i: (0, 0)),
             pl.BlockSpec((1, D), lambda i: (0, 0)),
             pl.BlockSpec((Fp, Fp), lambda i: (0, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_b, Fp), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32),
             jax.ShapeDtypeStruct((B, Fp), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(ctx, w1ctx, act1, w2, b2, wu, ainv, gate_p, avail, scal)

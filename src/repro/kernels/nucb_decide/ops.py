@@ -105,17 +105,17 @@ def _nucb_decide_pallas(ctx, w1ctx, act1, w2, b2, wu, bu, ainv, gate_p,
 
     # padded action rows are never read (the kernel's loop is static
     # over the true K); zeros keep them inert regardless
-    avail_full = jnp.zeros((K + pad_k,), jnp.float32)
-    avail_full = avail_full.at[:K].set(
+    avail_full = jnp.zeros((1, K + pad_k), jnp.float32)
+    avail_full = avail_full.at[0, :K].set(
         1.0 if avail is None else avail)
     scal = jnp.stack([beta.astype(jnp.float32),
                       tau_g.astype(jnp.float32),
-                      bu.astype(jnp.float32)])
+                      bu.astype(jnp.float32)])[None, :]
     a, g, mu_safe = nucb_decide_padded(
         ctx, w1ctx, act1, w2.astype(jnp.float32),
         b2.reshape(1, -1).astype(jnp.float32),
         wu.reshape(1, -1).astype(jnp.float32),
-        ainv.astype(jnp.float32), gate_p.astype(jnp.float32),
+        ainv.astype(jnp.float32), gate_p.astype(jnp.float32)[:, None],
         avail_full, scal, num_actions=K, d_last=D, block_b=bb,
         interpret=interpret, compute_dtype=compute_dtype)
-    return a[:B], g[:B], mu_safe[:B]
+    return a[:B, 0], g[:B], mu_safe[:B, 0]

@@ -41,7 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ainv_rebuild.kernel import _GRAM, _spd_inverse
-from repro.kernels.compat import CompilerParams
 
 _INNER = (((1,), (1,)), ((), ()))   # (k,n) x (m,n) -> X Y^T
 
@@ -96,6 +95,7 @@ def nucb_update_padded(gs, ainv, *, block_k: int = 128,
         out_specs=pl.BlockSpec((fp, fp), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((fp, fp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((fp, fp), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(gs, ainv)

@@ -22,17 +22,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ucb_kernel(g_ref, ainv_ref, mu_ref, beta_ref, out_ref):
     g = g_ref[...].astype(jnp.float32)        # (Br, F)
     ainv = ainv_ref[...].astype(jnp.float32)  # (F, F)
-    mu = mu_ref[...].astype(jnp.float32)      # (Br,)
-    beta = beta_ref[0]
+    mu = mu_ref[...].astype(jnp.float32)      # (Br, 1)
+    beta = beta_ref[0, 0]
 
     h = jax.lax.dot(g, ainv, preferred_element_type=jnp.float32)  # (Br, F)
-    quad = jnp.sum(h * g, axis=1)                                  # (Br,)
+    quad = jnp.sum(h * g, axis=1, keepdims=True)                   # (Br, 1)
     bonus = jnp.sqrt(jnp.maximum(quad, 0.0))
     out_ref[...] = mu + beta * bonus
 
@@ -41,7 +39,11 @@ def _ucb_kernel(g_ref, ainv_ref, mu_ref, beta_ref, out_ref):
 def ucb_score_padded(g, ainv, mu, beta, *, block_r: int = 512,
                      interpret: bool = True):
     """g: (R, F) with R % block_r == 0, F % 128 == 0; ainv: (F, F);
-    mu: (R,); beta: (1,) f32. Returns scores (R,) f32."""
+    mu: (R, 1); beta: (1, 1) f32 SMEM. Returns scores (R, 1) f32.
+
+    Per-row values are (R, 1) columns: a 1-D block's tiling need not
+    match the one XLA picks for the array, and under ``vmap`` a 1-D
+    block cannot tile at all (see ``nucb_decide_padded``)."""
     R, F = g.shape
     nr = R // block_r
     return pl.pallas_call(
@@ -50,12 +52,12 @@ def ucb_score_padded(g, ainv, mu, beta, *, block_r: int = 512,
         in_specs=[
             pl.BlockSpec((block_r, F), lambda i: (i, 0)),
             pl.BlockSpec((F, F), lambda i: (0, 0)),
-            pl.BlockSpec((block_r,), lambda i: (i,)),
+            pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((block_r,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((R,), jnp.float32),
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(g, ainv, mu, beta)

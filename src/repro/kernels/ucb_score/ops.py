@@ -55,7 +55,7 @@ def _ucb_score_pallas(g, ainv, mu, beta, *, block_r: int,
         g2 = jnp.pad(g2, ((0, pad_r), (0, 0)))
         mu2 = jnp.pad(mu2, (0, pad_r))
 
-    beta_arr = jnp.asarray(beta, jnp.float32).reshape(1)
-    out = ucb_score_padded(g2, ainv, mu2, beta_arr, block_r=br,
+    beta_arr = jnp.asarray(beta, jnp.float32).reshape(1, 1)
+    out = ucb_score_padded(g2, ainv, mu2[:, None], beta_arr, block_r=br,
                            interpret=interpret)
-    return out[:R].reshape(lead)
+    return out[:R, 0].reshape(lead)

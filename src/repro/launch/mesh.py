@@ -13,19 +13,27 @@ import math
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # jax.make_mesh defaults to Explicit axes, which
+    # with_sharding_constraint (distributed.api.constrain) refuses; the
+    # sharding rules here annotate activations, so the axes stay Auto
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over the actually-available local devices (tests/examples)."""
     n = len(jax.devices())
     mp = min(model_parallel, n)
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_sweep_mesh(n_grid: int = 1, n_seeds: int = 1,

@@ -25,7 +25,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.common.config import ModelConfig
@@ -152,10 +151,10 @@ def moe_ffn(params: dict, cfg: ModelConfig, x: jax.Array
         (bspec,) if bspec else ())
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(pspec, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     def run(p, xb):
         Bl, Sl, _ = xb.shape
         r = jax.lax.axis_index("model")

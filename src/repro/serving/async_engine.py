@@ -141,6 +141,9 @@ class AsyncRouterEngine:
         self._next_gid = 0
         self.counters = {k: 0 for k in COUNTERS}
         self.decide_wall_s: List[float] = []
+        # the latest swallowed decide exception, so a caller that treats
+        # any decide error as fatal can say what it was
+        self.last_decide_error: Optional[str] = None
         self.log = deque(maxlen=log_capacity)
         self._serving_v2 = bool(getattr(router, "serving_v2", False))
 
@@ -271,9 +274,10 @@ class AsyncRouterEngine:
                 decision = self.router.decide(x_emb, x_feat, domain)
             decided = np.asarray(decision["action"], np.int32).copy()
             self.decide_wall_s.append(time.perf_counter() - t0)
-        except Exception:
+        except Exception as e:
             # degrade, don't die: cheapest healthy arm, no router update
             self.counters["decide_errors"] += 1
+            self.last_decide_error = f"{type(e).__name__}: {e}"[:2000]
             decision = None
             decided = np.full(n, self._safe_arm(), np.int32)
 

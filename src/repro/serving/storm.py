@@ -15,6 +15,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.common.compilation import CompileCount
 from repro.serving.async_engine import AsyncRouterEngine
 from repro.serving.batcher import Request
 from repro.serving.faults import ScriptedFaults
@@ -78,28 +79,29 @@ def run_storm(env, router, *, requests: int, waves: int,
     # a caller actually sees (the overlap bench compares this)
     stalls: list = []
     t0 = time.perf_counter()
-    for w in range(waves):
-        faults.apply_wave(engine, w)
-        ids = rng.integers(0, n, size=int(sizes[w]))
-        reqs = [Request(tokens=_TOKENS, sample_idx=int(i)) for i in ids]
-        shed0 = (engine.counters["shed_queue_full"]
-                 + engine.counters["shed_no_arm"])
-        engine.submit(reqs)
-        recs = engine.pump()
-        recs += engine.drain()
-        for r in recs:
-            if r["status"] == "ok":
-                n_ok += 1
-                sum_reward += r["reward"]
-                sum_quality += r["quality"]
-                sum_cost += r["cost"]
-        per_wave_shed[w] = (engine.counters["shed_queue_full"]
-                            + engine.counters["shed_no_arm"]) - shed0
-        if train_every and (w + 1) % train_every == 0:
-            ts = time.perf_counter()
-            engine.end_slice(epochs)
-            stalls.append((len(engine.decide_wall_s),
-                           time.perf_counter() - ts))
+    with CompileCount() as storm_compiles:
+        for w in range(waves):
+            faults.apply_wave(engine, w)
+            ids = rng.integers(0, n, size=int(sizes[w]))
+            reqs = [Request(tokens=_TOKENS, sample_idx=int(i)) for i in ids]
+            shed0 = (engine.counters["shed_queue_full"]
+                     + engine.counters["shed_no_arm"])
+            engine.submit(reqs)
+            recs = engine.pump()
+            recs += engine.drain()
+            for r in recs:
+                if r["status"] == "ok":
+                    n_ok += 1
+                    sum_reward += r["reward"]
+                    sum_quality += r["quality"]
+                    sum_cost += r["cost"]
+            per_wave_shed[w] = (engine.counters["shed_queue_full"]
+                                + engine.counters["shed_no_arm"]) - shed0
+            if train_every and (w + 1) % train_every == 0:
+                ts = time.perf_counter()
+                engine.end_slice(epochs)
+                stalls.append((len(engine.decide_wall_s),
+                               time.perf_counter() - ts))
     wall = time.perf_counter() - t0
     acct = engine.check_accounting()
 
@@ -135,6 +137,10 @@ def run_storm(env, router, *, requests: int, waves: int,
         "shed_no_arm": int(c["shed_no_arm"]),
         "fallbacks": int(c["fallbacks"]),
         "decide_errors": int(c["decide_errors"]),
+        "last_decide_error": engine.last_decide_error,
+        # XLA compiles inside the storm window (warm-up excluded): a
+        # shape the warm-up missed compiles here, on the request path
+        "compiles": int(storm_compiles.compiles),
         "learned": int(c["learned"]),
         "skipped_learn": int(c["skipped_learn"]),
         "lost_requests": int(acct["lost"]),

@@ -46,6 +46,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core import neuralucb as NU
 from repro.core import utilitynet as UN
@@ -257,13 +258,13 @@ _policy_scan = jax.jit(_policy_scan_impl, static_argnames=_STATIC)
 
 @functools.partial(
     jax.jit, static_argnames=("policies", "delay", "fcfg", "train_chunks",
-                              "batch_size"))
+                              "batch_size", "mesh"))
 def _policy_zoo_scan(tables, xs, env_idx, cum0, keys_tup, hyp_tup,
                      policies: Tuple[BanditPolicy, ...], scn=None,
                      delay: int = 0,
                      fcfg: ForgettingConfig = VANILLA_FORGETTING,
                      train_chunks: int = 1, batch_size: int = 256,
-                     init_tup: Any = None):
+                     init_tup: Any = None, mesh=None):
     """The POLICY AXIS: every policy's (grid x seed) lane vmap, compiled
     and executed as ONE jitted dispatch. Per policy, ``keys`` (L, 2) and
     every hyp leaf (L,) are pre-flattened by the caller into one lane
@@ -275,7 +276,12 @@ def _policy_zoo_scan(tables, xs, env_idx, cum0, keys_tup, hyp_tup,
     rather than one more vmap dimension — what stays uniform is the lane
     schema, the sharding, and the (G, n_seeds, T, ...) result layout.
     Scenario transforms are broadcast, not vmapped: every lane replays
-    the same drift (one resident copy of the transform tables)."""
+    the same drift (one resident copy of the transform tables).
+
+    With a multi-device ``mesh`` the lane vmap runs under ``shard_map``:
+    each device scans its own lane shard. The compiler cannot partition
+    a Pallas kernel across devices by itself, and lanes never talk to
+    each other, so nothing crosses devices until the results return."""
     out = []
     for i, p in enumerate(policies):
         # a pretrained init state (one per policy) is CLOSED OVER, so it
@@ -286,7 +292,12 @@ def _policy_zoo_scan(tables, xs, env_idx, cum0, keys_tup, hyp_tup,
             return _policy_scan_impl(tables, xs, env_idx, cum0, k, h, p,
                                      scn, delay, fcfg, train_chunks,
                                      batch_size, init_state=ist)[1]
-        out.append(jax.vmap(one)(keys_tup[i], hyp_tup[i]))
+        lanes = jax.vmap(one)
+        if mesh is not None and mesh.devices.size > 1:
+            spec = P(("grid", "seed"))
+            lanes = jax.shard_map(lanes, mesh=mesh, in_specs=(spec, spec),
+                                  out_specs=spec, check_vma=False)
+        out.append(lanes(keys_tup[i], hyp_tup[i]))
     return tuple(out)
 
 
@@ -442,7 +453,8 @@ def run_policy_sweep(env: DeviceReplayEnv,
                      forgetting: ForgettingConfig = VANILLA_FORGETTING,
                      train_steps: Optional[int] = None, epochs: int = 5,
                      batch_size: int = 256,
-                     init_states: Optional[Dict[str, Any]] = None
+                     init_states: Optional[Dict[str, Any]] = None,
+                     n_devices: Optional[int] = None
                      ) -> Dict[str, Dict]:
     """(policy × hypers × seed) study as ONE sharded device dispatch.
 
@@ -452,7 +464,9 @@ def run_policy_sweep(env: DeviceReplayEnv,
     axis is sharded across local devices, and all policies run inside
     one jitted program (``_policy_zoo_scan``). ``init_states`` maps
     name -> pretrained state pytree (:func:`pretrain_policy_state`) —
-    one state per policy, broadcast across its lanes.
+    one state per policy, broadcast across its lanes. ``n_devices`` caps
+    the local devices the lanes shard over (None = all): sharding never
+    changes a lane's result, so 1 gives the one-device reference.
 
     Returns {name: sweep} in the unified annotated schema: metric leaves
     (G, n_seeds, T, ...), plus ``seeds``, ``train_steps``, ``grid``
@@ -477,7 +491,7 @@ def run_policy_sweep(env: DeviceReplayEnv,
     base_keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
     gsizes = [_grid_size(grid) for _, grid in policies.values()]
     mesh = make_sweep_mesh(functools.reduce(math.gcd, gsizes, 0) or 1,
-                           n_seeds)
+                           n_seeds, n_devices)
     names, pols, keys_t, hyp_t, grids, layouts = [], [], [], [], [], []
     for (name, (pol, grid)), G in zip(policies.items(), gsizes):
         hyp = _flatten_lanes(grid, G, n_seeds)
@@ -497,7 +511,7 @@ def run_policy_sweep(env: DeviceReplayEnv,
     ms_t = _policy_zoo_scan(_tables(env), env.slice_xs(), env.idx,
                             _cum_valid(env), tuple(keys_t), tuple(hyp_t),
                             tuple(pols), scn, delay, forgetting, chunks,
-                            batch_size, init_tup=init_tup)
+                            batch_size, init_tup=init_tup, mesh=mesh)
     out = {}
     for name, pol, G, grid, layout, ms in zip(names, pols, gsizes, grids,
                                               layouts, ms_t):

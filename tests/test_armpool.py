@@ -333,3 +333,24 @@ def test_physical_pool_preset_tiny_end_to_end():
     assert mani["checksum"] == compile_pool(
         ArmPoolSpec(arms=ARMS4),
         generate_routerbench(0, 600)).checksum
+
+
+def test_decode_arm_engine_pins_batch_width():
+    """Rows are padded to the engine's pinned width: after the warm-up no
+    batch size up to it compiles anything, padding never changes a real
+    row's tokens, and a wider batch is refused."""
+    from repro.armpool import DecodeArmEngine
+    from repro.common.compilation import CompileCount
+
+    eng = DecodeArmEngine("mamba2_130m",
+                          get_config("mamba2_130m").reduced(), max_batch=4)
+    toks = np.arange(1, 5, dtype=np.int32).reshape(4, 1)
+    with CompileCount() as cc:
+        full, _ = eng.generate(toks, max_new=2)
+        for b in (1, 3):
+            part, _ = eng.generate(toks[:b], max_new=2)
+            np.testing.assert_array_equal(part, full[:b])
+    assert cc.compiles == 0
+    assert full.shape == (4, 2)
+    with pytest.raises(ValueError, match="pinned width"):
+        eng.generate(np.ones((5, 1), np.int32), max_new=2)

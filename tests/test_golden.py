@@ -8,13 +8,10 @@ the pre-unification `_baseline_scan`) that pins the unified
 
 The run executes in a subprocess with PYTHONHASHSEED pinned: the whole
 pipeline (dataset, encoder, protocol scan) is then a deterministic
-function of (platform, jax version) — see the encoders crc32 fix.
-Tolerances are two-tier: when the snapshot was produced under the same
-jax version, per-slice curves must match tightly (2e-4); under a
-different jax version, XLA codegen changes can flip argmax decisions and
-chaotically perturb trajectories, so only the summary-level means are
-held (0.03) — still enough to catch schedule/PRNG/reward regressions,
-which shift means systematically.
+function of (platform, jax version) — see the encoders crc32 fix — and
+per-slice curves must match the snapshot tightly (2e-4). The snapshots
+record the jax version they were made under; a jax upgrade that moves
+the PRNG stream or XLA codegen is a reason to regenerate them.
 
 Regenerate (after an INTENTIONAL semantics change only):
 
@@ -125,25 +122,18 @@ def test_baselines_match_pre_unification_scan_snapshot():
         golden = json.load(f)
     got = _run_base_golden()
     assert got["config"] == golden["config"]
-    same_jax = got["jax"] == golden["jax"]
     names = [k for k in golden if k not in ("jax", "config")]
     for name in names:
         g0, g1 = golden[name], got[name]
-        if same_jax:
-            for key in ("avg_reward", "avg_cost", "avg_quality",
-                        "oracle_avg_reward"):
-                np.testing.assert_allclose(
-                    g1[key], g0[key], rtol=2e-5, atol=1e-6,
-                    err_msg=f"{name}/{key} drifted from tests/golden/"
-                            f"baselines_tiny.json")
-            np.testing.assert_array_equal(
-                np.asarray(g1["action_hist"]),
-                np.asarray(g0["action_hist"]), err_msg=name)
-        else:
-            for key in ("avg_reward", "avg_cost", "avg_quality"):
-                np.testing.assert_allclose(
-                    np.mean(g1[key][1:]), np.mean(g0[key][1:]), atol=0.03,
-                    err_msg=f"{name}/{key} (cross-jax tolerance)")
+        for key in ("avg_reward", "avg_cost", "avg_quality",
+                    "oracle_avg_reward"):
+            np.testing.assert_allclose(
+                g1[key], g0[key], rtol=2e-5, atol=1e-6,
+                err_msg=f"{name}/{key} drifted from tests/golden/"
+                        f"baselines_tiny.json")
+        np.testing.assert_array_equal(
+            np.asarray(g1["action_hist"]),
+            np.asarray(g0["action_hist"]), err_msg=name)
 
 
 def test_neuralucb_tiny_matches_golden_snapshot():
@@ -151,28 +141,19 @@ def test_neuralucb_tiny_matches_golden_snapshot():
         golden = json.load(f)
     got = _run_golden()
     assert got["config"] == golden["config"]
-    same_jax = got["jax"] == golden["jax"]
     curves = ("avg_reward", "cum_reward", "avg_cost", "avg_quality",
               "oracle_avg_reward")
-    if same_jax:
-        for key in curves:
-            np.testing.assert_allclose(
-                got[key], golden[key], rtol=2e-4, atol=2e-4,
-                err_msg=f"{key} drifted from tests/golden/"
-                        f"neuralucb_tiny.json — if the change is an "
-                        f"INTENDED semantics change, regenerate via "
-                        f"`python tests/test_golden.py --regen`")
-        # decisions: histograms may differ by a handful of argmax flips
-        h0 = np.asarray(golden["action_hist"], np.float64)
-        h1 = np.asarray(got["action_hist"], np.float64)
-        assert np.abs(h0 - h1).sum() <= 0.02 * h0.sum()
-    else:
-        for key in ("avg_reward", "avg_cost", "avg_quality",
-                    "oracle_avg_reward"):
-            np.testing.assert_allclose(
-                np.mean(got[key][1:]), np.mean(golden[key][1:]),
-                atol=0.03, err_msg=f"{key} summary mean drifted "
-                                   f"(cross-jax-version tolerance)")
+    for key in curves:
+        np.testing.assert_allclose(
+            got[key], golden[key], rtol=2e-4, atol=2e-4,
+            err_msg=f"{key} drifted from tests/golden/"
+                    f"neuralucb_tiny.json — if the change is an "
+                    f"INTENDED semantics change, regenerate via "
+                    f"`python tests/test_golden.py --regen`")
+    # decisions: histograms may differ by a handful of argmax flips
+    h0 = np.asarray(golden["action_hist"], np.float64)
+    h1 = np.asarray(got["action_hist"], np.float64)
+    assert np.abs(h0 - h1).sum() <= 0.02 * h0.sum()
     # structure is held unconditionally
     assert np.asarray(got["action_hist"]).shape == \
         np.asarray(golden["action_hist"]).shape

@@ -45,10 +45,9 @@ def test_parse_real_psum_module():
     def f(a):
         return jax.lax.psum(a, "x")
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P()))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P()))
     comp = g.lower(jax.ShapeDtypeStruct((8, 16), jnp.float32)).compile()
     agg = collective_bytes(comp.as_text())
     # 1-device groups may be optimized away; parser must not crash and must

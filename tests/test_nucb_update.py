@@ -59,7 +59,7 @@ def test_nucb_update_matches_sherman_morrison(n, d, block_k):
 
 @settings(deadline=None, max_examples=10)
 @given(n=st.sampled_from([1, 37, 260]), d=st.sampled_from([9, 130]))
-def test_nucb_update_jnp_backend_bit_level(n, d, monkey=None):
+def test_nucb_update_jnp_backend_bit_level(n, d):
     """The jnp backend IS ``woodbury_update`` — bit-identical in f32."""
     ainv, gs = _case(1, n, d)
     want = NU.woodbury_update(ainv, gs)

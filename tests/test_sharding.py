@@ -21,10 +21,11 @@ from repro.distributed.sharding import (
     param_partition_specs,
 )
 from repro.launch import specs as SPECS
+from repro.launch.mesh import make_host_mesh
 
 
 def _tiny_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_host_mesh()
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -89,10 +90,12 @@ from repro.distributed import logical_axis_rules
 from repro.distributed.sharding import (activation_rules,
     batch_partition_specs, param_partition_specs)
 from repro.launch import specs as SPECS
+from repro.launch.mesh import make_host_mesh
 from repro.training import train_step as TS
 import functools, numpy as np
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh(model_parallel=4)
+assert dict(mesh.shape) == {"data": 2, "model": 4}, mesh.shape
 cfg = dataclasses.replace(get_config("granite-moe-1b-a400m").reduced(),
                           dtype="float32", num_experts=4,
                           num_heads=4, num_kv_heads=4)
@@ -189,6 +192,14 @@ sw = run_policy_sweep(denv, zoo, seeds=range(4))
 assert set(sw) == {"greedy", "dyn_min_cost"}
 for d in sw.values():
     assert d["avg_reward"].shape == (1, 4, 3)
+# the lanes sharded over 2 devices are bit-identical to the same sweep
+# on one device
+import numpy as np
+one = run_policy_sweep(denv, zoo, seeds=range(4), n_devices=1)
+for n, d in one.items():
+    assert d["layout"]["n_devices"] == 1
+    for k in ("avg_reward", "avg_cost", "action_hist"):
+        np.testing.assert_array_equal(d[k], sw[n][k], err_msg=k)
 print("SWEEP_SUBPROC_OK")
 """
 

@@ -26,6 +26,10 @@ def run_protocol(env: RouterBenchSim, policies: Dict[str, object], *,
     Returns {policy: {"avg_reward": [...], "cum_reward": [...],
                       "avg_cost": [...], "avg_quality": [...],
                       "action_hist": (T, K), "wall_s": [...]}}.
+    Only host runners (this one and the host-stepped
+    ``repro.sim.engine`` runner) carry ``wall_s``, each slice's host
+    wall; the device scans and sweeps time no slice on its own and leave
+    it out.
     """
     T = env.n_slices if max_slices is None else min(env.n_slices, max_slices)
     K = env.K

@@ -43,6 +43,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.common.tracing import span
 from repro.core.reward import utility_reward
 from repro.serving.batcher import Request, RequestBatcher
 from repro.serving.snapshot import load_snapshot, save_snapshot, \
@@ -182,18 +183,19 @@ class AsyncRouterEngine:
     def submit(self, requests: Sequence[Request]):
         """Admit into the bounded queue; excess is shed with a counted
         drop (and a log record). Returns (n_admitted, n_shed)."""
-        now = self.clock()
-        shed = 0
-        for r in requests:
-            self.counters["submitted"] += 1
-            if len(self._admit) >= self.queue_capacity:
-                self.counters["shed_queue_full"] += 1
-                shed += 1
-                self._log({"rid": r.rid, "status": "shed_queue_full",
-                           "action": -1, "reward": 0.0})
-            else:
-                self.counters["admitted"] += 1
-                self._admit.append((r, now))
+        with span("engine.submit", rows=len(requests)):
+            now = self.clock()
+            shed = 0
+            for r in requests:
+                self.counters["submitted"] += 1
+                if len(self._admit) >= self.queue_capacity:
+                    self.counters["shed_queue_full"] += 1
+                    shed += 1
+                    self._log({"rid": r.rid, "status": "shed_queue_full",
+                               "action": -1, "reward": 0.0})
+                else:
+                    self.counters["admitted"] += 1
+                    self._admit.append((r, now))
         return len(requests) - shed, shed
 
     @property
@@ -205,15 +207,19 @@ class AsyncRouterEngine:
         """Advance decide -> dispatch -> serve as far as currently due;
         returns the records completed by this call."""
         out: List[Dict] = []
-        while self._decide_due(force):
-            reqs = [self._admit.popleft()[0]
-                    for _ in range(min(self.decide_batch, len(self._admit)))]
-            self._decide_and_dispatch(reqs, out)
-        while True:
-            nb = self.batcher.next_batch(force=force)
-            if nb is None:
-                break
-            self._serve_batch(*nb, out)
+        with span("engine.pump"):
+            while self._decide_due(force):
+                k = min(self.decide_batch, len(self._admit))
+                reqs, arrived = zip(*[self._admit.popleft()
+                                      for _ in range(k)])
+                # the rows' summed admission wait, on the engine's clock
+                wait_s = k * self.clock() - sum(arrived)
+                self._decide_and_dispatch(list(reqs), out, wait_s)
+            while True:
+                nb = self.batcher.next_batch(force=force)
+                if nb is None:
+                    break
+                self._serve_batch(*nb, out)
         return out
 
     def drain(self, max_rounds: int = 10_000) -> List[Dict]:
@@ -232,7 +238,8 @@ class AsyncRouterEngine:
                            f"counters={self.counters}")
 
     def end_slice(self, epochs: int = 1):
-        return self.router.end_slice(epochs)
+        with span("engine.end_slice"):
+            return self.router.end_slice(epochs)
 
     # ----------------------------------------------------------- decide --
     def _decide_due(self, force: bool) -> bool:
@@ -243,7 +250,8 @@ class AsyncRouterEngine:
             return True
         return self.clock() - self._admit[0][1] >= self.decide_flush
 
-    def _decide_and_dispatch(self, reqs: List[Request], out: List[Dict]):
+    def _decide_and_dispatch(self, reqs: List[Request], out: List[Dict],
+                             wait_s: float):
         n = len(reqs)
         if not self.arm_up.any():
             for r in reqs:
@@ -253,122 +261,130 @@ class AsyncRouterEngine:
                 self._log(rec)
                 out.append(rec)
             return
-        x_emb = x_feat = domain = None
-        if not self._serving_v2:
-            x_emb = np.stack([r.x_emb for r in reqs])
-            x_feat = np.stack([r.x_feat for r in reqs])
-            domain = np.array([r.domain for r in reqs], np.int32)
-        call_idx = self.counters["decide_calls"]
-        self.counters["decide_calls"] += 1
-        decision = None
-        t0 = time.perf_counter()
-        try:
-            if self.fault_hook is not None:
-                self.fault_hook(call_idx)
-            if self._serving_v2:
-                ids = np.array([r.sample_idx for r in reqs], np.int64)
-                decision = self.router.decide(
-                    sample_idx=ids,
-                    avail=self.arm_up.astype(np.float32))
-            else:
-                decision = self.router.decide(x_emb, x_feat, domain)
-            decided = np.asarray(decision["action"], np.int32).copy()
-            self.decide_wall_s.append(time.perf_counter() - t0)
-        except Exception as e:
-            # degrade, don't die: cheapest healthy arm, no router update
-            self.counters["decide_errors"] += 1
-            self.last_decide_error = f"{type(e).__name__}: {e}"[:2000]
-            decision = None
-            decided = np.full(n, self._safe_arm(), np.int32)
-
         gid = self._next_gid
         self._next_gid += 1
+        with span("engine.decide", gid=gid, rows=n, wait_us=wait_s * 1e6):
+            x_emb = x_feat = domain = None
+            if not self._serving_v2:
+                x_emb = np.stack([r.x_emb for r in reqs])
+                x_feat = np.stack([r.x_feat for r in reqs])
+                domain = np.array([r.domain for r in reqs], np.int32)
+            call_idx = self.counters["decide_calls"]
+            self.counters["decide_calls"] += 1
+            decision = None
+            t0 = time.perf_counter()
+            try:
+                if self.fault_hook is not None:
+                    self.fault_hook(call_idx)
+                if self._serving_v2:
+                    ids = np.array([r.sample_idx for r in reqs], np.int64)
+                    decision = self.router.decide(
+                        sample_idx=ids,
+                        avail=self.arm_up.astype(np.float32))
+                else:
+                    decision = self.router.decide(x_emb, x_feat, domain)
+                decided = np.asarray(decision["action"], np.int32).copy()
+                self.decide_wall_s.append(time.perf_counter() - t0)
+            except Exception as e:
+                # degrade, don't die: cheapest healthy arm, no router update
+                self.counters["decide_errors"] += 1
+                self.last_decide_error = f"{type(e).__name__}: {e}"[:2000]
+                decision = None
+                decided = np.full(n, self._safe_arm(), np.int32)
+
         g = _Group(decision, reqs, decided, x_emb, x_feat, domain)
         self._groups[gid] = g
-        for i, r in enumerate(reqs):
-            served, depth = self._resolve_arm(int(decided[i]))
-            if served < 0:
-                self.counters["shed_no_arm"] += 1
-                g.remaining -= 1
-                rec = {"rid": r.rid, "status": "shed_no_arm", "action": -1,
-                       "reward": 0.0}
-                self._log(rec)
-                out.append(rec)
-                continue
-            if depth > 0:
-                self.counters["fallbacks"] += 1
-            g.depth[i] = depth
-            self._rid_slot[r.rid] = (gid, i)
-            self.batcher.submit(served, r)
+        with span("engine.dispatch", gid=gid, rows=n):
+            for i, r in enumerate(reqs):
+                served, depth = self._resolve_arm(int(decided[i]))
+                if served < 0:
+                    self.counters["shed_no_arm"] += 1
+                    g.remaining -= 1
+                    rec = {"rid": r.rid, "status": "shed_no_arm",
+                           "action": -1, "reward": 0.0}
+                    self._log(rec)
+                    out.append(rec)
+                    continue
+                if depth > 0:
+                    self.counters["fallbacks"] += 1
+                g.depth[i] = depth
+                self._rid_slot[r.rid] = (gid, i)
+                self.batcher.submit(served, r)
         if g.remaining == 0:
             self._finalize(gid, out)
 
     # ------------------------------------------------------------ serve --
     def _serve_batch(self, target: int, reqs: List[Request],
                      toks: np.ndarray, out: List[Dict]):
-        n_new = self.max_new
-        if self.engines is not None:
-            new_tokens, _ = self.engines[target].generate(
-                toks, max_new=self.max_new)
-            n_new = new_tokens.shape[1]
-        ids = np.array([r.sample_idx for r in reqs], np.int64)
-        if self.reward_table is not None:
-            rw = np.asarray(self.reward_table[ids, target], np.float32)
-            q = rw if self.quality_table is None else \
-                np.asarray(self.quality_table[ids, target], np.float32)
-            c = np.zeros(len(reqs), np.float32) if self.cost_table is None \
-                else np.asarray(self.cost_table[ids, target], np.float32)
-        else:
-            n_tok = np.array([len(r.tokens) + n_new for r in reqs])
-            c = (self.cost_per_token[target] * n_tok).astype(np.float32)
-            q = np.full(len(reqs), 0.5, np.float32)
-            if self.quality_table is not None:
-                sel = ids >= 0
-                q[sel] = self.quality_table[ids[sel], target]
-            rw = np.asarray(utility_reward(q, c, self.c_max,
-                                           self.cost_lambda), np.float32)
-        touched = set()
-        for i, r in enumerate(reqs):
-            gid, pos = self._rid_slot.pop(r.rid)
-            g = self._groups[gid]
-            g.served[pos] = target
-            g.reward[pos] = rw[i]
-            g.quality[pos] = q[i]
-            g.cost[pos] = c[i]
-            g.remaining -= 1
-            touched.add(gid)
-        for gid in sorted(touched):
-            if self._groups[gid].remaining == 0:
-                self._finalize(gid, out)
+        with span("engine.serve", arm=target, rows=len(reqs)):
+            n_new = self.max_new
+            if self.engines is not None:
+                new_tokens, _ = self.engines[target].generate(
+                    toks, max_new=self.max_new)
+                n_new = new_tokens.shape[1]
+            ids = np.array([r.sample_idx for r in reqs], np.int64)
+            if self.reward_table is not None:
+                rw = np.asarray(self.reward_table[ids, target], np.float32)
+                q = rw if self.quality_table is None else \
+                    np.asarray(self.quality_table[ids, target], np.float32)
+                c = (np.zeros(len(reqs), np.float32)
+                     if self.cost_table is None else
+                     np.asarray(self.cost_table[ids, target], np.float32))
+            else:
+                n_tok = np.array([len(r.tokens) + n_new for r in reqs])
+                c = (self.cost_per_token[target] * n_tok).astype(np.float32)
+                q = np.full(len(reqs), 0.5, np.float32)
+                if self.quality_table is not None:
+                    sel = ids >= 0
+                    q[sel] = self.quality_table[ids[sel], target]
+                rw = np.asarray(utility_reward(q, c, self.c_max,
+                                               self.cost_lambda), np.float32)
+            touched = set()
+            for i, r in enumerate(reqs):
+                gid, pos = self._rid_slot.pop(r.rid)
+                g = self._groups[gid]
+                g.served[pos] = target
+                g.reward[pos] = rw[i]
+                g.quality[pos] = q[i]
+                g.cost[pos] = c[i]
+                g.remaining -= 1
+                touched.add(gid)
+            for gid in sorted(touched):
+                if self._groups[gid].remaining == 0:
+                    self._finalize(gid, out)
 
     # --------------------------------------------------------- feedback --
     def _finalize(self, gid: int, out: List[Dict]):
         g = self._groups.pop(gid)
         ok = g.served >= 0
-        if g.decision is not None and ok.any():
-            if self._serving_v2:
-                served = np.where(ok, g.served, g.decided)
-                learned = self.router.update_wave(
-                    g.decision, served, g.reward, learn_mask=ok)
-                self.counters["updates"] += 1
-                self.counters["learned"] += int(learned)
-                self.counters["skipped_learn"] += int(ok.sum()) - int(learned)
-            else:
-                self._update_legacy(g, ok)
-        elif g.decision is None:
-            self.counters["skipped_learn"] += int(ok.sum())
-        for i, r in enumerate(g.reqs):
-            if not ok[i]:
-                continue   # shed rows were logged at dispatch
-            self.counters["completed"] += 1
-            rec = {"rid": r.rid, "status": "ok", "action": int(g.served[i]),
-                   "decided": int(g.decided[i]),
-                   "fallback_depth": int(g.depth[i]),
-                   "reward": float(g.reward[i]),
-                   "quality": float(g.quality[i]),
-                   "cost": float(g.cost[i])}
-            self._log(rec)
-            out.append(rec)
+        with span("engine.finalize", gid=gid,
+                  rows=int(np.count_nonzero(ok))):
+            if g.decision is not None and ok.any():
+                if self._serving_v2:
+                    served = np.where(ok, g.served, g.decided)
+                    learned = self.router.update_wave(
+                        g.decision, served, g.reward, learn_mask=ok)
+                    self.counters["updates"] += 1
+                    self.counters["learned"] += int(learned)
+                    self.counters["skipped_learn"] += (int(ok.sum())
+                                                       - int(learned))
+                else:
+                    self._update_legacy(g, ok)
+            elif g.decision is None:
+                self.counters["skipped_learn"] += int(ok.sum())
+            for i, r in enumerate(g.reqs):
+                if not ok[i]:
+                    continue   # shed rows were logged at dispatch
+                self.counters["completed"] += 1
+                rec = {"rid": r.rid, "status": "ok",
+                       "action": int(g.served[i]),
+                       "decided": int(g.decided[i]),
+                       "fallback_depth": int(g.depth[i]),
+                       "reward": float(g.reward[i]),
+                       "quality": float(g.quality[i]),
+                       "cost": float(g.cost[i])}
+                self._log(rec)
+                out.append(rec)
 
     def _update_legacy(self, g: _Group, ok: np.ndarray):
         """Host-router feedback: slice the decision to completed rows;

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.tracing import span
 from repro.sim.policies import (
     VANILLA_FORGETTING,
     BanditPolicy,
@@ -70,7 +71,8 @@ def _srv_decide(policy: BanditPolicy, state, key, tables, hyp, ids, avail,
              "domain": tables["domain"][ids]}
     ctx = _ctx(tables, hyp, t=t, idx=ids, avail=avail, fcfg=fcfg,
                train_chunks=train_chunks, batch_size=batch_size)
-    return policy.decide(state, key, batch, ctx)
+    with jax.named_scope("router.decide"):
+        return policy.decide(state, key, batch, ctx)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC,
@@ -95,7 +97,8 @@ def _srv_update(policy: BanditPolicy, state, env_idx, tables, hyp, row,
              "domain": tables["domain"][ids]}
     ctx = _ctx(tables, hyp, env_idx=env_idx, t=row, idx=ids, mask=mask,
                fcfg=fcfg, train_chunks=train_chunks, batch_size=batch_size)
-    state = policy.update(state, batch, a, r, ctx, aux)
+    with jax.named_scope("router.update"):
+        state = policy.update(state, batch, a, r, ctx, aux)
     return state, env_idx
 
 
@@ -109,8 +112,10 @@ def _srv_train(policy: BanditPolicy, state, key, tables, hyp, env_idx,
     so the committed state decide reads stays live."""
     ctx = _ctx(tables, hyp, env_idx=env_idx, cum0=cum0, t=t, fcfg=fcfg,
                train_chunks=train_chunks, batch_size=batch_size)
-    state, key = policy.train(state, key, ctx)
-    state = policy.rebuild(state, ctx)
+    with jax.named_scope("router.train_sgd"):
+        state, key = policy.train(state, key, ctx)
+    with jax.named_scope("router.rebuild"):
+        state = policy.rebuild(state, ctx)
     return state, key
 
 
@@ -126,7 +131,8 @@ def _srv_train_sgd(policy: BanditPolicy, state, key, tables, hyp, env_idx,
     duration on a busy device)."""
     ctx = _ctx(tables, hyp, env_idx=env_idx, cum0=cum0, t=t, fcfg=fcfg,
                train_chunks=train_chunks, batch_size=batch_size)
-    return policy.train(state, key, ctx)
+    with jax.named_scope("router.train_sgd"):
+        return policy.train(state, key, ctx)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC,
@@ -137,7 +143,8 @@ def _srv_rebuild(policy: BanditPolicy, state, tables, hyp, env_idx, cum0,
     """A^-1 rebuild stage of the staged overlapped train."""
     ctx = _ctx(tables, hyp, env_idx=env_idx, cum0=cum0, t=t, fcfg=fcfg,
                train_chunks=train_chunks, batch_size=batch_size)
-    return policy.rebuild(state, ctx)
+    with jax.named_scope("router.rebuild"):
+        return policy.rebuild(state, ctx)
 
 
 def _merge_trained(new, cur):
@@ -307,25 +314,33 @@ class DevicePolicyRouter:
         scan's no-scenario path)."""
         ids = np.asarray(sample_idx, np.int64).reshape(-1)
         B = ids.size
-        if not 0 < B <= self.S:
-            raise ValueError(f"microbatch size {B} outside (0, {self.S}]")
-        ids_pad = np.zeros(self.S, np.int32)
-        ids_pad[:B] = ids
-        av = None
-        if avail is not None and not np.all(np.asarray(avail) > 0):
-            av = jnp.asarray(avail, jnp.float32)
-        if self._pending:
-            # overlapped mode: tick the train pipeline (SGD -> rebuild
-            # -> commit) — decide reads the freshest COMMITTED state
-            self._advance()
-        self._key, k = jax.random.split(self._key)
-        a, logp, aux = _srv_decide(
-            self.policy, self.state, k, self.tables, self.hyp,
-            jnp.asarray(ids_pad), av, jnp.int32(min(self.slices, 1)),
-            **self._statics())
-        return {"action": np.asarray(a)[:B].astype(np.int32),
-                "logp": np.asarray(logp)[:B].astype(np.float32),
-                "ids": ids, "aux": aux, "n": B}
+        with span("router.decide", rows=B):
+            with span("router.decide.prep"):
+                if not 0 < B <= self.S:
+                    raise ValueError(
+                        f"microbatch size {B} outside (0, {self.S}]")
+                ids_pad = np.zeros(self.S, np.int32)
+                ids_pad[:B] = ids
+                av = None
+                if avail is not None and not np.all(np.asarray(avail) > 0):
+                    av = jnp.asarray(avail, jnp.float32)
+                if self._pending:
+                    # overlapped mode: tick the train pipeline (SGD ->
+                    # rebuild -> commit) — decide reads the freshest
+                    # COMMITTED state
+                    self._advance()
+                self._key, k = jax.random.split(self._key)
+                ids_dev = jnp.asarray(ids_pad)
+                t = jnp.int32(min(self.slices, 1))
+            with span("router.decide.launch"):
+                a, logp, aux = _srv_decide(
+                    self.policy, self.state, k, self.tables, self.hyp,
+                    ids_dev, av, t, **self._statics())
+            with span("router.decide.fetch"):
+                action = np.asarray(a)[:B].astype(np.int32)
+                logp = np.asarray(logp)[:B].astype(np.float32)
+        return {"action": action, "logp": logp, "ids": ids, "aux": aux,
+                "n": B}
 
     # ----------------------------------------------------------- UPDATE --
     def update_wave(self, decision: Dict, served, rewards,
@@ -336,25 +351,30 @@ class DevicePolicyRouter:
         (sheds and remaps excluded by the engine). Returns the number of
         rows learned."""
         B = decision["n"]
-        served = np.asarray(served, np.int32).reshape(-1)
-        rewards = np.asarray(rewards, np.float32).reshape(-1)
-        assert served.size == B and rewards.size == B
-        learn = (np.ones(B, bool) if learn_mask is None
-                 else np.asarray(learn_mask, bool).reshape(-1))
-        decided = decision["action"]
-        learn = learn & (served == decided)   # remapped rows: aux is stale
-        order = np.argsort(~learn, kind="stable")
-        perm = np.concatenate([order, np.arange(B, self.S)]).astype(np.int32)
-        pad = lambda v, dt: np.concatenate(  # noqa: E731
-            [v, np.zeros(self.S - B, dt)]).astype(dt)
-        row = self.wave % self.T
-        self.state, self._env_idx = _srv_update(
-            self.policy, self.state, self._env_idx, self.tables, self.hyp,
-            jnp.int32(row), jnp.asarray(pad(decision["ids"], np.int32)),
-            jnp.asarray(pad(served, np.int32)),
-            jnp.asarray(pad(rewards, np.float32)),
-            jnp.asarray(pad(learn.astype(np.float32), np.float32)),
-            jnp.asarray(perm), decision["aux"], **self._statics())
+        with span("router.update", rows=B):
+            with span("router.update.prep"):
+                served = np.asarray(served, np.int32).reshape(-1)
+                rewards = np.asarray(rewards, np.float32).reshape(-1)
+                assert served.size == B and rewards.size == B
+                learn = (np.ones(B, bool) if learn_mask is None
+                         else np.asarray(learn_mask, bool).reshape(-1))
+                decided = decision["action"]
+                # remapped rows: aux is stale
+                learn = learn & (served == decided)
+                order = np.argsort(~learn, kind="stable")
+                perm = np.concatenate(
+                    [order, np.arange(B, self.S)]).astype(np.int32)
+                pad = lambda v, dt: jnp.asarray(np.concatenate(  # noqa: E731
+                    [v, np.zeros(self.S - B, dt)]).astype(dt))
+                row = self.wave % self.T
+                args = (jnp.int32(row), pad(decision["ids"], np.int32),
+                        pad(served, np.int32), pad(rewards, np.float32),
+                        pad(learn.astype(np.float32), np.float32),
+                        jnp.asarray(perm))
+            with span("router.update.launch"):
+                self.state, self._env_idx = _srv_update(
+                    self.policy, self.state, self._env_idx, self.tables,
+                    self.hyp, *args, decision["aux"], **self._statics())
         self._counts[row] = int(learn.sum())
         self.wave += 1
         if self.log_capacity and learn.any():
@@ -414,49 +434,60 @@ class DevicePolicyRouter:
         host thread goes straight back to admitting and deciding the
         next microbatches while the device grinds the train program."""
         del epochs
-        if self.wave > 0:
-            t = min(self.wave, self.T) - 1
-            cum0 = jnp.asarray(np.concatenate(
-                [[0], np.cumsum(self._counts)]).astype(np.int32))
-            if self.max_train_lag == 0:
+        with span("router.end_slice"):
+            self._train_slice()
+        self.slices += 1
+
+    def _train_slice(self) -> None:
+        """Dispatch the slice's train (and, with ``max_train_lag`` 0,
+        wait for it); nothing before the first microbatch."""
+        if self.wave == 0:
+            return
+        t = min(self.wave, self.T) - 1
+        cum0 = jnp.asarray(np.concatenate(
+            [[0], np.cumsum(self._counts)]).astype(np.int32))
+        if self.max_train_lag == 0:
+            with span("router.train.launch"):
                 self.state, self._key = _srv_train(
                     self.policy, self.state, self._key, self.tables,
                     self.hyp, self._env_idx, cum0, jnp.int32(t),
                     **self._statics())
-                self.train_epoch += 1
-                self.committed_epoch = self.train_epoch
+            self.train_epoch += 1
+            self.committed_epoch = self.train_epoch
+            with span("router.train.wait"):
                 jax.block_until_ready(self.state)
-            else:
-                # bounded staleness: dispatching the (lag+1)-th in-flight
-                # train blocks on the oldest, so decide never lags the
-                # freshest dispatched train by more than max_train_lag
-                self._advance()
-                while len(self._pending) >= self.max_train_lag:
-                    self._force_oldest()
-                base = (_merge_trained(self._pending[-1][2], self.state)
-                        if self._pending else self.state)
-                # donate a device-side copy: `base` aliases the committed
-                # state (and possibly a pending commit target) that
-                # decide keeps reading while this train is in flight.
-                # env_idx is copied too — the live buffer is donated away
-                # by the next update_wave, and the rebuild stage reads it
-                # later than this dispatch.
-                tin = jax.tree_util.tree_map(jnp.copy, base)
-                env_c = jnp.copy(self._env_idx)
-                t32 = jnp.int32(t)
-                s1, self._key = _srv_train_sgd(
-                    self.policy, tin, self._key, self.tables, self.hyp,
-                    env_c, cum0, t32, **self._statics())
-                self.train_epoch += 1
-                # `tin` rides along as a keep-alive: dropping the last
-                # reference to a DONATED array blocks the host until the
-                # consuming computation finishes (which would make this
-                # "zero-sync" dispatch silently synchronous). It is
-                # released when the stage completes, when deletion is
-                # free. Entry: [epoch, stage, output, keep, rebuild ctx].
-                self._pending.append(
-                    [self.train_epoch, "sgd", s1, tin, (env_c, cum0, t32)])
-        self.slices += 1
+            return
+        # bounded staleness: dispatching the (lag+1)-th in-flight train
+        # blocks on the oldest, so decide never lags the freshest
+        # dispatched train by more than max_train_lag
+        with span("router.train.commit"):
+            self._advance()
+            while len(self._pending) >= self.max_train_lag:
+                self._force_oldest()
+        base = (_merge_trained(self._pending[-1][2], self.state)
+                if self._pending else self.state)
+        with span("router.train.launch"):
+            # donate a device-side copy: `base` aliases the committed
+            # state (and possibly a pending commit target) that decide
+            # keeps reading while this train is in flight. env_idx is
+            # copied too — the live buffer is donated away by the next
+            # update_wave, and the rebuild stage reads it later than
+            # this dispatch.
+            tin = jax.tree_util.tree_map(jnp.copy, base)
+            env_c = jnp.copy(self._env_idx)
+            t32 = jnp.int32(t)
+            s1, self._key = _srv_train_sgd(
+                self.policy, tin, self._key, self.tables, self.hyp,
+                env_c, cum0, t32, **self._statics())
+        self.train_epoch += 1
+        # `tin` rides along as a keep-alive: dropping the last reference
+        # to a DONATED array blocks the host until the consuming
+        # computation finishes (which would make this "zero-sync"
+        # dispatch silently synchronous). It is released when the stage
+        # completes, when deletion is free. Entry: [epoch, stage, output,
+        # keep, rebuild ctx].
+        self._pending.append(
+            [self.train_epoch, "sgd", s1, tin, (env_c, cum0, t32)])
 
     # --------------------------------------------------------- SNAPSHOT --
     def state_dict(self) -> Dict:

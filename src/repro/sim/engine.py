@@ -163,10 +163,10 @@ def _slice_metrics(tables, eff, idx, mask, actions):
             "action_hist": hist, "oracle_avg_reward": o.sum() / denom}
 
 
-def _metrics_to_results(ms: Dict[str, np.ndarray], wall_s: float) -> Dict:
+def _metrics_to_results(ms: Dict[str, np.ndarray]) -> Dict:
     """Convert stacked per-slice device metrics to the
-    ``core.protocol.run_protocol`` per-policy result format."""
-    T = len(ms["avg_reward"])
+    ``core.protocol.run_protocol`` per-policy result format (a scan
+    times no slice on its own, so there is no ``wall_s``)."""
     cum = np.cumsum(np.asarray(ms["sum_reward"], np.float64))
     out = {
         "avg_reward": [float(v) for v in ms["avg_reward"]],
@@ -175,7 +175,6 @@ def _metrics_to_results(ms: Dict[str, np.ndarray], wall_s: float) -> Dict:
         "avg_quality": [float(v) for v in ms["avg_quality"]],
         "oracle_avg_reward": [float(v) for v in ms["oracle_avg_reward"]],
         "action_hist": np.asarray(ms["action_hist"]),
-        "wall_s": [wall_s / T] * T,
     }
     if "mean_logp" in ms:
         out["mean_logp"] = [float(v) for v in ms["mean_logp"]]
@@ -234,7 +233,8 @@ def _policy_scan_impl(tables, xs, env_idx, cum0, key, hyp,
         batch = _context(tables, idx)
         avail = None if eff is None else eff["avail"]
         ctx = ctx0._replace(eff=eff, t=t, idx=idx, mask=mask, avail=avail)
-        a, logp, aux = policy.decide(state, k_slice, batch, ctx)
+        with jax.named_scope("router.decide"):
+            a, logp, aux = policy.decide(state, k_slice, batch, ctx)
         if not policy.availability_aware and avail is not None:
             a = _avail_fallback(a, avail, tables["mean_cost"])
         m = _slice_metrics(tables, eff, idx, mask, a)
@@ -242,9 +242,12 @@ def _policy_scan_impl(tables, xs, env_idx, cum0, key, hyp,
         m["mean_logp"] = (logp * mask).sum() / jnp.maximum(mask.sum(), 1.0)
         if record_log:
             m["action"], m["logp"], m["reward"] = a, logp, r
-        state = policy.update(state, batch, a, r, ctx, aux)
-        state, key = policy.train(state, key, ctx)
-        state = policy.rebuild(state, ctx)
+        with jax.named_scope("router.update"):
+            state = policy.update(state, batch, a, r, ctx, aux)
+        with jax.named_scope("router.train_sgd"):
+            state, key = policy.train(state, key, ctx)
+        with jax.named_scope("router.rebuild"):
+            state = policy.rebuild(state, ctx)
         return (state, key), m
 
     return jax.lax.scan(step, (state, key), xs)
@@ -365,7 +368,6 @@ def run_policy_device(env: DeviceReplayEnv, policy: BanditPolicy,
     from repro.data.logged import from_run_log
     env, scn, delay = resolve_scenario(env, scenario)
     chunks = _chunks_for(env, policy, train_steps, epochs, batch_size)
-    t0 = time.perf_counter()
     (state, key), ms = _policy_scan(
         _tables(env), env.slice_xs(), env.idx, _cum_valid(env),
         jax.random.PRNGKey(seed), hypers, policy, scn, delay, forgetting,
@@ -374,7 +376,7 @@ def run_policy_device(env: DeviceReplayEnv, policy: BanditPolicy,
     ms = {k: np.asarray(v) for k, v in ms.items()}
     log = {k: ms.pop(k) for k in ("action", "logp", "reward")
            if k in ms}
-    res = _metrics_to_results(ms, time.perf_counter() - t0)
+    res = _metrics_to_results(ms)
     extras = []
     if record_log:
         extras.append(from_run_log(env, log, behavior=policy.name))
@@ -659,7 +661,6 @@ def sweep_point_results(sweep: Dict[str, np.ndarray], g: int,
     sweep as a ``run_protocol`` per-policy result dict, so sweep cells
     feed ``repro.core.protocol.summarize`` unchanged."""
     cum = np.cumsum(np.asarray(sweep["sum_reward"][g, s], np.float64))
-    T = len(cum)
     out = {
         "avg_reward": [float(v) for v in sweep["avg_reward"][g, s]],
         "cum_reward": [float(v) for v in cum],
@@ -668,7 +669,6 @@ def sweep_point_results(sweep: Dict[str, np.ndarray], g: int,
         "oracle_avg_reward": [float(v)
                               for v in sweep["oracle_avg_reward"][g, s]],
         "action_hist": np.asarray(sweep["action_hist"][g, s]),
-        "wall_s": [0.0] * T,
     }
     if "mean_logp" in sweep:
         out["mean_logp"] = [float(v) for v in sweep["mean_logp"][g, s]]
@@ -861,7 +861,7 @@ class DeviceNeuralUCB:
                       f"avg_reward={float(m['avg_reward']):.3f}", flush=True)
         ms = {k: np.stack([np.asarray(m[k]) for m in per_slice])
               for k in per_slice[0]}
-        out = _metrics_to_results(ms, sum(wall))
+        out = _metrics_to_results(ms)
         out["wall_s"] = wall
         return out
 

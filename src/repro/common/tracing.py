@@ -31,13 +31,18 @@ SPANS = {
     "engine.end_slice": "the router's end of slice",
     # serving/policy_router.py
     "router.decide": "DevicePolicyRouter.decide (stat rows)",
-    "router.decide.prep": "id padding, availability mask, train pipeline "
-                          "tick, key split",
-    "router.decide.launch": "the decide program's call up to its return",
-    "router.decide.fetch": "copying action and logp to the host (waits "
-                           "for the device)",
+    "router.decide.prep": "ids and step packed into one buffer, "
+                          "availability mask, train pipeline tick, the "
+                          "puts to the device (stat puts: 1, or 2 with "
+                          "an arm down)",
+    "router.decide.launch": "the decide program's call up to its return "
+                            "(the key split runs inside the program)",
+    "router.decide.fetch": "one copy of the packed action and logp to the "
+                           "host, waiting for the device (stat gets)",
     "router.update": "DevicePolicyRouter.update_wave (stat rows)",
-    "router.update.prep": "learn mask, row order, padding",
+    "router.update.prep": "learn mask, row order, row, ids, outcomes and "
+                          "order packed into one buffer and put to the "
+                          "device (stat puts)",
     "router.update.launch": "the update program's call up to its return",
     "router.end_slice": "DevicePolicyRouter.end_slice",
     "router.train.launch": "dispatch of the train program(s)",

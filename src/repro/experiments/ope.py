@@ -4,7 +4,7 @@
 One propensity-aware behavior log, scored against every target policy
 of the spec via ``repro.core.protocol.estimate_offline`` (IPS / SNIPS /
 DM / DR). Target action distributions reuse the SERVING decide kernel
-(``repro.serving.policy_router._srv_decide``) chunked over the logged
+(``repro.serving.policy_router._srv_decide_rows``) chunked over the logged
 contexts at ``t=1`` — the post-warm-up step — so offline scoring runs
 the exact routing code the online paths run, not a reimplementation.
 Targets with a pretrain hook are first fit offline on the behavior log
@@ -74,7 +74,7 @@ def _target_actions(plan: ExperimentPlan, name: str,
     serving kernel at ``t=1`` (past the neural warm-up slice), with the
     target pretrained on the behavior log when it has an offline
     phase."""
-    from repro.serving.policy_router import _srv_decide, _srv_init
+    from repro.serving.policy_router import _srv_decide_rows, _srv_init
     from repro.sim.engine import _tables
 
     ope = plan.spec.ope
@@ -98,9 +98,9 @@ def _target_actions(plan: ExperimentPlan, name: str,
     t1 = jnp.int32(1)
     acts: List[np.ndarray] = []
     for c0 in range(0, ids_p.shape[0], _CHUNK):
-        a, _, _ = _srv_decide(pol, state, jax.random.fold_in(key, c0),
-                              ptables, hyp, jnp.asarray(ids_p[c0:c0 + _CHUNK]),
-                              avail, t1)
+        a, _, _ = _srv_decide_rows(
+            pol, state, jax.random.fold_in(key, c0), ptables, hyp,
+            jnp.asarray(ids_p[c0:c0 + _CHUNK]), avail, t1)
         acts.append(np.asarray(a))
     return np.concatenate(acts)[:n]
 

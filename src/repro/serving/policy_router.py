@@ -8,7 +8,9 @@ for SERVING: router state (net, optimizer, A^-1, outcome ring buffers)
 never leaves the device, requests carry only their sample id (features
 are gathered on device from the resident tables — zero host feature
 transfer per request), and each microbatch is ONE jitted decide call and
-ONE jitted update call regardless of batch width.
+ONE jitted update call regardless of batch width, each crossing between
+host and device once each way: its small inputs travel as one packed
+int32 buffer, the decide's outputs come back as one.
 
 Outcome buffers are a (T, S) ring: row = wave mod capacity, S = the
 microbatch width. `end_slice` runs the policy's chunked replay SGD +
@@ -32,6 +34,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.common.tracing import span
 from repro.sim.policies import (
@@ -64,9 +67,10 @@ def _srv_init(policy: BanditPolicy, key, tables, hyp, env_idx,
     return state, key, tables
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
-def _srv_decide(policy: BanditPolicy, state, key, tables, hyp, ids, avail,
-                t, fcfg=VANILLA_FORGETTING, train_chunks=1, batch_size=256):
+def _decide(policy: BanditPolicy, state, key, tables, hyp, ids, avail, t,
+            fcfg=VANILLA_FORGETTING, train_chunks=1, batch_size=256):
+    """The policy's decide over resident-table rows ``ids`` with ``key``
+    as given: ``(a, logp, aux)``."""
     batch = {"x_emb": tables["x_emb"][ids], "x_feat": tables["x_feat"][ids],
              "domain": tables["domain"][ids]}
     ctx = _ctx(tables, hyp, t=t, idx=ids, avail=avail, fcfg=fcfg,
@@ -75,18 +79,77 @@ def _srv_decide(policy: BanditPolicy, state, key, tables, hyp, ids, avail,
         return policy.decide(state, key, batch, ctx)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC,
-                   donate_argnames=("state", "env_idx"))
-def _srv_update(policy: BanditPolicy, state, env_idx, tables, hyp, row,
-                ids, a, r, mask, perm, aux, fcfg=VANILLA_FORGETTING,
-                train_chunks=1, batch_size=256):
+# offline scoring (experiments/ope.py): the decide with an explicit key
+# and unpacked outputs
+_srv_decide_rows = functools.partial(
+    jax.jit, static_argnames=_STATIC)(_decide)
+
+
+def _pack_decision(a, logp):
+    """int32 (2, S): the actions, then logp's float32 bits, so the host
+    reads both with one copy (:func:`_unpack_decision`). A policy's
+    scalar or zeros logp is broadcast to the actions' shape."""
+    lp = jnp.broadcast_to(jnp.asarray(logp, jnp.float32), a.shape)
+    return jnp.stack([a.astype(jnp.int32),
+                      lax.bitcast_convert_type(lp, jnp.int32)])
+
+
+def _unpack_decision(packed: np.ndarray, B: int):
+    """Host side of :func:`_pack_decision`: (action int32, logp float32)
+    of the first ``B`` rows, each its own array."""
+    return packed[0, :B].copy(), packed[1, :B].copy().view(np.float32)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _srv_decide(policy: BanditPolicy, state, key, tables, hyp, host, avail,
+                fcfg=VANILLA_FORGETTING, train_chunks=1, batch_size=256):
+    """One serving decide. ``host`` is the call's one int32 input: the
+    ids padded to S, then the step t. The carried ``key`` splits here,
+    once a call, as the scanned runner splits per decide (threefry is
+    integer arithmetic, so this equals a split on the host). Returns
+    ``(packed, aux, key)``: :func:`_pack_decision`'s buffer, the decide
+    aux (left on the device for the update) and the next carried key."""
+    key, k = jax.random.split(key)
+    a, logp, aux = _decide(policy, state, k, tables, hyp, host[:-1], avail,
+                           host[-1], fcfg=fcfg, train_chunks=train_chunks,
+                           batch_size=batch_size)
+    return _pack_decision(a, logp), aux, key
+
+
+def _pack_update(row: int, ids, served, rewards, learn, perm) -> np.ndarray:
+    """The update's one int32 input, of ``1 + 5 S`` entries: ``row``, then
+    the ids, served arms, rewards' float32 bits and learn mask's float32
+    bits, each zero-padded to ``S = perm.size``, then ``perm``
+    (:func:`_unpack_update` inverts it exactly)."""
+    S, B = perm.size, len(ids)
+    buf = np.zeros(1 + 5 * S, np.int32)
+    buf[0] = row
+    seg = buf[1:].reshape(5, S)
+    seg[0, :B] = ids
+    seg[1, :B] = served
+    seg[2, :B] = np.asarray(rewards, np.float32).view(np.int32)
+    seg[3, :B] = np.asarray(learn, np.float32).view(np.int32)
+    seg[4] = perm
+    return buf
+
+
+def _unpack_update(host):
+    """``(row, ids, a, r, mask, perm)`` of a :func:`_pack_update` buffer,
+    inside the program: int32 values sliced, float32 values bitcast."""
+    S = (host.shape[0] - 1) // 5
+    ids, a, r, mask, perm = host[1:].reshape(5, S)
+    return (host[0], ids, a, lax.bitcast_convert_type(r, jnp.float32),
+            lax.bitcast_convert_type(mask, jnp.float32), perm)
+
+
+def _update(policy: BanditPolicy, state, env_idx, tables, hyp, row, ids, a,
+            r, mask, perm, aux, fcfg=VANILLA_FORGETTING, train_chunks=1,
+            batch_size=256):
     """One microbatch's feedback write + A^-1 maintenance. ``perm``
     compacts learnable rows to the row prefix (ring rows keep the
     prefix-validity layout `_sample_valid` assumes); identity when
     nothing was remapped or shed, so the permuted gather is a no-op and
-    the sim-parity path stays bit-exact. ``state`` and ``env_idx`` are
-    donated — the router rebinds both from the outputs every wave, so
-    the ring buffers and A^-1 update in place."""
+    the sim-parity path stays bit-exact."""
     n = perm.shape[0]
     ids, a, r, mask = ids[perm], a[perm], r[perm], mask[perm]
     aux = jax.tree_util.tree_map(
@@ -100,6 +163,20 @@ def _srv_update(policy: BanditPolicy, state, env_idx, tables, hyp, row,
     with jax.named_scope("router.update"):
         state = policy.update(state, batch, a, r, ctx, aux)
     return state, env_idx
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC,
+                   donate_argnames=("state", "env_idx"))
+def _srv_update(policy: BanditPolicy, state, env_idx, tables, hyp, host,
+                aux, fcfg=VANILLA_FORGETTING, train_chunks=1,
+                batch_size=256):
+    """:func:`_update` fed by the call's one int32 input ``host``
+    (:func:`_pack_update`). ``state`` and ``env_idx`` are donated — the
+    router rebinds both from the outputs every wave, so the ring buffers
+    and A^-1 update in place."""
+    return _update(policy, state, env_idx, tables, hyp,
+                   *_unpack_update(host), aux, fcfg=fcfg,
+                   train_chunks=train_chunks, batch_size=batch_size)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC,
@@ -173,7 +250,8 @@ class DevicePolicyRouter:
     ``slice_width`` is the microbatch capacity S (decide pads shorter
     batches); ``capacity_slices`` is the ring depth T. The PRNG
     discipline mirrors the scanned runner exactly: one split per decide
-    call, train splitting further from the carried stream.
+    call, run inside the decide program, which returns the carried key;
+    train splits further from the carried stream.
 
     ``max_train_lag`` bounds the zero-sync train overlap (DESIGN.md
     §15.2). 0 (default): ``end_slice`` blocks until train + rebuild
@@ -294,16 +372,17 @@ class DevicePolicyRouter:
 
     def warmup(self) -> None:
         """Compile both decide variants (mask-free fast path and masked
-        outage path) with a throwaway key, so jit compile time never
-        lands in a storm's decide-latency samples. State and PRNG stream
-        are untouched — compilation caches by shape, not value."""
-        k, _ = jax.random.split(jax.random.PRNGKey(0))
-        ids = jnp.zeros(self.S, jnp.int32)
+        outage path) at their packed signatures with a throwaway key
+        and input, so jit compile time never lands in a storm's
+        decide-latency samples. State and PRNG stream are untouched —
+        compilation caches by shape, not value, and the key the program
+        returns is dropped."""
+        host = jax.device_put(np.zeros(self.S + 1, np.int32))
         for av in (None, jnp.ones(self.num_actions, jnp.float32)):
-            a, _, _ = _srv_decide(self.policy, self.state, k, self.tables,
-                                  self.hyp, ids, av, jnp.int32(0),
-                                  **self._statics())
-            jax.block_until_ready(a)
+            packed, _, _ = _srv_decide(
+                self.policy, self.state, jax.random.PRNGKey(0), self.tables,
+                self.hyp, host, av, **self._statics())
+            jax.block_until_ready(packed)
 
     # ----------------------------------------------------------- DECIDE --
     def decide(self, x_emb=None, x_feat=None, domain=None, *,
@@ -311,34 +390,36 @@ class DevicePolicyRouter:
         """Decide for a microbatch of replay sample ids. ``avail`` is the
         engine's live arm-health mask ((K,) float, 1 = up); None or
         all-up takes the stationary fast trace (bit-identical to the sim
-        scan's no-scenario path)."""
+        scan's no-scenario path). The fast path crosses between host
+        and device once each way: the ids and step go over as one
+        buffer, the program splits the carried key, and action and logp
+        come back as one; a mask with an arm down is one more put."""
         ids = np.asarray(sample_idx, np.int64).reshape(-1)
         B = ids.size
         with span("router.decide", rows=B):
-            with span("router.decide.prep"):
+            with span("router.decide.prep") as prep:
                 if not 0 < B <= self.S:
                     raise ValueError(
                         f"microbatch size {B} outside (0, {self.S}]")
-                ids_pad = np.zeros(self.S, np.int32)
-                ids_pad[:B] = ids
+                host = np.zeros(self.S + 1, np.int32)
+                host[:B] = ids
+                host[-1] = min(self.slices, 1)
                 av = None
                 if avail is not None and not np.all(np.asarray(avail) > 0):
-                    av = jnp.asarray(avail, jnp.float32)
+                    av = jax.device_put(np.asarray(avail, np.float32))
                 if self._pending:
                     # overlapped mode: tick the train pipeline (SGD ->
                     # rebuild -> commit) — decide reads the freshest
                     # COMMITTED state
                     self._advance()
-                self._key, k = jax.random.split(self._key)
-                ids_dev = jnp.asarray(ids_pad)
-                t = jnp.int32(min(self.slices, 1))
+                host = jax.device_put(host)
+                prep.set_metadata(puts=1 + (av is not None))
             with span("router.decide.launch"):
-                a, logp, aux = _srv_decide(
-                    self.policy, self.state, k, self.tables, self.hyp,
-                    ids_dev, av, t, **self._statics())
-            with span("router.decide.fetch"):
-                action = np.asarray(a)[:B].astype(np.int32)
-                logp = np.asarray(logp)[:B].astype(np.float32)
+                packed, aux, self._key = _srv_decide(
+                    self.policy, self.state, self._key, self.tables,
+                    self.hyp, host, av, **self._statics())
+            with span("router.decide.fetch", gets=1):
+                action, logp = _unpack_decision(jax.device_get(packed), B)
         return {"action": action, "logp": logp, "ids": ids, "aux": aux,
                 "n": B}
 
@@ -348,11 +429,12 @@ class DevicePolicyRouter:
         """Absorb one decided microbatch's outcomes into the ring.
         ``served`` are the arms actually run (== decided unless a
         fallback remapped); ``learn_mask`` marks rows to learn from
-        (sheds and remaps excluded by the engine). Returns the number of
-        rows learned."""
+        (sheds and remaps excluded by the engine). The row, ids,
+        outcomes, mask and row order go to the device as one buffer.
+        Returns the number of rows learned."""
         B = decision["n"]
         with span("router.update", rows=B):
-            with span("router.update.prep"):
+            with span("router.update.prep", puts=1):
                 served = np.asarray(served, np.int32).reshape(-1)
                 rewards = np.asarray(rewards, np.float32).reshape(-1)
                 assert served.size == B and rewards.size == B
@@ -364,17 +446,13 @@ class DevicePolicyRouter:
                 order = np.argsort(~learn, kind="stable")
                 perm = np.concatenate(
                     [order, np.arange(B, self.S)]).astype(np.int32)
-                pad = lambda v, dt: jnp.asarray(np.concatenate(  # noqa: E731
-                    [v, np.zeros(self.S - B, dt)]).astype(dt))
                 row = self.wave % self.T
-                args = (jnp.int32(row), pad(decision["ids"], np.int32),
-                        pad(served, np.int32), pad(rewards, np.float32),
-                        pad(learn.astype(np.float32), np.float32),
-                        jnp.asarray(perm))
+                host = jax.device_put(_pack_update(
+                    row, decision["ids"], served, rewards, learn, perm))
             with span("router.update.launch"):
                 self.state, self._env_idx = _srv_update(
                     self.policy, self.state, self._env_idx, self.tables,
-                    self.hyp, *args, decision["aux"], **self._statics())
+                    self.hyp, host, decision["aux"], **self._statics())
         self._counts[row] = int(learn.sum())
         self.wave += 1
         if self.log_capacity and learn.any():

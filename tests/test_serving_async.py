@@ -10,9 +10,11 @@ continuous batching, admission control, and the two parity pins —
 
 Plus snapshot/restore round-trips: serve N, snapshot, kill, restore,
 serve N more — identical to the uninterrupted run."""
+import collections
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,8 +29,10 @@ from repro.serving import (
     RoutedServingPool,
     ServingEngine,
 )
+from repro.serving import policy_router as pr
 from repro.sim import DeviceReplayEnv, make_policy, run_policy_device
 from repro.sim.engine import _tables
+from repro.sim.policies import POLICIES
 from serving_fakes import FakeRouter
 
 TOK = np.arange(1, 5, dtype=np.int32)
@@ -138,6 +142,123 @@ def test_device_router_bit_parity_with_sim_scan():
     for i, (a, b) in enumerate(zip(ref, got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f"state leaf {i}")
+
+
+
+# ------------------------------------------ one crossing each way --
+def _small_router(name, env):
+    cfg = UtilityNetConfig(emb_dim=env.x_emb.shape[1], num_actions=env.K,
+                           d_text=16, d_hidden=16, d_last=8)
+    pol, hyp = make_policy(name, env, cfg)
+    return DevicePolicyRouter(pol, hyp, _tables(env), seed=3,
+                              slice_width=16, capacity_slices=3,
+                              batch_size=8, train_chunks=1)
+
+
+def _same_bits(a, b, what):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape, what
+    assert a.tobytes() == b.tobytes(), what
+
+
+@pytest.mark.parametrize("down", [None, 1], ids=["all_up", "arm1_down"])
+@pytest.mark.parametrize("name", sorted(POLICIES))
+def test_packed_router_calls_are_exact(name, down):
+    """The packed decide returns the same actions, logp, aux and next
+    key, bit for bit, as the decide program run alone on the unpacked
+    ids with the key split on the host; an update fed edge values (-0.0,
+    a subnormal, 1e30, 0/1 masks, a remap that reorders the rows)
+    through the one packed buffer leaves the same state as the unpacked
+    arrays."""
+    _, env = _replay_env()
+    r = _small_router(name, env)
+    st = dict(fcfg=r.fcfg, train_chunks=r.train_chunks,
+              batch_size=r.batch_size)
+    B = 11                                   # < S: the padding is live
+    ids = (np.arange(B) * 5) % 48
+    avail = None if down is None else np.where(
+        np.arange(env.K) == down, 0.0, 1.0)
+
+    key0 = r._key
+    dec = r.decide(sample_idx=ids, avail=avail)
+    nxt, k = jax.random.split(key0)
+    a, logp, aux = pr._srv_decide_rows(
+        r.policy, r.state, k, r.tables, r.hyp,
+        jnp.asarray(np.pad(ids, (0, r.S - B)).astype(np.int32)),
+        None if avail is None else jnp.asarray(avail, jnp.float32),
+        jnp.int32(0), **st)
+    _same_bits(dec["action"], np.asarray(a)[:B].astype(np.int32), "action")
+    _same_bits(dec["logp"], np.broadcast_to(
+        np.asarray(logp, np.float32), a.shape)[:B], "logp")
+    _same_bits(r._key, nxt, "key")
+    for i, (x, y) in enumerate(zip(jax.tree_util.tree_leaves(dec["aux"]),
+                                   jax.tree_util.tree_leaves(aux))):
+        _same_bits(x, y, f"aux leaf {i}")
+
+    # the update's transport: every value makes an exact round trip
+    tiny = np.float32(1e-45)                 # the least subnormal
+    rewards = np.resize(np.array([-0.0, tiny, 1e30, 0.5], np.float32), B)
+    served = dec["action"].copy()
+    served[3] = (served[3] + 1) % env.K      # a fallback remap
+    mask = np.resize(np.array([1, 1, 0, 1, 1], bool), B)
+    learn = mask & (served == dec["action"])
+    perm = np.concatenate([np.argsort(~learn, kind="stable"),
+                           np.arange(B, r.S)]).astype(np.int32)
+    assert not np.array_equal(perm, np.arange(r.S))
+    row = r.wave % r.T
+    buf = pr._pack_update(row, ids, served, rewards, learn, perm)
+    pad = lambda v, dt: np.pad(np.asarray(v, dt), (0, r.S - B))  # noqa: E731
+    args = (np.int32(row), pad(ids, np.int32), pad(served, np.int32),
+            pad(rewards, np.float32), pad(learn, np.float32), perm)
+    for i, (x, y) in enumerate(zip(jax.jit(pr._unpack_update)(buf), args)):
+        _same_bits(x, y, f"unpacked field {i}")
+
+    before = jax.tree_util.tree_map(jnp.copy, (r.state, r._env_idx))
+    r.update_wave(dec, served, rewards, learn_mask=mask)
+    want = jax.jit(pr._update, static_argnames=pr._STATIC)(
+        r.policy, *before, r.tables, r.hyp, *map(jnp.asarray, args),
+        dec["aux"], **st)
+    got = jax.tree_util.tree_leaves((r.state, r._env_idx))
+    want = jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want)
+    for i, (x, y) in enumerate(zip(got, want)):
+        _same_bits(x, y, f"state leaf {i}")
+
+
+def test_router_calls_cross_once_each_way(monkeypatch):
+    """After warm-up the fast path makes one put per decide and update,
+    one get per decide, and no key split on the host; any other
+    host-to-device transfer is refused by the transfer guard. A decide
+    with an arm down puts its mask too."""
+    henv, env = _replay_env()
+    r = _small_router("neuralucb", env)
+    reward = np.asarray(env.reward)
+    ids = henv.slice_batch(0)["idx"]
+    r.warmup()
+    dec = r.decide(sample_idx=ids)           # compiles the update
+    r.update_wave(dec, dec["action"], reward[ids, dec["action"]])
+
+    calls = collections.Counter()
+
+    def counted(what, fn):
+        def shim(*a, **kw):
+            calls[what] += 1
+            return fn(*a, **kw)
+        return shim
+
+    monkeypatch.setattr(jax.random, "split",
+                        counted("split", jax.random.split))
+    monkeypatch.setattr(jax, "device_put", counted("put", jax.device_put))
+    monkeypatch.setattr(jax, "device_get", counted("get", jax.device_get))
+    with jax.transfer_guard_host_to_device("disallow"):
+        for _ in range(3):
+            dec = r.decide(sample_idx=ids)
+            assert calls == {"put": 1, "get": 1}
+            r.update_wave(dec, dec["action"], reward[ids, dec["action"]])
+            assert calls == {"put": 2, "get": 1}
+            calls.clear()
+        r.decide(sample_idx=ids, avail=np.array([1.0, 0.0]))
+        assert calls == {"put": 2, "get": 1}
 
 
 def test_async_train_overlap_staleness_bounded():

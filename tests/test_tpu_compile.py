@@ -141,10 +141,10 @@ def test_serving_router_decide_compiles(one_chip, monkeypatch):
                                    hyp, jnp.zeros((4, S), jnp.int32))
     monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
     monkeypatch.setattr(KB, "on_tpu", lambda: True)
-    args = _shapes((state, key, tables, hyp, jnp.zeros((S,), jnp.int32)),
-                   one_chip)
-    text = _srv_decide.lower(pol, *args, None, jnp.int32(1)).compile() \
-        .as_text()
+    # the call's one int32 input: the ids padded to S, then the step t
+    args = _shapes((state, key, tables, hyp,
+                    jnp.zeros((S + 1,), jnp.int32)), one_chip)
+    text = _srv_decide.lower(pol, *args, None).compile().as_text()
     assert "tpu_custom_call" in text
 
 

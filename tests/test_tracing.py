@@ -37,14 +37,14 @@ def _scopes_in(compiled) -> set:
 
 def _programs(env, r):
     key = jax.random.PRNGKey(1)
-    ids = jnp.zeros(r.S, jnp.int32)
+    host = jnp.zeros(r.S + 1, jnp.int32)    # the ids, then t
     st = dict(fcfg=r.fcfg, train_chunks=r.train_chunks,
               batch_size=r.batch_size)
     cum0 = jnp.zeros(r.T + 1, jnp.int32)
     return {
         "decide": (pr._srv_decide.lower(
-            r.policy, r.state, key, r.tables, r.hyp, ids, None,
-            jnp.int32(0), **st), {"router.decide"}),
+            r.policy, r.state, key, r.tables, r.hyp, host, None, **st),
+            {"router.decide"}),
         "train": (pr._srv_train.lower(
             r.policy, r.state, key, r.tables, r.hyp, r._env_idx, cum0,
             jnp.int32(0), **st), {"router.train_sgd", "router.rebuild"}),
@@ -66,12 +66,12 @@ def test_update_program_carries_its_scope(router):
     env, r = router
     dec = r.decide(sample_idx=np.arange(r.S))
     n = r.S
-    z = jnp.zeros(n, jnp.int32)
+    z = np.zeros(n, np.int32)
+    host = jnp.asarray(pr._pack_update(0, z, z, np.zeros(n), np.ones(n),
+                                       np.arange(n, dtype=np.int32)))
     lowered = pr._srv_update.lower(
-        r.policy, r.state, r._env_idx, r.tables, r.hyp, jnp.int32(0), z,
-        z, jnp.zeros(n, jnp.float32), jnp.ones(n, jnp.float32),
-        jnp.arange(n, dtype=jnp.int32), dec["aux"], fcfg=r.fcfg,
-        train_chunks=r.train_chunks, batch_size=r.batch_size)
+        r.policy, r.state, r._env_idx, r.tables, r.hyp, host, dec["aux"],
+        fcfg=r.fcfg, train_chunks=r.train_chunks, batch_size=r.batch_size)
     assert _scopes_in(lowered.compile()) == {"router.update"}
 
 

@@ -7,13 +7,18 @@ module builds the pool's engine list:
   — REAL jitted decode steps through ``repro.serving.engine.ServingEngine``
   (with ``reduced_decode=True`` the config's CPU-runnable ``reduced()``
   variant: still the real decode program, smoke-test weights);
+* an arm named in ``ArmPoolSpec.local`` gets a :class:`DecodeArmEngine`
+  at its chip share (``ModelConfig.chip_share``: the layers and experts
+  one chip of its deployment holds, every width as published), whatever
+  its size;
 * every other arm gets a :class:`RooflineArmEngine` — a clocked sleep of
   the pool's roofline step time per decode step, so the storm's wall
   and per-arm service times reflect the declared hardware without
   materializing 100B-scale weights.
 
 Both expose the engine protocol the async engine expects:
-``generate(tokens, max_new) -> (new_tokens (B, max_new), steps)``.
+``generate(tokens, max_new, lengths) -> (new_tokens (B, max_new), steps)``,
+``tokens`` right-padded and ``lengths`` each row's prompt length.
 """
 from __future__ import annotations
 
@@ -23,7 +28,10 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from repro.armpool.compile import CompiledArmPool
+from repro.armpool.pool import canonical_arm
+from repro.common.tracing import span
 from repro.configs import get_config
+from repro.serving.batcher import left_align
 
 
 class RooflineArmEngine:
@@ -41,7 +49,8 @@ class RooflineArmEngine:
         self.decode_steps = 0
         self.real_decode = False
 
-    def generate(self, tokens, max_new: int = 8) -> Tuple[np.ndarray, int]:
+    def generate(self, tokens, max_new: int = 8, lengths=None
+                 ) -> Tuple[np.ndarray, int]:
         B = np.asarray(tokens).shape[0]
         steps = int(max_new)
         wait = self.step_s * self.latency_scale * steps
@@ -61,13 +70,17 @@ class DecodeArmEngine:
     stage's batch cap): rows are padded up to it, so the decode step
     compiles once, in the warm-up, whatever batch the batcher forms —
     not once per batch size on the request path. Rows decode
-    independently, so padding never changes a real row's tokens."""
+    independently, so padding never changes a real row's tokens, and
+    rows of different prompt lengths decode left-padded (see
+    ``repro.serving.engine``). ``index``: the arm's place in its pool,
+    the stat ``arm`` of the span ``arm.generate``."""
 
     def __init__(self, name: str, cfg, *, max_batch: int, max_seq: int = 64,
-                 seed: int = 0, warm: bool = True):
+                 seed: int = 0, warm: bool = True, index: int = -1):
         from repro.serving.engine import ServingEngine
 
         self.name = name
+        self.index = int(index)
         self.cfg = cfg
         self.max_batch = int(max_batch)
         self.max_seq = max_seq
@@ -78,32 +91,47 @@ class DecodeArmEngine:
             self.generate(np.ones((1, 1), np.int32), max_new=2)
             self.decode_steps = 0
 
-    def generate(self, tokens, max_new: int = 8) -> Tuple[np.ndarray, int]:
+    def generate(self, tokens, max_new: int = 8, lengths=None,
+                 on_step=None, on_cache=None) -> Tuple[np.ndarray, int]:
+        """``tokens`` (B, S) right-padded, ``lengths`` each row's prompt
+        length (None: all S). ``on_step``, ``on_cache``: as
+        ``ServingEngine.generate``, over all ``max_batch`` rows, the rows
+        left-padded."""
         toks = np.asarray(tokens, np.int64)
-        B = toks.shape[0]
+        B, S = toks.shape
         if B > self.max_batch:
             raise ValueError(f"{self.name}: batch of {B} rows exceeds the "
                              f"engine's pinned width {self.max_batch}")
         # clamp into the (possibly reduced) vocab and cache budget
         toks = np.clip(toks, 0, self.cfg.vocab_size - 1)
+        toks, start = left_align(toks, S if lengths is None else lengths)
         keep = max(1, self.max_seq - max_new - 1)
-        toks = toks[:, -keep:]
+        if S > keep:
+            toks, start = toks[:, -keep:], np.maximum(start - (S - keep), 0)
         padded = np.zeros((self.max_batch, toks.shape[1]), np.int32)
         padded[:B] = toks
-        # host tokens: prefill slices them per step on the host, so no
-        # eager device slice compiles per prompt position
-        new, steps = self.engine.generate(padded, max_new=max_new)
+        starts = np.zeros(self.max_batch, np.int32)
+        starts[:B] = start
+        with span("arm.generate", arm=self.index, rows=B,
+                  prompt_max=toks.shape[1]):
+            # host tokens: prefill slices them per step on the host, so
+            # no eager device slice compiles per prompt position
+            new, steps = self.engine.generate(padded, max_new=max_new,
+                                              start=starts, on_step=on_step,
+                                              on_cache=on_cache)
+            new = np.asarray(new)[:B]
         # prefill replays the prompt through width-1 decode steps, so
         # the real dispatch count per call is prompt + (max_new - 1)
         self.decode_steps += toks.shape[1] + steps
-        return np.asarray(new)[:B], steps
+        return new, steps
 
 
-def build_arm_engines(pool: CompiledArmPool, aspec, *, max_batch: int
-                      ) -> Tuple[List, Dict[str, object]]:
+def build_arm_engines(pool: CompiledArmPool, aspec, *, max_batch: int,
+                      seed: int = None) -> Tuple[List, Dict[str, object]]:
     """Pool -> per-arm engine list (+ an info block for the artifact).
     ``max_batch`` is the largest batch the serve stage hands an engine
-    (the real-decode engines pin their batch width to it).
+    (the real-decode engines pin their batch width to it). ``seed``: the
+    real-decode engines' weights (None: from the pool's checksum).
 
     Raises on a pool/spec K disagreement (the engines MUST line up
     with the pool's arm order — the router's arm ids index this list).
@@ -111,14 +139,19 @@ def build_arm_engines(pool: CompiledArmPool, aspec, *, max_batch: int
     pool.validate_against(len(pool.arms), what="engine list")
     engines: List = []
     real, clocked = [], []
+    local = {canonical_arm(a): cut for a, cut in aspec.local}
+    if seed is None:
+        seed = int(pool.checksum % 997)
     for a, name in enumerate(pool.arms):
         params = float(pool.params_b[a]) * 1e9
-        if params <= aspec.serve_real_max_params:
+        if name in local or params <= aspec.serve_real_max_params:
             cfg = get_config(name)
-            if aspec.reduced_decode:
+            if name in local:
+                cfg = cfg.chip_share(**dict(local[name]))
+            elif aspec.reduced_decode:
                 cfg = cfg.reduced()
             engines.append(DecodeArmEngine(name, cfg, max_batch=max_batch,
-                                           seed=int(pool.checksum % 997)))
+                                           seed=seed, index=a))
             real.append(name)
         else:
             engines.append(RooflineArmEngine(
@@ -134,6 +167,7 @@ def build_arm_engines(pool: CompiledArmPool, aspec, *, max_batch: int
                          "d_model": e.cfg.d_model,
                          "vocab_size": e.cfg.vocab_size,
                          "ssm_state": e.cfg.ssm_state,
+                         "experts_held": e.cfg.local_experts,
                          "max_batch": e.max_batch}
                 for e in engines if e.real_decode},
             "latency_scale": float(aspec.latency_scale)}

@@ -29,10 +29,16 @@ class ModelConfig:
     head_dim: int = 0  # 0 -> d_model // num_heads
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0  # experts the router scores (its output width)
     experts_per_token: int = 0
     moe_every: int = 1  # an MoE FFN every N layers (jamba: 2); dense FFN else
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25  # 0: dropless (no token is dropped)
+    shared_expert_ff: int = 0  # width of an always-on shared expert; 0: none
+    # expert parallelism's share: this chip holds experts
+    # [expert_first, expert_first + experts_held) and computes their part
+    # of each expert layer's output (0: it holds all ``num_experts``)
+    experts_held: int = 0
+    expert_first: int = 0
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0
@@ -41,8 +47,11 @@ class ModelConfig:
     ssm_chunk: int = 256
     ssm_conv_width: int = 4
 
-    # --- hybrid (jamba): one attention layer per `attn_every` layers ---
+    # --- hybrid: one attention layer per `attn_every` layers, at position
+    # `attn_index` of each period (-1: the last, as in jamba's superblock;
+    # granite-4.0-h: 5 of 10) ---
     attn_every: int = 0
+    attn_index: int = -1
 
     # --- attention pattern ---
     sliding_window: int = 0  # 0 = full attention
@@ -65,7 +74,16 @@ class ModelConfig:
     # --- misc ---
     remat: str = "layer"  # activation checkpointing for train: none|layer
     qk_norm: bool = False
+    use_rope: bool = True  # False: no position embedding (NoPE)
     rope_theta: float = 10000.0
+    # granite's multipliers: x0 = embedding_multiplier * E[t]; each
+    # residual branch is scaled by residual_multiplier; attention scores
+    # by attention_multiplier (0: 1/sqrt(head_dim)); logits are divided
+    # by logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
@@ -82,6 +100,32 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights this configuration holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def attn_position(self) -> int:
+        """Position of the attention mixer within a hybrid period."""
+        return self.attn_index % self.attn_every
+
+    def layer_is_attn(self, i: int) -> bool:
+        """Whether layer ``i``'s mixer is attention (else Mamba2)."""
+        if self.arch_type == "ssm":
+            return False
+        if self.attn_every:
+            return i % self.attn_every == self.attn_position
+        return True
+
+    def layer_is_moe(self, i: int) -> bool:
+        return self.is_moe and i % self.moe_every == self.moe_every - 1
+
+    @property
+    def attention_scale(self) -> float:
+        return self.attention_multiplier or \
+            1.0 / self.resolved_head_dim ** 0.5
 
     @property
     def supports_long_decode(self) -> bool:
@@ -126,11 +170,19 @@ class ModelConfig:
         if self.num_experts:
             upd["num_experts"] = min(self.num_experts, 4)
             upd["experts_per_token"] = min(self.experts_per_token, 2)
-            # no capacity drops at toy scale: keeps decode == forward exactly
-            upd["moe_capacity_factor"] = 8.0
+            if self.moe_capacity_factor > 0:
+                # no capacity drops at toy scale: keeps decode == forward
+                upd["moe_capacity_factor"] = 8.0
+        if self.shared_expert_ff:
+            upd["shared_expert_ff"] = min(self.shared_expert_ff, 512)
         if self.num_encoder_layers:
             upd["num_encoder_layers"] = 2
-        if self.attn_every:
+        if self.attn_every and self.attn_position != self.attn_every - 1:
+            # one period with attention inside it (mamba, attn, mamba)
+            upd["attn_every"] = 3
+            upd["attn_index"] = 1
+            upd["num_layers"] = 3
+        elif self.attn_every:
             upd["attn_every"] = 2
             upd["num_layers"] = 4  # two (1 mamba + 1 attn) super-blocks
         if self.cross_attn_every:
@@ -141,6 +193,32 @@ class ModelConfig:
             upd["sliding_window"] = min(self.sliding_window or 128, 128)
         elif self.sliding_window:
             upd["sliding_window"] = 128
+        return dataclasses.replace(self, **upd)
+
+    def chip_share(self, num_layers: int, experts_held: int = 0,
+                   expert_first: int = 0) -> "ModelConfig":
+        """What one chip of an expert-parallel, pipelined deployment
+        holds: the first ``num_layers`` layers (a pipeline stage; whole
+        periods of a hybrid's pattern) and ``experts_held`` of each
+        expert layer's experts from ``expert_first``. Every width, the
+        router's ``num_experts`` outputs and its top-k stay as
+        published."""
+        if not 0 < num_layers <= self.num_layers:
+            raise ValueError(f"{self.name}: {num_layers} layers of "
+                             f"{self.num_layers}")
+        if self.attn_every and num_layers % self.attn_every:
+            raise ValueError(f"{self.name}: {num_layers} layers is not a "
+                             f"whole number of {self.attn_every}-layer "
+                             f"periods")
+        if experts_held and not (
+                self.is_moe and 0 <= expert_first
+                and expert_first + experts_held <= self.num_experts):
+            raise ValueError(f"{self.name}: experts [{expert_first}, "
+                             f"{expert_first + experts_held}) of "
+                             f"{self.num_experts}")
+        upd = {"num_layers": num_layers}
+        if experts_held:
+            upd.update(experts_held=experts_held, expert_first=expert_first)
         return dataclasses.replace(self, **upd)
 
 
@@ -156,16 +234,20 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         return 3 * d * cfg.d_ff  # gate/up/down (SwiGLU)
 
     def moe_ffn() -> int:
-        n = cfg.experts_per_token if active_only else cfg.num_experts
-        return n * 3 * d * cfg.d_ff + d * cfg.num_experts  # experts + router
+        n = cfg.experts_per_token if active_only else cfg.local_experts
+        shared = 3 * d * cfg.shared_expert_ff
+        # experts + shared expert + router
+        return n * 3 * d * cfg.d_ff + shared + d * cfg.num_experts
 
     def mamba_params() -> int:
         d_inner = cfg.ssm_expand * d
         nheads = d_inner // cfg.ssm_head_dim
         in_proj = d * (2 * d_inner + 2 * cfg.ssm_state + nheads)
-        conv = cfg.ssm_conv_width * (d_inner + 2 * cfg.ssm_state)
+        conv_dim = d_inner + 2 * cfg.ssm_state
+        conv = (cfg.ssm_conv_width + 1) * conv_dim       # weights + bias
         out = d_inner * d
-        return in_proj + conv + out + 2 * nheads  # + A_log, D
+        # + A_log, D, dt_bias and the gated norm's weight
+        return in_proj + conv + out + 3 * nheads + d_inner
 
     total = cfg.vocab_size * d  # embed
     if not cfg.tie_embeddings:
@@ -177,11 +259,8 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         return total
 
     for i in range(cfg.num_layers):
-        mixer_is_attn = True
-        if cfg.attn_every:
-            mixer_is_attn = (i % cfg.attn_every) == (cfg.attn_every - 1)
-        total += attn_params() if mixer_is_attn else mamba_params()
-        if cfg.is_moe and (i % cfg.moe_every) == (cfg.moe_every - 1):
+        total += attn_params() if cfg.layer_is_attn(i) else mamba_params()
+        if cfg.layer_is_moe(i):
             total += moe_ffn()
         elif cfg.d_ff:
             total += dense_ffn()

@@ -49,13 +49,28 @@ SPANS = {
     "router.train.wait": "blocking on the train (max_train_lag 0)",
     "router.train.commit": "pipeline tick and forced commits before a "
                            "new train (max_train_lag > 0)",
+    # armpool/serving.py, serving/engine.py
+    "arm.generate": "one arm batch through a real decode engine, tokens "
+                    "back on the host (stats arm: the arm's pool index, "
+                    "rows, prompt_max: the batch's prompt width)",
+    "arm.prefill": "dispatch of the prompt's width-1 decode steps (stat "
+                   "steps)",
+    "arm.decode": "dispatch of the generated tokens' decode steps and "
+                  "their sampling (stat steps)",
 }
 
 SCOPES = {
+    # serving/policy_router.py
     "router.decide": "the policy's decide",
     "router.update": "feedback write and A^-1 update",
     "router.train_sgd": "replay SGD",
     "router.rebuild": "A^-1 rebuild over the replay ring",
+    # models/model.py: inside an arm's decode step (and its forward)
+    "arm.mamba": "a Mamba2 mixer",
+    "arm.attention": "a self-attention mixer",
+    "arm.moe": "an expert layer: routing, the held experts, the shared "
+               "expert",
+    "arm.head": "final norm and logits",
 }
 
 

@@ -1,5 +1,5 @@
-"""Architecture registry: the 10 assigned pool members + the paper's own
-router config. Each module defines CONFIG (exact assigned spec)."""
+"""Architecture registry: the pool's members + the paper's own router
+config. Each module defines CONFIG (exact assigned spec)."""
 from __future__ import annotations
 
 import importlib
@@ -18,6 +18,7 @@ ARCH_IDS: List[str] = [
     "llama3_2_3b",
     "mistral_nemo_12b",
     "llama3_2_vision_11b",
+    "granite_4_0_h_small",
 ]
 
 _ALIASES = {
@@ -31,6 +32,7 @@ _ALIASES = {
     "llama3.2-3b": "llama3_2_3b",
     "mistral-nemo-12b": "mistral_nemo_12b",
     "llama-3.2-vision-11b": "llama3_2_vision_11b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 

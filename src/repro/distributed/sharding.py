@@ -349,6 +349,8 @@ def cache_partition_specs(cfg: ModelConfig, shape: InputShape, mesh,
         nd = len(leaf.shape)
         if name in ("pos", "offset"):
             return P()
+        if name == "start":
+            return P(b)
         if name in ("k", "v"):
             # (L?, B, KV, S, hd)
             lead = (None,) * (nd - 4)

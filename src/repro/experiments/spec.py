@@ -230,6 +230,11 @@ class ArmPoolSpec:
       CPU-runnable reduced variant); larger arms sleep their roofline
       step time scaled by ``latency_scale``; ``max_new`` tokens are
       generated per request.
+    * ``local`` — ``(arm, ((key, value), ...))``: arms served on this
+      host whatever their size, each at the chip share the pairs give
+      (``ModelConfig.chip_share``: ``num_layers``, ``experts_held``,
+      ``expert_first``). The tables still cost the arm from its full
+      published configuration.
     """
 
     arms: Tuple[str, ...] = ()
@@ -244,11 +249,16 @@ class ArmPoolSpec:
     reduced_decode: bool = True
     latency_scale: float = 1.0
     max_new: int = 4
+    local: Tuple[Tuple[str, Tuple[Tuple[str, int], ...]], ...] = ()
 
     def __post_init__(self):
         if not self.arms:
             raise ValueError("ArmPoolSpec: no arms (list at least one "
                              "repro.configs arch id)")
+        stray = sorted({a for a, _ in self.local} - set(self.arms))
+        if stray:
+            raise ValueError(f"ArmPoolSpec: local arm(s) {stray} are not "
+                             f"in arms {self.arms}")
         if self.decode_batch <= 0 or self.context <= 0:
             raise ValueError("ArmPoolSpec: decode_batch and context "
                              "must be positive")
@@ -477,6 +487,11 @@ def spec_to_json(spec: ExperimentSpec) -> Dict[str, Any]:
         ap = dataclasses.asdict(spec.armpool)
         ap["arms"] = list(spec.armpool.arms)
         ap["mapping"] = [[a, m] for a, m in spec.armpool.mapping]
+        # emit-only-when-set: pre-PR-15 pool specs keep their hashes
+        ap.pop("local")
+        if spec.armpool.local:
+            ap["local"] = [[a, [list(kv) for kv in cut]]
+                           for a, cut in spec.armpool.local]
         j["armpool"] = ap
     return j
 
@@ -607,6 +622,9 @@ def spec_from_json(d: Dict[str, Any]) -> ExperimentSpec:
                 else (v,)
         if "mapping" in a:
             a["mapping"] = tuple((arm, m) for arm, m in a["mapping"])
+        if "local" in a:
+            a["local"] = tuple((arm, tuple((k, v) for k, v in cut))
+                               for arm, cut in a["local"])
         kw["armpool"] = _strict(ArmPoolSpec, a)
     return ExperimentSpec(**kw)
 

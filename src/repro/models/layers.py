@@ -131,7 +131,7 @@ def _attn_mask(q_pos: jax.Array, k_pos: jax.Array, causal: bool,
     return m
 
 
-def _sdpa_grouped(q, k, v, mask) -> jax.Array:
+def _sdpa_grouped(q, k, v, mask, scale=None) -> jax.Array:
     """Decode-path attention (q_len small): q: (B,Q,H,D); k,v in the KV
     cache's NATIVE layout (B,KV,S,D) — no transpose, so the multi-GB cache
     is never copied for a layout change; GQA via grouped reshape so it is
@@ -141,7 +141,7 @@ def _sdpa_grouped(q, k, v, mask) -> jax.Array:
     G = H // KV
     qg = q.reshape(B, Q, KV, G, D)
     scores = jnp.einsum("bqkgd,bksd->bkgqs", qg, k).astype(jnp.float32)
-    scores *= 1.0 / math.sqrt(D)
+    scores *= scale or 1.0 / math.sqrt(D)
     if mask.ndim == 2:
         mask = mask[None]
     scores = jnp.where(mask[:, None, None], scores, -1e30)
@@ -150,7 +150,7 @@ def _sdpa_grouped(q, k, v, mask) -> jax.Array:
     return out.reshape(B, Q, H, D)
 
 
-def _sdpa_folded(q, k, v, mask) -> jax.Array:
+def _sdpa_folded(q, k, v, mask, scale=None) -> jax.Array:
     """Train/prefill attention with GQA groups FOLDED into the head dim and
     explicit sharding constraints on the score tensor. Without this, SPMD
     propagation computes (B, H, S, T) scores replicated over the model axis
@@ -168,7 +168,7 @@ def _sdpa_folded(q, k, v, mask) -> jax.Array:
     k = shard(k, "batch", None, "heads", "head_dim")
     v = shard(v, "batch", None, "heads", "head_dim")
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores *= 1.0 / math.sqrt(D)
+    scores *= scale or 1.0 / math.sqrt(D)
     scores = shard(scores, "batch", "heads", "attn_q_seq", None)
     if mask.ndim == 2:
         mask = mask[None]
@@ -179,7 +179,7 @@ def _sdpa_folded(q, k, v, mask) -> jax.Array:
     return shard(out, "batch", "attn_q_seq", "heads", "head_dim")
 
 
-def _sdpa_local(q, k, v, window: int) -> jax.Array:
+def _sdpa_local(q, k, v, window: int, scale=None) -> jax.Array:
     """Banded block attention for sliding-window layers: queries in blocks
     of ``window``; each block attends to its own and the previous block
     (2w keys) — O(S*w) score work/memory instead of the O(S^2) full band
@@ -212,7 +212,7 @@ def _sdpa_local(q, k, v, window: int) -> jax.Array:
     k2 = shard(k2, "batch", None, None, "heads", "head_dim")
 
     s = jnp.einsum("bnqhd,bnkhd->bhnqk", qb, k2).astype(jnp.float32)
-    s *= 1.0 / math.sqrt(D)
+    s *= scale or 1.0 / math.sqrt(D)
     s = shard(s, "batch", "heads", None, None, None)
     a_idx = jax.lax.broadcasted_iota(jnp.int32, (nb, w, 2 * w), 1)
     b_idx = jax.lax.broadcasted_iota(jnp.int32, (nb, w, 2 * w), 2)
@@ -228,7 +228,8 @@ def _sdpa_local(q, k, v, window: int) -> jax.Array:
     return out
 
 
-def _sdpa_chunked(q, k, v, q_pos, k_pos, causal, window) -> jax.Array:
+def _sdpa_chunked(q, k, v, q_pos, k_pos, causal, window,
+                  scale=None) -> jax.Array:
     """Online-softmax attention (the pure-XLA flash equivalent): ALL queries
     held live, ``lax.scan`` over KV chunks only; the live score block is
     (B, H, Q, kv_chunk). Folded-head layout like ``_sdpa_folded``, with the
@@ -256,7 +257,7 @@ def _sdpa_chunked(q, k, v, q_pos, k_pos, causal, window) -> jax.Array:
     kblocks = kp.reshape(B, nk, kc, H, D).transpose(1, 0, 3, 2, 4)
     vblocks = vp.reshape(B, nk, kc, H, D).transpose(1, 0, 3, 2, 4)
     kpos_b = kpos.reshape(nk, kc)
-    scale = 1.0 / math.sqrt(D)
+    scale = scale or 1.0 / math.sqrt(D)
 
     def kv_block(state, kb):
         m_prev, l_prev, acc = state
@@ -303,7 +304,8 @@ def attention(params: dict, cfg: ModelConfig, x: jax.Array, *,
               kv_source: Optional[jax.Array] = None,
               use_rope: bool = True,
               k_offset: jax.Array | int = 0,
-              local_window: Optional[int] = None
+              local_window: Optional[int] = None,
+              rows: Optional[dict] = None
               ) -> Tuple[jax.Array, Optional[dict]]:
     """General attention: self/cross, train/prefill/decode.
 
@@ -312,11 +314,16 @@ def attention(params: dict, cfg: ModelConfig, x: jax.Array, *,
     ``cache_pos``; decode attends over the cache.
     kv_source: cross-attention memory (B, T, D) -- no cache path needed for
     training; for decode the cross K/V are precomputed in the cache.
+    rows (width-1 decode of left-padded rows): {"active": (B,) bool,
+    "start": (B,) int32}; an inactive row (a pad step) leaves its cache
+    slot as it was, and row b attends only to slots from ``start[b]``.
+    Scores are scaled by ``cfg.attention_scale``.
     """
     B, S, D = x.shape
     hd = cfg.resolved_head_dim
     H, KV = cfg.num_heads, cfg.num_kv_heads
     dt = x.dtype
+    scale = cfg.attention_scale
 
     q = (x @ params["wq"]).reshape(B, S, H, hd)
     src = kv_source if kv_source is not None else x
@@ -331,7 +338,7 @@ def attention(params: dict, cfg: ModelConfig, x: jax.Array, *,
     k = shard(k, "batch", "seq", "kv_heads", "head_dim")
     v = shard(v, "batch", "seq", "kv_heads", "head_dim")
 
-    if use_rope and kv_source is None:
+    if use_rope and cfg.use_rope and kv_source is None:
         sin, cos = rope_angles(positions, hd, cfg.rope_theta)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
@@ -341,28 +348,41 @@ def attention(params: dict, cfg: ModelConfig, x: jax.Array, *,
         # decode: write S (normally 1) new entries at cache_pos
         kc = cache["k"]  # (B, KV, S_max, hd)
         vc = cache["v"]
-        k_t = k.transpose(0, 2, 1, 3)  # (B, KV, S, hd)
-        v_t = v.transpose(0, 2, 1, 3)
-        kc = jax.lax.dynamic_update_slice(kc, k_t.astype(kc.dtype), (0, 0, cache_pos, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v_t.astype(vc.dtype), (0, 0, cache_pos, 0))
+        k_t = k.transpose(0, 2, 1, 3).astype(kc.dtype)  # (B, KV, S, hd)
+        v_t = v.transpose(0, 2, 1, 3).astype(vc.dtype)
+        at = (0, 0, cache_pos, 0)
+        if rows is not None:
+            keep = ~rows["active"][:, None, None, None]
+            k_t = jnp.where(keep, jax.lax.dynamic_slice(kc, at, k_t.shape),
+                            k_t)
+            v_t = jnp.where(keep, jax.lax.dynamic_slice(vc, at, v_t.shape),
+                            v_t)
+        kc = jax.lax.dynamic_update_slice(kc, k_t, at)
+        vc = jax.lax.dynamic_update_slice(vc, v_t, at)
         new_cache = {"k": kc, "v": vc}
         k_idx = jnp.arange(kc.shape[2])
         valid = k_idx <= (cache_pos + S - 1)
         k_pos = k_idx + k_offset  # real positions of cache slots
         q_pos = positions
         mask = _attn_mask(q_pos, k_pos, causal, window) & valid[None, :]
+        if rows is not None:
+            mask = mask[None] & (k_idx[None, :] >= rows["start"][:, None]
+                                 )[:, None, :]
         if q.shape[1] * kc.shape[2] <= _DIRECT_LIMIT or q.shape[1] == 1:
-            out = _sdpa_grouped(q, kc, vc, mask)  # native cache layout
+            # native cache layout
+            out = _sdpa_grouped(q, kc, vc, mask, scale)
+        elif rows is not None:
+            raise NotImplementedError("row starts apply to width-1 decode")
         else:
             out = _sdpa_chunked(q, kc.transpose(0, 2, 1, 3),
                                 vc.transpose(0, 2, 1, 3), q_pos,
                                 jnp.where(valid, k_pos, 10 ** 9),
-                                causal, window)
+                                causal, window, scale)
     elif cache is not None and kv_source is not None:
         # decode-time cross-attention: cached K/V (native layout), no update
         kc, vc = cache["k"], cache["v"]
         mask = jnp.ones((S, kc.shape[2]), bool)
-        out = _sdpa_grouped(q, kc, vc, mask)
+        out = _sdpa_grouped(q, kc, vc, mask, scale)
         new_cache = cache
     else:
         k_pos = positions if kv_source is None else jnp.arange(Tsrc)
@@ -370,13 +390,13 @@ def attention(params: dict, cfg: ModelConfig, x: jax.Array, *,
         if (local_window is not None and kv_source is None and causal
                 and S >= 2 * local_window):
             # banded block path: O(S*w) instead of O(S^2)-then-mask
-            out = _sdpa_local(q, k, v, local_window)
+            out = _sdpa_local(q, k, v, local_window, scale)
         elif S * Tsrc <= _DIRECT_LIMIT:
             mask = _attn_mask(q_pos, k_pos, causal and kv_source is None, window)
-            out = _sdpa_folded(q, k, v, mask)
+            out = _sdpa_folded(q, k, v, mask, scale)
         else:
             out = _sdpa_chunked(q, k, v, q_pos, k_pos,
-                                causal and kv_source is None, window)
+                                causal and kv_source is None, window, scale)
 
     out = shard(out, "batch", "seq", "heads", "head_dim")
     out = out.reshape(B, S, H * hd).astype(dt) @ params["wo"]

@@ -1,5 +1,9 @@
 """Mamba2 (SSD — state-space duality, arXiv:2405.21060) block in pure JAX.
 
+The block's output is ``out_proj(rmsnorm(y * silu(z)) * norm)``: the
+published Mamba-2 block's gated RMSNorm, over all ``d_inner`` channels
+(one group, as in mamba2-130m and granite-4.0-h).
+
 The chunked SSD algorithm: within-chunk work is quadratic in the chunk size
 and maps onto the MXU; the inter-chunk recurrence is a ``lax.scan`` carrying
 the (B, H, P, N) state. Decode is the O(1) recurrent update. A Pallas TPU
@@ -15,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.common.config import ModelConfig
 from repro.distributed import shard
-from repro.models.layers import dense_init
+from repro.models.layers import dense_init, rmsnorm
 
 
 def mamba_dims(cfg: ModelConfig, d_model: Optional[int] = None):
@@ -40,6 +44,7 @@ def init_mamba(key, cfg: ModelConfig, d_model: Optional[int] = None) -> dict:
         "dt_bias": jnp.log(jnp.expm1(
             jnp.exp(jax.random.uniform(k3, (H,), jnp.float32,
                                        math.log(1e-3), math.log(1e-1))))),
+        "norm": jnp.ones((d_inner,), dt),
         "out_proj": dense_init(k4, d_inner, d, dt),
     }
 
@@ -147,11 +152,13 @@ def ssd_decode_step(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
 
 
 def mamba_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
-                cache: Optional[dict] = None
+                cache: Optional[dict] = None, rows: Optional[dict] = None
                 ) -> Tuple[jax.Array, Optional[dict]]:
     """Full Mamba2 block. x: (B, S, D).
 
     cache (decode): {"conv": (B, W-1, conv_dim), "ssm": (B, H, P, N)}.
+    rows (width-1 decode): {"active": (B,) bool}; an inactive row (a pad
+    step) keeps its conv and SSM state as they were.
     """
     Bsz, S, Dm = x.shape
     _, d_inner, H, P, N = mamba_dims(cfg, Dm)
@@ -175,6 +182,12 @@ def mamba_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
         y, new_state = ssd_decode_step(xh[:, 0], dt[:, 0], A, Bm[:, 0],
                                        Cm[:, 0], params["D"], cache["ssm"])
         y = y[:, None]
+        if rows is not None:
+            act = rows["active"]
+            new_state = jnp.where(act[:, None, None, None], new_state,
+                                  cache["ssm"])
+            new_conv_state = jnp.where(act[:, None, None], new_conv_state,
+                                       cache["conv"])
         new_cache = {"conv": new_conv_state, "ssm": new_state}
     else:
         init_state = cache["ssm"] if cache is not None else None
@@ -184,6 +197,7 @@ def mamba_block(params: dict, cfg: ModelConfig, x: jax.Array, *,
             new_cache = {"conv": new_conv_state, "ssm": final_state}
 
     y = y.reshape(Bsz, S, d_inner) * jax.nn.silu(z)
+    y = rmsnorm(params["norm"], y, cfg.norm_eps)
     out = y @ params["out_proj"]
     return shard(out, "batch", "seq", None), new_cache
 

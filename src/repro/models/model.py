@@ -8,8 +8,11 @@ dense   : uniform [attn + SwiGLU] blocks; gemma-style local:global sliding
           window handled with per-layer flags scanned alongside the params.
 moe     : uniform [attn + MoE] blocks (granite, qwen3).
 ssm     : uniform Mamba2 blocks (mamba2-130m).
-hybrid  : jamba super-blocks of 8 layers: 7 mamba + 1 attention mixer,
-          alternating dense/MoE FFNs (MoE every 2nd layer).
+hybrid  : super-blocks of one period of `attn_every` layers: mamba mixers
+          and one attention mixer at `attn_position` (jamba: 7 mamba + 1
+          attention, last; alternating dense/MoE FFNs; granite-4.0-h: 9
+          mamba + attention at position 5, MoE plus a shared expert at
+          every layer, NoPE, granite's multipliers).
 audio   : whisper encoder-decoder backbone; conv/mel frontend is a stub —
           the caller supplies frame embeddings (B, T_frames, d_model).
 vlm     : llama-3.2-vision style: decoder super-blocks of 5 layers where the
@@ -87,8 +90,10 @@ def _init_ssm_block(cfg: ModelConfig):
 
 
 def _init_hybrid_superblock(cfg: ModelConfig):
-    """Jamba super-block: `attn_every` layers, last mixer is attention, the
-    rest mamba; FFN alternates dense / MoE (MoE at odd positions)."""
+    """Hybrid super-block: `attn_every` layers, the mixer at
+    `attn_position` is attention, the rest mamba; an MoE FFN every
+    `moe_every` layers (with the shared expert, if any), dense FFNs
+    between."""
     dt = jnp.dtype(cfg.dtype)
     n = cfg.attn_every
     n_mamba = n - 1
@@ -97,14 +102,21 @@ def _init_hybrid_superblock(cfg: ModelConfig):
 
     def f(key):
         km, ka, kd, ke = jax.random.split(key, 4)
-        return {
+        p = {
             "mamba": _stack_init(lambda k: M.init_mamba(k, cfg), km, n_mamba),
             "attn": L.init_attention(ka, cfg),
-            "ffn_dense": _stack_init(lambda k: L.init_ffn(k, cfg), kd, n_dense),
             "moe": _stack_init(lambda k: MOE.init_moe(k, cfg), ke, n_moe),
             "ln_mix": jnp.ones((n, cfg.d_model), dt),
             "ln_ffn": jnp.ones((n, cfg.d_model), dt),
         }
+        if n_dense:
+            p["ffn_dense"] = _stack_init(lambda k: L.init_ffn(k, cfg), kd,
+                                         n_dense)
+        if cfg.shared_expert_ff:
+            p["shared"] = _stack_init(
+                lambda k: L.init_ffn(k, cfg, cfg.shared_expert_ff),
+                jax.random.fold_in(key, 1), n_moe)
+        return p
 
     return f
 
@@ -225,41 +237,66 @@ def layer_windows(cfg: ModelConfig, decode: bool = False) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # block bodies (shared between train forward and decode)
+#
+# Device scopes (repro.common.tracing.SCOPES): arm.attention, arm.mamba,
+# arm.moe (routed and shared experts) and, in decode_step, arm.head.
+# ``rows`` (decode of left-padded rows, see decode_step) goes to every
+# mixer that keeps state.
 # ---------------------------------------------------------------------------
 
 
+def _res(cfg, x, y):
+    """Residual add, the branch scaled by ``residual_multiplier``."""
+    if cfg.residual_multiplier == 1.0:
+        return x + y
+    return x + cfg.residual_multiplier * y
+
+
+def _self_attention(p, cfg, h, positions, window, cache_l, cache_pos,
+                    k_offset=0, rows=None):
+    with jax.named_scope("arm.attention"):
+        return L.attention(p, cfg, h, positions=positions, causal=True,
+                           window=window, cache=cache_l, cache_pos=cache_pos,
+                           k_offset=k_offset, rows=rows)
+
+
+def _mamba(p, cfg, h, cache_l, rows):
+    with jax.named_scope("arm.mamba"):
+        return M.mamba_block(p, cfg, h, cache=cache_l, rows=rows)
+
+
 def _dense_body(p, cfg, x, positions, window, cache_l=None, cache_pos=None,
-                k_offset=0):
+                k_offset=0, rows=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    a, new_c = L.attention(p["attn"], cfg, h, positions=positions,
-                           causal=True, window=window, cache=cache_l,
-                           cache_pos=cache_pos, k_offset=k_offset)
-    x = x + a
+    a, new_c = _self_attention(p["attn"], cfg, h, positions, window, cache_l,
+                               cache_pos, k_offset, rows)
+    x = _res(cfg, x, a)
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    x = x + L.ffn(p["ffn"], h)
+    x = _res(cfg, x, L.ffn(p["ffn"], h))
     return x, new_c
 
 
 def _moe_body(p, cfg, x, positions, window, cache_l=None, cache_pos=None,
-              k_offset=0):
+              k_offset=0, rows=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    a, new_c = L.attention(p["attn"], cfg, h, positions=positions, causal=True,
-                           window=window, cache=cache_l, cache_pos=cache_pos,
-                           k_offset=k_offset)
-    x = x + a
+    a, new_c = _self_attention(p["attn"], cfg, h, positions, window, cache_l,
+                               cache_pos, k_offset, rows)
+    x = _res(cfg, x, a)
     h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
-    mo, aux = MOE.moe_ffn(p["moe"], cfg, h)
-    return x + mo, new_c, aux
+    with jax.named_scope("arm.moe"):
+        mo, aux = MOE.moe_ffn(p["moe"], cfg, h)
+    return _res(cfg, x, mo), new_c, aux
 
 
-def _ssm_body(p, cfg, x, cache_l=None):
+def _ssm_body(p, cfg, x, cache_l=None, rows=None):
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    m, new_c = M.mamba_block(p["mamba"], cfg, h, cache=cache_l)
-    return x + m, new_c
+    m, new_c = _mamba(p["mamba"], cfg, h, cache_l, rows)
+    return _res(cfg, x, m), new_c
 
 
-def _hybrid_body(p, cfg, x, positions, cache_l=None, cache_pos=None):
-    """One jamba super-block, unrolled over its `attn_every` positions."""
+def _hybrid_body(p, cfg, x, positions, cache_l=None, cache_pos=None,
+                 rows=None):
+    """One hybrid super-block, unrolled over its `attn_every` positions."""
     n = cfg.attn_every
     aux_total = jnp.float32(0.0)
     new_cache = {"mamba": [], "attn": None} if cache_l is not None else None
@@ -267,35 +304,38 @@ def _hybrid_body(p, cfg, x, positions, cache_l=None, cache_pos=None):
     for pos in range(n):
         ln_mix = p["ln_mix"][pos]
         ln_ffn = p["ln_ffn"][pos]
-        is_attn = pos == (n - 1)
         h = L.rmsnorm(ln_mix, x, cfg.norm_eps)
-        if is_attn:
+        if pos == cfg.attn_position:
             c = cache_l["attn"] if cache_l is not None else None
-            a, nc = L.attention(p["attn"], cfg, h, positions=positions,
-                                causal=True, window=cfg.sliding_window,
-                                cache=c, cache_pos=cache_pos)
+            a, nc = _self_attention(p["attn"], cfg, h, positions,
+                                    cfg.sliding_window, c, cache_pos,
+                                    rows=rows)
             if new_cache is not None:
                 new_cache["attn"] = nc
-            x = x + a
+            x = _res(cfg, x, a)
         else:
             mp = jax.tree.map(lambda t: t[i_mamba], p["mamba"])
             c = (jax.tree.map(lambda t: t[i_mamba], cache_l["mamba"])
                  if cache_l is not None else None)
-            m, nc = M.mamba_block(mp, cfg, h, cache=c)
+            m, nc = _mamba(mp, cfg, h, c, rows)
             if new_cache is not None:
                 new_cache["mamba"].append(nc)
-            x = x + m
+            x = _res(cfg, x, m)
             i_mamba += 1
         h = L.rmsnorm(ln_ffn, x, cfg.norm_eps)
         if (pos % cfg.moe_every) == (cfg.moe_every - 1):
             ep = jax.tree.map(lambda t: t[i_moe], p["moe"])
-            mo, aux = MOE.moe_ffn(ep, cfg, h)
-            x = x + mo
+            with jax.named_scope("arm.moe"):
+                mo, aux = MOE.moe_ffn(ep, cfg, h)
+                if cfg.shared_expert_ff:
+                    sp = jax.tree.map(lambda t: t[i_moe], p["shared"])
+                    mo = mo + L.ffn(sp, h)
+            x = _res(cfg, x, mo)
             aux_total = aux_total + aux
             i_moe += 1
         else:
             fp = jax.tree.map(lambda t: t[i_dense], p["ffn_dense"])
-            x = x + L.ffn(fp, h)
+            x = _res(cfg, x, L.ffn(fp, h))
             i_dense += 1
     if new_cache is not None:
         new_cache["mamba"] = jax.tree.map(
@@ -304,13 +344,15 @@ def _hybrid_body(p, cfg, x, positions, cache_l=None, cache_pos=None):
 
 
 def _vlm_superblock_body(p, cfg, x, positions, image_embed, window,
-                         cache_l=None, cache_pos=None, cross_cache=None):
+                         cache_l=None, cache_pos=None, cross_cache=None,
+                         rows=None):
     n = cfg.cross_attn_every
     new_self = [] if cache_l is not None else None
     for i in range(n):
         sp = jax.tree.map(lambda t: t[i], p["self"])
         c = (jax.tree.map(lambda t: t[i], cache_l) if cache_l is not None else None)
-        x, nc = _dense_body(sp, cfg, x, positions, window, c, cache_pos)
+        x, nc = _dense_body(sp, cfg, x, positions, window, c, cache_pos,
+                            rows=rows)
         if new_self is not None:
             new_self.append(nc)
     # gated cross-attention into image tokens
@@ -342,8 +384,7 @@ def forward_hidden(params: Dict, cfg: ModelConfig, batch: Dict
     """
     tokens = batch["tokens"]
     B, S = tokens.shape
-    x = params["embed"][tokens]
-    x = shard(x, "batch", "seq", None)
+    x = shard(_embed(params, cfg, tokens), "batch", "seq", None)
     positions = jnp.arange(S)
     aux = jnp.float32(0.0)
     at = cfg.arch_type
@@ -440,12 +481,26 @@ def unembed_matrix(params: Dict) -> jax.Array:
     return unembed if unembed is not None else params["embed"].T
 
 
+def _embed(params: Dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    x = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _logits(params: Dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """Final-norm output -> logits, divided by ``logits_scaling``."""
+    logits = x @ unembed_matrix(params)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
 def forward_train(params: Dict, cfg: ModelConfig, batch: Dict
                   ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits (B, S, V_pad), moe_aux_loss scalar)."""
     x, aux = forward_hidden(params, cfg, batch)
-    logits = x @ unembed_matrix(params)
-    logits = shard(logits, "batch", "seq", "vocab")
+    logits = shard(_logits(params, cfg, x), "batch", "seq", "vocab")
     return logits, aux
 
 
@@ -583,8 +638,10 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
     dt = jnp.dtype(cfg.dtype)
     at = cfg.arch_type
     W = cache_len_for(cfg, seq_len)
+    # "start": each row's first real position (see decode_step)
     cache: Dict = {"pos": jnp.zeros((), jnp.int32),
-                   "offset": jnp.zeros((), jnp.int32)}
+                   "offset": jnp.zeros((), jnp.int32),
+                   "start": jnp.zeros((batch,), jnp.int32)}
 
     if at in ("dense", "moe"):
         cache["layers"] = jax.vmap(
@@ -649,12 +706,20 @@ def _scan_decode(body, x, blocks, cache_layers, extra_xs=None):
 
 def decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
                 tokens: jax.Array) -> Tuple[jax.Array, Dict]:
-    """One serving step: tokens (B, 1) -> (logits (B, 1, V_pad), new cache)."""
+    """One serving step: tokens (B, 1) -> (logits (B, 1, V_pad), new cache).
+
+    Rows of different prompt lengths decode together left-padded: row b's
+    prompt starts at step ``cache["start"][b]``, so every prompt ends at
+    the same step. A step before a row's start is a pad step: it leaves
+    the row's state (Mamba2 conv and SSM state, attention K/V) as it was,
+    and the row's attention never reads the slots before its start. The
+    position of every real token then shifts by its row's start, which
+    relative position embeddings (RoPE) and NoPE do not see."""
     B, S = tokens.shape
     pos = cache["pos"]
     real_pos = (cache["offset"] + pos)[None]
-    x = params["embed"][tokens]
-    x = shard(x, "batch", None, None)
+    x = shard(_embed(params, cfg, tokens), "batch", None, None)
+    rows = {"active": pos >= cache["start"], "start": cache["start"]}
     at = cfg.arch_type
 
     if at in ("dense", "moe"):
@@ -662,9 +727,9 @@ def decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
             (p, w) = layer_xs
             if at == "dense":
                 return _dense_body(p, cfg, x, real_pos, w, c, pos,
-                                   k_offset=cache["offset"])
+                                   k_offset=cache["offset"], rows=rows)
             x, nc, _ = _moe_body(p, cfg, x, real_pos, w, c, pos,
-                                 k_offset=cache["offset"])
+                                 k_offset=cache["offset"], rows=rows)
             return x, nc
 
         x, new_layers = _scan_decode(
@@ -672,11 +737,11 @@ def decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
             cache["layers"], extra_xs=layer_windows(cfg, decode=True))
     elif at == "ssm":
         x, new_layers = _scan_decode(
-            lambda x, p, c: _ssm_body(p, cfg, x, c), x, params["blocks"],
-            cache["layers"])
+            lambda x, p, c: _ssm_body(p, cfg, x, c, rows), x,
+            params["blocks"], cache["layers"])
     elif at == "hybrid":
         def body(x, p, c):
-            x, nc, _ = _hybrid_body(p, cfg, x, real_pos, c, pos)
+            x, nc, _ = _hybrid_body(p, cfg, x, real_pos, c, pos, rows)
             return x, nc
 
         x, new_layers = _scan_decode(body, x, params["blocks"],
@@ -686,7 +751,8 @@ def decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
             (p, cx) = layer_xs
             h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
             a, nc = L.attention(p["attn"], cfg, h, positions=real_pos,
-                                causal=True, cache=c, cache_pos=pos)
+                                causal=True, cache=c, cache_pos=pos,
+                                rows=rows)
             x = x + a
             h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
             ca, _ = L.attention(p["cross"], cfg, h, positions=real_pos,
@@ -705,17 +771,16 @@ def decode_step(params: Dict, cfg: ModelConfig, cache: Dict,
 
         def body(x, p, c):
             return _vlm_superblock_body(p, cfg, x, real_pos, img, w0,
-                                        cache_l=c, cache_pos=pos)
+                                        cache_l=c, cache_pos=pos, rows=rows)
 
         x, new_layers = _scan_decode(body, x, params["blocks"],
                                      cache["layers"])
     else:
         raise ValueError(at)
 
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    unembed = params.get("unembed")
-    logits = x @ unembed if unembed is not None else x @ params["embed"].T
-    logits = shard(logits, "batch", None, "vocab")
+    with jax.named_scope("arm.head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = shard(_logits(params, cfg, x), "batch", None, "vocab")
 
     new_cache = dict(cache)
     new_cache["layers"] = new_layers

@@ -1,6 +1,14 @@
 """Mixture-of-Experts FFN: top-k routing with sort/gather-based dispatch
 and shard_map expert parallelism.
 
+An expert layer may hold a share of the experts
+(``cfg.experts_held`` from ``cfg.expert_first``): it routes over all
+``cfg.num_experts``, takes the top-k, and computes only its own
+experts' part of the output, as one chip of an expert-parallel
+deployment does before the exchange. With ``moe_capacity_factor`` 0 the
+layer is dropless: each expert's capacity is the token count, which no
+routing can exceed (a token picks k distinct experts).
+
 Why not the classic GShard one-hot dispatch/combine einsums: their cost is
 T x E x C x D_model, which at production shapes (qwen3-moe train_4k:
 T=1M, E=128, C=100k) is ~630x the useful expert FLOPs — the §Perf roofline
@@ -37,14 +45,14 @@ CAPACITY_FACTOR = 1.25
 def init_moe(key, cfg: ModelConfig) -> dict:
     kr, kg, ku, kd = jax.random.split(key, 4)
     dt = jnp.dtype(cfg.dtype)
-    E, D, F = cfg.num_experts, cfg.d_model, cfg.d_ff
+    E, D, F = cfg.local_experts, cfg.d_model, cfg.d_ff
     scale = 1.0 / math.sqrt(D)
 
     def ew(k, a, b):
         return (jax.random.normal(k, (E, a, b), jnp.float32) * scale).astype(dt)
 
     return {
-        "router": dense_init(kr, D, E, jnp.float32),
+        "router": dense_init(kr, D, cfg.num_experts, jnp.float32),
         "wg": ew(kg, D, F),
         "wu": ew(ku, D, F),
         "wd": (jax.random.normal(kd, (E, F, D), jnp.float32) / math.sqrt(F)).astype(dt),
@@ -53,6 +61,9 @@ def init_moe(key, cfg: ModelConfig) -> dict:
 
 def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
                     factor: float = CAPACITY_FACTOR) -> int:
+    """Slots per expert; ``factor`` 0: dropless (``num_tokens``)."""
+    if factor <= 0:
+        return num_tokens
     cap = int(math.ceil(num_tokens * top_k * factor / num_experts))
     return max(cap, 4)
 
@@ -126,13 +137,14 @@ def moe_ffn(params: dict, cfg: ModelConfig, x: jax.Array
     mesh = current_mesh()
     rules = current_rules() or {}
     ep = (mesh is not None and "model" in getattr(mesh, "axis_names", ())
-          and rules.get("experts") == "model"
+          and rules.get("experts") == "model" and not cfg.experts_held
           and E % mesh.shape["model"] == 0)
 
     if not ep:
         out, aux = _moe_local(params["router"], params["wg"], params["wu"],
                               params["wd"], cfg, x.reshape(B * S, D),
-                              0, E, expert_capacity(B * S, E, K, cf))
+                              cfg.expert_first, cfg.local_experts,
+                              expert_capacity(B * S, E, K, cf))
         return out.reshape(B, S, D), aux
 
     mp = mesh.shape["model"]

@@ -72,11 +72,13 @@ def decode_step_costs(cfg, batch: int, context: int) -> Dict[str, float]:
     primitive, DESIGN.md §16).
 
     Accounting mirrors ``repro.common.config._param_count``'s layer walk
-    so every arch family is costed by its actual mixer schedule:
+    so every arch family is costed by its actual mixer schedule (a
+    hybrid's attention layers by their position, ``layer_is_attn``):
 
     * GEMMs: ``2 * active_params * batch`` FLOPs, weights read once per
       step (``active_params * dtype_bytes`` — the batch amortizes the
-      weight traffic; MoE reads the per-token expert subset).
+      weight traffic; MoE reads the per-token expert subset and the
+      shared expert).
     * attention layers: QK^T + attn·V FLOPs over the layer's EFFECTIVE
       KV length (sliding window / local-global cap bound it) plus the
       KV-cache read+append traffic — the decode-dominant term at scale.
@@ -122,34 +124,25 @@ def decode_step_costs(cfg, batch: int, context: int) -> Dict[str, float]:
         by += 2.0 * B * d_inner * cfg.ssm_conv_width * db   # conv state
         return f, by
 
-    if cfg.arch_type == "ssm":
-        for _ in range(cfg.num_layers):
+    for i in range(cfg.num_layers):
+        if cfg.layer_is_attn(i):
+            f, by = _attn(_ctx_eff(i))
+            by += B * 2.0 * kv_dim * db       # append this step's K/V
+        else:
             f, by = _mamba()
+        flops += f
+        kv_bytes += by
+        if cfg.cross_attn_every and \
+                (i % cfg.cross_attn_every) == (cfg.cross_attn_every - 1):
+            f, by = _attn(float(cfg.num_image_tokens))
             flops += f
             kv_bytes += by
-    else:
-        for i in range(cfg.num_layers):
-            mixer_is_attn = True
-            if cfg.attn_every:
-                mixer_is_attn = (i % cfg.attn_every) == (cfg.attn_every - 1)
-            if mixer_is_attn:
-                f, by = _attn(_ctx_eff(i))
-                by += B * 2.0 * kv_dim * db       # append this step's K/V
-            else:
-                f, by = _mamba()
+    if cfg.is_encoder_decoder:
+        # decoder cross-attention over the (prefill-encoded) memory
+        for _ in range(cfg.num_layers):
+            f, by = _attn(float(cfg.num_audio_frames))
             flops += f
             kv_bytes += by
-            if cfg.cross_attn_every and \
-                    (i % cfg.cross_attn_every) == (cfg.cross_attn_every - 1):
-                f, by = _attn(float(cfg.num_image_tokens))
-                flops += f
-                kv_bytes += by
-        if cfg.is_encoder_decoder:
-            # decoder cross-attention over the (prefill-encoded) memory
-            for _ in range(cfg.num_layers):
-                f, by = _attn(float(cfg.num_audio_frames))
-                flops += f
-                kv_bytes += by
 
     # activations round-trip once per layer (residual stream read+write)
     act_bytes = 2.0 * B * d * db * max(cfg.num_layers, 1)

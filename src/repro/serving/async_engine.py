@@ -320,7 +320,8 @@ class AsyncRouterEngine:
             n_new = self.max_new
             if self.engines is not None:
                 new_tokens, _ = self.engines[target].generate(
-                    toks, max_new=self.max_new)
+                    toks, max_new=self.max_new,
+                    lengths=[len(r.tokens) for r in reqs])
                 n_new = new_tokens.shape[1]
             ids = np.array([r.sample_idx for r in reqs], np.int64)
             if self.reward_table is not None:

@@ -9,7 +9,11 @@ kept a stale deadline armed across an idle period, so the first request
 of a post-idle burst "expired" immediately and was flushed alone in an
 undersized batch; deriving readiness from arrival timestamps makes an
 empty epoch leave nothing armed (see the regression test in
-tests/test_serving.py)."""
+tests/test_serving.py).
+
+A batch's rows are right-padded to one width; ``left_align`` moves each
+row's prompt to the right end for the decode engine, so that every
+prompt ends at the same step and no row replays pads as tokens."""
 from __future__ import annotations
 
 import dataclasses
@@ -31,6 +35,21 @@ class Request:
     domain: int = 0
     sample_idx: int = -1                    # replay-table row (quality/cost)
     rid: int = dataclasses.field(default_factory=lambda: next(_counter))
+
+
+def left_align(tokens: np.ndarray, lengths, pad_token: int = 0):
+    """Right-padded rows (B, S) with each row's prompt length ->
+    (the rows left-padded, each row's first prompt column (B,) int32).
+    Lengths over S are cut to S."""
+    tokens = np.asarray(tokens)
+    B, S = tokens.shape
+    lengths = np.broadcast_to(np.asarray(lengths, np.int64), (B,))
+    start = S - np.minimum(lengths, S)
+    src = np.arange(S)[None, :] - start[:, None]
+    out = np.where(src >= 0,
+                   np.take_along_axis(tokens, np.maximum(src, 0), axis=1),
+                   pad_token).astype(tokens.dtype)
+    return out, start.astype(np.int32)
 
 
 class RequestBatcher:

@@ -22,7 +22,7 @@ from typing import Deque, Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.core.reward import utility_reward
-from repro.serving.batcher import Request, RequestBatcher
+from repro.serving.batcher import Request, RequestBatcher, left_align
 from repro.serving.engine import ServingEngine
 
 
@@ -90,8 +90,9 @@ class RoutedServingPool:
                 break
             target, reqs, toks = nb
             eng = self.engines[target]
+            toks, start = left_align(toks, [len(r.tokens) for r in reqs])
             t0 = time.time()
-            new_tokens, _ = eng.generate(toks, max_new=8)
+            new_tokens, _ = eng.generate(toks, max_new=8, start=start)
             wall = time.time() - t0
             for i, r in enumerate(reqs):
                 n_tok = len(r.tokens) + new_tokens.shape[1]

@@ -23,6 +23,8 @@ def make_train_state(key, cfg: ModelConfig):
 
 def loss_fn(params, cfg: ModelConfig, batch: Dict) -> jax.Array:
     hidden, aux = MODEL.forward_hidden(params, cfg, batch)
+    if cfg.logits_scaling != 1.0:   # (x / s) @ U == (x @ U) / s
+        hidden = hidden / cfg.logits_scaling
     return MODEL.lm_loss_chunked(hidden, MODEL.unembed_matrix(params),
                                  batch["labels"], cfg.vocab_size, aux)
 

@@ -51,7 +51,7 @@ def _programs(env, r):
         "scan": (_policy_scan.lower(
             _tables(env), env.slice_xs(), env.idx, _cum_valid(env), key,
             r.hyp, r.policy, None, 0, r.fcfg, 1, 8, None, False),
-            set(SCOPES)),
+            {s for s in SCOPES if s.startswith("router.")}),
     }
 
 
@@ -80,6 +80,28 @@ def test_span_helper_runs_without_a_trace():
     with span("engine.pump"):
         with span("engine.decide", gid=1, rows=2, wait_us=0.5):
             pass
-    assert all(name.startswith(("engine.", "router.")) for name in SPANS)
+    assert all(name.startswith(("engine.", "router.", "arm."))
+               for name in SPANS)
     assert set(SCOPES) == {"router.decide", "router.update",
-                           "router.train_sgd", "router.rebuild"}
+                           "router.train_sgd", "router.rebuild",
+                           "arm.mamba", "arm.attention", "arm.moe",
+                           "arm.head"}
+
+
+def test_decode_program_carries_the_arm_scopes():
+    """The hybrid arm's decode step: every mixer kind, the expert layer
+    and the head under their scopes."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import model as MODEL
+    from repro.serving.engine import serve_step
+    cfg = dataclasses.replace(get_config("granite_4_0_h_small").reduced(),
+                              dtype="float32")
+    params = jax.eval_shape(lambda: MODEL.init_params(
+        jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: MODEL.init_cache(cfg, 2, 8))
+    lowered = jax.jit(serve_step, static_argnums=1).lower(
+        params, cfg, cache, jax.ShapeDtypeStruct((2, 1), jnp.int32))
+    assert _scopes_in(lowered.compile()) == {
+        "arm.mamba", "arm.attention", "arm.moe", "arm.head"}

@@ -4,7 +4,7 @@ Continuous-batching front end for any bandit router:
 
     submit -> [bounded admission queue] -> microbatched DECIDE (one
     batched jit call per microbatch) -> per-arm fallback chain ->
-    [per-arm RequestBatcher] -> serve -> reward feedback -> router UPDATE
+    [per-arm queues] -> serve -> reward feedback -> router UPDATE
 
 The loop is cooperative and deterministic: ``pump()`` advances every
 stage as far as it can (decide everything due, serve every ready arm
@@ -13,6 +13,18 @@ flushes until nothing is in flight. "Async" here is the continuous-
 batching sense — decides and serves interleave across microbatches, and
 nothing blocks on a full wave — while keeping single-threaded replayable
 semantics (an injectable ``clock`` makes every timeout testable).
+
+A decided microbatch is held as columns (request ids, table rows,
+decided and served arm, reward, quality, cost, chain depth), not walked
+row by row: the fallback chains resolve through a (K,) table of
+(served arm, depth) rebuilt when an arm's health changes, one stable
+sort groups the rows by served arm, and each arm's queue
+(:class:`~repro.serving.batcher.ArmQueues`, the batcher's selection
+rule) holds (microbatch, row positions) chunks. A serve batch writes its
+feedback back with one store per microbatch it touches; a completed
+microbatch's records are built from its columns. Only an arm engine
+that reads prompts (``real_decode`` not False) gets a padded token
+matrix (counted in ``padded_batches``).
 
 Graceful degradation (the CostSavingRouter pattern, SNIPPETS.md §1):
 
@@ -45,33 +57,37 @@ import numpy as np
 
 from repro.common.tracing import span
 from repro.core.reward import utility_reward
-from repro.serving.batcher import Request, RequestBatcher
+from repro.serving.batcher import ArmQueues, Request, pad_rows
 from repro.serving.snapshot import load_snapshot, save_snapshot, \
     unflatten_state
 
 COUNTERS = ("submitted", "admitted", "completed", "shed_queue_full",
             "shed_no_arm", "fallbacks", "decide_calls", "decide_errors",
-            "updates", "learned", "skipped_learn", "dropped_log_records")
+            "updates", "learned", "skipped_learn", "dropped_log_records",
+            "padded_batches")
 
 
 class _Group:
-    """One decided microbatch awaiting completion."""
+    """One decided microbatch awaiting completion, held as columns: row
+    ``i`` is ``reqs[i]``; an arm queue holds its rows' positions."""
 
-    __slots__ = ("decision", "reqs", "decided", "served", "reward",
-                 "quality", "cost", "depth", "remaining", "x_emb", "x_feat",
-                 "domain")
+    __slots__ = ("decision", "reqs", "rid", "ids", "decided", "served",
+                 "reward", "quality", "cost", "depth", "remaining",
+                 "x_emb", "x_feat", "domain")
 
-    def __init__(self, decision, reqs, decided, x_emb=None, x_feat=None,
-                 domain=None):
+    def __init__(self, decision, reqs, rid, ids, decided, depth,
+                 x_emb=None, x_feat=None, domain=None):
         n = len(reqs)
         self.decision = decision      # router decision dict, or None
         self.reqs = reqs
+        self.rid = rid                # (n,) request ids
+        self.ids = ids                # (n,) replay-table rows
         self.decided = decided        # (n,) pre-fallback actions
         self.served = np.full(n, -1, np.int32)
         self.reward = np.zeros(n, np.float32)
         self.quality = np.zeros(n, np.float32)
         self.cost = np.zeros(n, np.float32)
-        self.depth = np.zeros(n, np.int32)   # fallback-chain depth
+        self.depth = depth            # (n,) fallback-chain depth
         self.remaining = n
         self.x_emb, self.x_feat, self.domain = x_emb, x_feat, domain
 
@@ -129,16 +145,18 @@ class AsyncRouterEngine:
         self.max_new = int(max_new)
         self.clock = clock
         self.fault_hook = fault_hook
-        self.batcher = RequestBatcher(max_batch=serve_batch,
-                                      pad_to_multiple=pad_to_multiple,
-                                      flush_timeout=serve_flush, clock=clock)
+        self.pad_to_multiple = int(pad_to_multiple)
+        # per-arm queues of (gid, row positions) chunks
+        self._queues = ArmQueues(serve_batch, flush_timeout=serve_flush,
+                                 clock=clock)
         self.arm_up = np.ones(self.K, bool)
         self.chains = self._default_chains() if fallback_chains is None \
             else {int(a): [int(x) for x in c]
                   for a, c in fallback_chains.items()}
-        self._admit: deque = deque()          # (Request, arrival clock)
+        self._resolve_table()
+        self._admit: List[Request] = []       # the admission queue
+        self._arrive: List[float] = []        # its rows' arrival clocks
         self._groups: Dict[int, _Group] = {}
-        self._rid_slot: Dict[int, tuple] = {}  # rid -> (gid, pos)
         self._next_gid = 0
         self.counters = {k: 0 for k in COUNTERS}
         self.decide_wall_s: List[float] = []
@@ -162,7 +180,9 @@ class AsyncRouterEngine:
         return {a: [b for b in order if b != a] for a in range(self.K)}
 
     def set_arm_health(self, arm: int, up: bool) -> None:
-        self.arm_up[int(arm)] = bool(up)
+        if self.arm_up[int(arm)] != bool(up):
+            self.arm_up[int(arm)] = bool(up)
+            self._resolve_table()
 
     def _safe_arm(self) -> int:
         for a in self._arm_cost_rank():
@@ -170,37 +190,44 @@ class AsyncRouterEngine:
                 return int(a)
         return -1
 
-    def _resolve_arm(self, a: int):
-        """(served_arm, chain_depth); served < 0 = whole chain down."""
-        if self.arm_up[a]:
-            return a, 0
-        for d, b in enumerate(self.chains.get(a, ()), start=1):
-            if self.arm_up[b]:
-                return b, d
-        return -1, len(self.chains.get(a, ())) + 1
+    def _resolve_table(self) -> None:
+        """The fallback chains under the current health mask, as (K,)
+        tables: the arm that serves a row decided onto arm ``a``
+        (``_served_of[a]``; -1: the whole chain is down) and its depth
+        in the chain (``_depth_of[a]``)."""
+        self._served_of = np.full(self.K, -1, np.int32)
+        self._depth_of = np.zeros(self.K, np.int32)
+        for a in range(self.K):
+            chain = [a, *self.chains.get(a, ())]
+            d = next((d for d, b in enumerate(chain) if self.arm_up[b]),
+                     None)
+            if d is not None:
+                self._served_of[a], self._depth_of[a] = chain[d], d
 
     # -------------------------------------------------------- admission --
     def submit(self, requests: Sequence[Request]):
-        """Admit into the bounded queue; excess is shed with a counted
-        drop (and a log record). Returns (n_admitted, n_shed)."""
-        with span("engine.submit", rows=len(requests)):
+        """Admit into the bounded queue; excess (the newest arrivals) is
+        shed with a counted drop and a log record each. Returns
+        (n_admitted, n_shed)."""
+        n = len(requests)
+        with span("engine.submit", rows=n):
             now = self.clock()
-            shed = 0
-            for r in requests:
-                self.counters["submitted"] += 1
-                if len(self._admit) >= self.queue_capacity:
-                    self.counters["shed_queue_full"] += 1
-                    shed += 1
-                    self._log({"rid": r.rid, "status": "shed_queue_full",
-                               "action": -1, "reward": 0.0})
-                else:
-                    self.counters["admitted"] += 1
-                    self._admit.append((r, now))
-        return len(requests) - shed, shed
+            keep = min(n, max(0, self.queue_capacity - len(self._admit)))
+            self._admit.extend(requests[:keep])
+            self._arrive.extend([now] * keep)
+            c = self.counters
+            c["submitted"] += n
+            c["admitted"] += keep
+            if keep < n:
+                c["shed_queue_full"] += n - keep
+                self._log_many([{"rid": r.rid, "status": "shed_queue_full",
+                                 "action": -1, "reward": 0.0}
+                                for r in requests[keep:]])
+        return keep, n - keep
 
     @property
     def in_flight(self) -> int:
-        return len(self._admit) + self.batcher.pending()
+        return len(self._admit) + self._queues.pending()
 
     # ------------------------------------------------------------- pump --
     def pump(self, force: bool = False) -> List[Dict]:
@@ -210,13 +237,13 @@ class AsyncRouterEngine:
         with span("engine.pump"):
             while self._decide_due(force):
                 k = min(self.decide_batch, len(self._admit))
-                reqs, arrived = zip(*[self._admit.popleft()
-                                      for _ in range(k)])
+                reqs, arrived = self._admit[:k], self._arrive[:k]
+                del self._admit[:k], self._arrive[:k]
                 # the rows' summed admission wait, on the engine's clock
                 wait_s = k * self.clock() - sum(arrived)
-                self._decide_and_dispatch(list(reqs), out, wait_s)
+                self._decide_and_dispatch(reqs, out, wait_s)
             while True:
-                nb = self.batcher.next_batch(force=force)
+                nb = self._queues.pop(force)
                 if nb is None:
                     break
                 self._serve_batch(*nb, out)
@@ -248,22 +275,20 @@ class AsyncRouterEngine:
             return False
         if force or n >= self.decide_batch or self.decide_flush is None:
             return True
-        return self.clock() - self._admit[0][1] >= self.decide_flush
+        return self.clock() - self._arrive[0] >= self.decide_flush
 
     def _decide_and_dispatch(self, reqs: List[Request], out: List[Dict],
                              wait_s: float):
         n = len(reqs)
         if not self.arm_up.any():
-            for r in reqs:
-                self.counters["shed_no_arm"] += 1
-                rec = {"rid": r.rid, "status": "shed_no_arm", "action": -1,
-                       "reward": 0.0}
-                self._log(rec)
-                out.append(rec)
+            self.counters["shed_no_arm"] += n
+            self._shed_no_arm([r.rid for r in reqs], out)
             return
         gid = self._next_gid
         self._next_gid += 1
         with span("engine.decide", gid=gid, rows=n, wait_us=wait_s * 1e6):
+            rid = np.array([r.rid for r in reqs], np.int64)
+            ids = np.array([r.sample_idx for r in reqs], np.int64)
             x_emb = x_feat = domain = None
             if not self._serving_v2:
                 x_emb = np.stack([r.x_emb for r in reqs])
@@ -277,7 +302,6 @@ class AsyncRouterEngine:
                 if self.fault_hook is not None:
                     self.fault_hook(call_idx)
                 if self._serving_v2:
-                    ids = np.array([r.sample_idx for r in reqs], np.int64)
                     decision = self.router.decide(
                         sample_idx=ids,
                         avail=self.arm_up.astype(np.float32))
@@ -292,65 +316,87 @@ class AsyncRouterEngine:
                 decision = None
                 decided = np.full(n, self._safe_arm(), np.int32)
 
-        g = _Group(decision, reqs, decided, x_emb, x_feat, domain)
-        self._groups[gid] = g
         with span("engine.dispatch", gid=gid, rows=n):
-            for i, r in enumerate(reqs):
-                served, depth = self._resolve_arm(int(decided[i]))
-                if served < 0:
-                    self.counters["shed_no_arm"] += 1
-                    g.remaining -= 1
-                    rec = {"rid": r.rid, "status": "shed_no_arm",
-                           "action": -1, "reward": 0.0}
-                    self._log(rec)
-                    out.append(rec)
-                    continue
-                if depth > 0:
-                    self.counters["fallbacks"] += 1
-                g.depth[i] = depth
-                self._rid_slot[r.rid] = (gid, i)
-                self.batcher.submit(served, r)
+            served = self._served_of[decided]
+            depth = self._depth_of[decided]
+            if decided.min() < 0:
+                served[decided < 0] = -1     # no such arm: shed
+            g = _Group(decision, reqs, rid, ids, decided, depth, x_emb,
+                       x_feat, domain)
+            self._groups[gid] = g
+            # one stable sort groups the rows by served arm, the shed
+            # rows (served -1) first, each arm's rows in microbatch order
+            order = np.argsort(served, kind="stable")
+            bounds = np.cumsum(np.bincount(served + 1,
+                                           minlength=self.K + 1)).tolist()
+            n_shed = bounds[0]
+            self.counters["fallbacks"] += int(
+                np.count_nonzero(depth[order[n_shed:]]))
+            if n_shed:
+                self.counters["shed_no_arm"] += n_shed
+                g.remaining -= n_shed
+                self._shed_no_arm(rid[order[:n_shed]].tolist(), out)
+            now = self.clock()
+            for arm in range(self.K):
+                lo, hi = bounds[arm], bounds[arm + 1]
+                if hi > lo:
+                    self._queues.push(arm, order[lo:hi], now, gid)
         if g.remaining == 0:
             self._finalize(gid, out)
 
     # ------------------------------------------------------------ serve --
-    def _serve_batch(self, target: int, reqs: List[Request],
-                     toks: np.ndarray, out: List[Dict]):
-        with span("engine.serve", arm=target, rows=len(reqs)):
+    def _serve_batch(self, target: int, parts: List[tuple],
+                     out: List[Dict]):
+        """Serve one arm batch: ``parts`` is its (gid, row positions)
+        slices in arrival order."""
+        parts = [(gid, self._groups[gid], pos) for gid, pos in parts]
+        n = sum(len(pos) for _, _, pos in parts)
+        with span("engine.serve", arm=target, rows=n):
+            ids = np.concatenate([g.ids[pos] for _, g, pos in parts])
             n_new = self.max_new
-            if self.engines is not None:
-                new_tokens, _ = self.engines[target].generate(
-                    toks, max_new=self.max_new,
-                    lengths=[len(r.tokens) for r in reqs])
+            engine = None if self.engines is None else self.engines[target]
+            lengths = None
+            if engine is not None or self.reward_table is None:
+                reqs = [g.reqs[i] for _, g, pos in parts
+                        for i in pos.tolist()]
+                lengths = [len(r.tokens) for r in reqs]
+            if engine is not None:
+                # the matrix only for an engine that reads the prompts
+                if getattr(engine, "real_decode", True):
+                    toks = pad_rows([r.tokens for r in reqs],
+                                    self.pad_to_multiple)
+                    self.counters["padded_batches"] += 1
+                else:
+                    toks = np.zeros((n, 0), np.int32)
+                new_tokens, _ = engine.generate(
+                    toks, max_new=self.max_new, lengths=lengths)
                 n_new = new_tokens.shape[1]
-            ids = np.array([r.sample_idx for r in reqs], np.int64)
             if self.reward_table is not None:
                 rw = np.asarray(self.reward_table[ids, target], np.float32)
                 q = rw if self.quality_table is None else \
                     np.asarray(self.quality_table[ids, target], np.float32)
-                c = (np.zeros(len(reqs), np.float32)
+                c = (np.zeros(n, np.float32)
                      if self.cost_table is None else
                      np.asarray(self.cost_table[ids, target], np.float32))
             else:
-                n_tok = np.array([len(r.tokens) + n_new for r in reqs])
+                n_tok = np.asarray(lengths) + n_new
                 c = (self.cost_per_token[target] * n_tok).astype(np.float32)
-                q = np.full(len(reqs), 0.5, np.float32)
+                q = np.full(n, 0.5, np.float32)
                 if self.quality_table is not None:
                     sel = ids >= 0
                     q[sel] = self.quality_table[ids[sel], target]
                 rw = np.asarray(utility_reward(q, c, self.c_max,
                                                self.cost_lambda), np.float32)
-            touched = set()
-            for i, r in enumerate(reqs):
-                gid, pos = self._rid_slot.pop(r.rid)
-                g = self._groups[gid]
+            lo = 0
+            for _, g, pos in parts:
+                hi = lo + len(pos)
                 g.served[pos] = target
-                g.reward[pos] = rw[i]
-                g.quality[pos] = q[i]
-                g.cost[pos] = c[i]
-                g.remaining -= 1
-                touched.add(gid)
-            for gid in sorted(touched):
+                g.reward[pos] = rw[lo:hi]
+                g.quality[pos] = q[lo:hi]
+                g.cost[pos] = c[lo:hi]
+                g.remaining -= hi - lo
+                lo = hi
+            for gid in sorted({gid for gid, _, _ in parts}):
                 if self._groups[gid].remaining == 0:
                     self._finalize(gid, out)
 
@@ -358,34 +404,31 @@ class AsyncRouterEngine:
     def _finalize(self, gid: int, out: List[Dict]):
         g = self._groups.pop(gid)
         ok = g.served >= 0
-        with span("engine.finalize", gid=gid,
-                  rows=int(np.count_nonzero(ok))):
-            if g.decision is not None and ok.any():
+        n_ok = int(np.count_nonzero(ok))
+        with span("engine.finalize", gid=gid, rows=n_ok):
+            if g.decision is not None and n_ok:
                 if self._serving_v2:
                     served = np.where(ok, g.served, g.decided)
                     learned = self.router.update_wave(
                         g.decision, served, g.reward, learn_mask=ok)
                     self.counters["updates"] += 1
                     self.counters["learned"] += int(learned)
-                    self.counters["skipped_learn"] += (int(ok.sum())
-                                                       - int(learned))
+                    self.counters["skipped_learn"] += n_ok - int(learned)
                 else:
                     self._update_legacy(g, ok)
             elif g.decision is None:
-                self.counters["skipped_learn"] += int(ok.sum())
-            for i, r in enumerate(g.reqs):
-                if not ok[i]:
-                    continue   # shed rows were logged at dispatch
-                self.counters["completed"] += 1
-                rec = {"rid": r.rid, "status": "ok",
-                       "action": int(g.served[i]),
-                       "decided": int(g.decided[i]),
-                       "fallback_depth": int(g.depth[i]),
-                       "reward": float(g.reward[i]),
-                       "quality": float(g.quality[i]),
-                       "cost": float(g.cost[i])}
-                self._log(rec)
-                out.append(rec)
+                self.counters["skipped_learn"] += n_ok
+            self.counters["completed"] += n_ok   # shed rows: at dispatch
+            recs = [{"rid": r, "status": "ok", "action": a, "decided": d,
+                     "fallback_depth": f, "reward": w, "quality": q,
+                     "cost": c}
+                    for r, a, d, f, w, q, c in zip(
+                        g.rid[ok].tolist(), g.served[ok].tolist(),
+                        g.decided[ok].tolist(), g.depth[ok].tolist(),
+                        g.reward[ok].tolist(), g.quality[ok].tolist(),
+                        g.cost[ok].tolist())]
+            self._log_many(recs)
+            out.extend(recs)
 
     def _update_legacy(self, g: _Group, ok: np.ndarray):
         """Host-router feedback: slice the decision to completed rows;
@@ -415,10 +458,17 @@ class AsyncRouterEngine:
         self.counters["learned"] += int(rows.size)
 
     # ------------------------------------------------------- accounting --
-    def _log(self, rec: Dict):
-        if self.log.maxlen is not None and len(self.log) == self.log.maxlen:
-            self.counters["dropped_log_records"] += 1
-        self.log.append(rec)
+    def _log_many(self, recs: List[Dict]):
+        if self.log.maxlen is not None:
+            self.counters["dropped_log_records"] += max(
+                0, len(self.log) + len(recs) - self.log.maxlen)
+        self.log.extend(recs)
+
+    def _shed_no_arm(self, rids: List[int], out: List[Dict]):
+        recs = [{"rid": r, "status": "shed_no_arm", "action": -1,
+                 "reward": 0.0} for r in rids]
+        self._log_many(recs)
+        out.extend(recs)
 
     def check_accounting(self) -> Dict[str, int]:
         """The no-silent-drop invariant; raises if any request is

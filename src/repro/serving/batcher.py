@@ -11,7 +11,11 @@ undersized batch; deriving readiness from arrival timestamps makes an
 empty epoch leave nothing armed (see the regression test in
 tests/test_serving.py).
 
-A batch's rows are right-padded to one width; ``left_align`` moves each
+The selection rule lives in :class:`ArmQueues` alone: per-target FIFO
+queues of row chunks, which ``RequestBatcher`` fills with ``Request``
+objects one at a time and the async engine fills with a decided
+microbatch's row indices, one chunk per arm. A batch's rows are
+right-padded to one width (:func:`pad_rows`); ``left_align`` moves each
 row's prompt to the right end for the decode engine, so that every
 prompt ends at the same step and no row replays pads as tokens."""
 from __future__ import annotations
@@ -19,22 +23,22 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
-from collections import defaultdict
-from typing import Callable, Dict, List, Optional
+from collections import defaultdict, deque
+from typing import Callable, Dict, Optional, Sequence
 
 import numpy as np
 
 _counter = itertools.count()
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(slots=True)
 class Request:
     tokens: np.ndarray                      # (S,) prompt token ids
     x_emb: Optional[np.ndarray] = None      # router features
     x_feat: Optional[np.ndarray] = None
     domain: int = 0
     sample_idx: int = -1                    # replay-table row (quality/cost)
-    rid: int = dataclasses.field(default_factory=lambda: next(_counter))
+    rid: int = dataclasses.field(default_factory=_counter.__next__)
 
 
 def left_align(tokens: np.ndarray, lengths, pad_token: int = 0):
@@ -52,42 +56,63 @@ def left_align(tokens: np.ndarray, lengths, pad_token: int = 0):
     return out, start.astype(np.int32)
 
 
-class RequestBatcher:
-    def __init__(self, max_batch: int = 8, pad_to_multiple: int = 4,
-                 pad_token: int = 0, max_starve: int = 4,
+def pad_rows(prompts: Sequence[np.ndarray], pad_to_multiple: int = 4,
+             pad_token: int = 0) -> np.ndarray:
+    """Prompts -> one right-padded (B, S) int32 matrix, S the longest
+    prompt rounded up to ``pad_to_multiple``."""
+    max_len = max(len(t) for t in prompts)
+    max_len = -(-max_len // pad_to_multiple) * pad_to_multiple
+    toks = np.full((len(prompts), max_len), pad_token, np.int32)
+    for i, t in enumerate(prompts):
+        toks[i, :len(t)] = t
+    return toks
+
+
+class ArmQueues:
+    """Per-target FIFO queues of row chunks and the rule that picks the
+    next batch. A chunk is ``rows`` (anything sliceable: a list of
+    requests, an index array) that arrived together at ``arrival`` on
+    ``clock``, with a ``tag`` handed back with each slice of it."""
+
+    def __init__(self, max_batch: int, max_starve: int = 4,
                  flush_timeout: Optional[float] = None,
                  clock: Callable[[], float] = time.monotonic):
         self.max_batch = max_batch
-        self.pad_to_multiple = pad_to_multiple
-        self.pad_token = pad_token
         self.max_starve = max_starve
         self.flush_timeout = flush_timeout
         self.clock = clock
-        self.queues: Dict[int, List[Request]] = defaultdict(list)
-        # arrival clock() per queued request, parallel to ``queues``
-        self._arrivals: Dict[int, List[float]] = defaultdict(list)
+        # target -> chunks [rows, first unserved row, arrival, tag]
+        self._chunks: Dict[int, deque] = {}
+        self._rows: Dict[int, int] = {}         # target -> rows queued
+        self._pending = 0
         # rounds a non-empty queue has been passed over (aging)
         self._age: Dict[int, int] = defaultdict(int)
 
-    def submit(self, target: int, req: Request) -> None:
-        self.queues[target].append(req)
-        self._arrivals[target].append(self.clock())
+    def push(self, target: int, rows, arrival: float, tag=None) -> None:
+        q = self._chunks.get(target)
+        if q is None:
+            q = self._chunks[target] = deque()
+            self._rows[target] = 0
+        q.append([rows, 0, arrival, tag])
+        self._rows[target] += len(rows)
+        self._pending += len(rows)
 
     def pending(self) -> int:
-        return sum(len(q) for q in self.queues.values())
+        return self._pending
 
     def _ready(self, target: int, now: float, force: bool) -> bool:
-        q = self.queues[target]
-        if force or self.flush_timeout is None or len(q) >= self.max_batch:
+        if force or self.flush_timeout is None \
+                or self._rows[target] >= self.max_batch:
             return True
-        return now - self._arrivals[target][0] >= self.flush_timeout
+        return now - self._chunks[target][0][2] >= self.flush_timeout
 
-    def next_batch(self, force: bool = False):
-        """Pop up to max_batch requests for the highest-priority READY
-        queue. Returns (target, requests, padded_tokens (B, S)) or None
-        — None either because nothing is pending or because no queue is
-        ready yet (partial fills still inside their flush window).
-        ``force=True`` treats every non-empty queue as ready (drain).
+    def pop(self, force: bool = False):
+        """Take up to ``max_batch`` rows from the highest-priority READY
+        queue. Returns (target, [(tag, rows slice), ...] in arrival
+        order) or None — None either because nothing is pending or
+        because no queue is ready yet (partial fills still inside their
+        flush window). ``force=True`` treats every non-empty queue as
+        ready (drain).
 
         Pure fullest-first starved minority targets indefinitely: a
         queue that refills above a small queue's length every round is
@@ -101,32 +126,66 @@ class RequestBatcher:
         break to the lowest target id (deterministic). With a flush
         timeout, both tiers select among ready queues only — a queue
         inside its window is waiting, not passed over."""
-        if not self.pending():
+        if not self._pending:
             return None
         now = self.clock()
-        ready = [t for t in self.queues if self._ready(t, now, force)]
+        ready = [t for t in self._chunks if self._ready(t, now, force)]
         if not ready:
             return None
-        starving = [t for t in ready if self._age[t] >= self.max_starve]
+        age = self._age
+        starving = [t for t in ready if age[t] >= self.max_starve]
         if starving:
-            target = max(starving, key=lambda t: (self._age[t], -t))
+            target = max(starving, key=lambda t: (age[t], -t))
         else:
-            target = max(ready,
-                         key=lambda t: (len(self.queues[t]) + self._age[t],
-                                        -t))
-        q = self.queues[target]
-        reqs, self.queues[target] = q[:self.max_batch], q[self.max_batch:]
-        self._arrivals[target] = self._arrivals[target][len(reqs):]
-        if not self.queues[target]:
-            del self.queues[target]
-            self._arrivals.pop(target, None)
-        self._age.pop(target, None)
-        for t in self.queues:
+            target = max(ready, key=lambda t: (self._rows[t] + age[t], -t))
+        q = self._chunks[target]
+        take = min(self.max_batch, self._rows[target])
+        parts, need = [], take
+        while need:
+            chunk = q[0]
+            rows, lo = chunk[0], chunk[1]
+            if len(rows) - lo <= need:
+                q.popleft()
+                parts.append((chunk[3], rows[lo:] if lo else rows))
+                need -= len(rows) - lo
+            else:
+                parts.append((chunk[3], rows[lo:lo + need]))
+                chunk[1] = lo + need
+                need = 0
+        self._pending -= take
+        if q:
+            self._rows[target] -= take
+        else:
+            del self._chunks[target], self._rows[target]
+        age.pop(target, None)
+        for t in self._chunks:
             if t != target:
-                self._age[t] += 1
-        max_len = max(len(r.tokens) for r in reqs)
-        max_len = -(-max_len // self.pad_to_multiple) * self.pad_to_multiple
-        toks = np.full((len(reqs), max_len), self.pad_token, np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, :len(r.tokens)] = r.tokens
-        return target, reqs, toks
+                age[t] += 1
+        return target, parts
+
+
+class RequestBatcher(ArmQueues):
+    """:class:`ArmQueues` over ``Request`` objects, each arriving on its
+    own ``clock()`` reading; a batch comes with its padded prompts."""
+
+    def __init__(self, max_batch: int = 8, pad_to_multiple: int = 4,
+                 pad_token: int = 0, max_starve: int = 4,
+                 flush_timeout: Optional[float] = None,
+                 clock: Callable[[], float] = time.monotonic):
+        super().__init__(max_batch, max_starve, flush_timeout, clock)
+        self.pad_to_multiple = pad_to_multiple
+        self.pad_token = pad_token
+
+    def submit(self, target: int, req: Request) -> None:
+        self.push(target, [req], self.clock())
+
+    def next_batch(self, force: bool = False):
+        """:meth:`ArmQueues.pop` as (target, requests, padded_tokens
+        (B, S)), or None."""
+        got = self.pop(force)
+        if got is None:
+            return None
+        target, parts = got
+        reqs = [r for _, rows in parts for r in rows]
+        return target, reqs, pad_rows([r.tokens for r in reqs],
+                                      self.pad_to_multiple, self.pad_token)

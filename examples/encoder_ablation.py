@@ -1,5 +1,5 @@
-"""Paper Figure 3 at example scale: run the online protocol once per text
-encoder and print the comparison (full-scale version: benchmarks/bench_encoders).
+"""Paper Figure 3: run the online protocol once per text encoder and
+print the comparison (``--samples 36497`` for the full stream).
 
     PYTHONPATH=src python examples/encoder_ablation.py [--samples 5000]
 """

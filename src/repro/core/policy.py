@@ -12,7 +12,7 @@ REBUILD:  recompute all buffered features with the new net; Cholesky.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -91,10 +91,12 @@ def _features_jit(params, cfg: UN.UtilityNetConfig, x_emb, x_feat, domain,
 
 
 class NeuralUCBRouter:
-    """Stateful router implementing the paper's policy — and, via
-    ``exploration``, the serving-side face of the policy zoo (DESIGN.md
-    §10): the same UtilityNet / replay / A^-1 stack with the decision
-    rule swapped.
+    """Stateful host router implementing the paper's policy, the
+    reference that `core.protocol.run_protocol` drives — and, via
+    ``exploration``, the host face of the policy zoo (DESIGN.md §10):
+    the same UtilityNet / replay / A^-1 stack with the decision rule
+    swapped. Serving runs the device twin,
+    :class:`repro.serving.policy_router.DevicePolicyRouter`.
 
     * ``"ucb"`` (default) — the paper's gated UCB (§3.3).
     * ``"ts"`` — NeuralTS: scores mu + scale * bonus * z, z ~ N(0, 1).
@@ -238,41 +240,3 @@ class NeuralUCBRouter:
         self.rebuild()
         self.warm = False
         return metrics
-
-    # --------------------------------------------------------- SNAPSHOT --
-    def action_features(self, x_emb, x_feat, domain, actions) -> np.ndarray:
-        """Augmented features g(x, a) for explicit (x, action) pairs —
-        the serving engine's fallback hook: when a down arm reroutes a
-        request after decide, the learned update must carry the features
-        of the arm actually SERVED, not the one decided (DESIGN.md §12)."""
-        return np.asarray(_features_jit(
-            self.params, self.cfg, jnp.asarray(x_emb), jnp.asarray(x_feat),
-            jnp.asarray(domain), jnp.asarray(actions, jnp.int32)))
-
-    def state_dict(self) -> Dict:
-        """Full learned state for snapshot/restore (the SNIPPETS.md §2
-        production checklist): net + optimizer + A^-1 + replay buffer as
-        an ``arrays`` pytree, plus JSON-able ``meta`` (host RNG state and
-        the warm flag) — a restored router resumes the exact PRNG stream
-        and learning trajectory (tests/test_serving_async.py)."""
-        return {
-            "arrays": {
-                "params": jax.tree_util.tree_map(np.asarray, self.params),
-                "opt": jax.tree_util.tree_map(np.asarray, self.opt),
-                "ainv": np.asarray(self.ainv),
-                "buffer": self.buffer.state_dict(),
-            },
-            "meta": {
-                "rng": self.np_rng.bit_generator.state,
-                "warm": bool(self.warm),
-            },
-        }
-
-    def load_state_dict(self, d: Dict) -> None:
-        arrays = d["arrays"]
-        self.params = jax.tree_util.tree_map(jnp.asarray, arrays["params"])
-        self.opt = jax.tree_util.tree_map(jnp.asarray, arrays["opt"])
-        self.ainv = jnp.asarray(arrays["ainv"])
-        self.buffer.load_state_dict(arrays["buffer"])
-        self.np_rng.bit_generator.state = d["meta"]["rng"]
-        self.warm = bool(d["meta"]["warm"])

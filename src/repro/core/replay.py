@@ -46,28 +46,6 @@ class ReplayBuffer:
             }
         return self._cached
 
-    def state_dict(self) -> Dict[str, np.ndarray]:
-        """Snapshot as one consolidated chunk (DESIGN.md §12): fixed key
-        set with zero-length arrays when empty, so the serving snapshot
-        format has a stable schema regardless of fill level."""
-        if self._chunks:
-            return {k: v.copy() for k, v in self.data().items()}
-        return {
-            "x_emb": np.zeros((0, self.emb_dim), np.float32),
-            "x_feat": np.zeros((0, self.feat_dim), np.float32),
-            "domain": np.zeros(0, np.int32),
-            "action": np.zeros(0, np.int32),
-            "reward": np.zeros(0, np.float32),
-            "gate_label": np.zeros(0, np.float32),
-            "gate_mask": np.zeros(0, np.float32),
-        }
-
-    def load_state_dict(self, d: Dict[str, np.ndarray]) -> None:
-        n = len(d["action"])
-        self._chunks = [] if n == 0 else [
-            {k: np.asarray(v) for k, v in d.items()}]
-        self._cached = None
-
     def minibatches(self, rng: np.random.Generator, batch_size: int, *,
                     drop_tail: bool = False
                     ) -> Iterator[Dict[str, np.ndarray]]:

@@ -223,8 +223,9 @@ def _ope_selection() -> ExperimentSpec:
 
 @register_preset("bench_nucb_sweep")
 def _bench_nucb_sweep() -> ExperimentSpec:
-    """Bench: the neuralucb_sweep section's multi-seed Algorithm-1
-    workload (engine structure at reduced stream size)."""
+    """Unused: nothing runs this preset since its CPU benchmark section
+    went. Kept only because ``test_pre_pr10_spec_hashes_unchanged`` pins
+    its spec hash (ROADMAP C8)."""
     return ExperimentSpec(
         name="bench_nucb_sweep",
         data=DataSpec(n_samples=1200, n_slices=32),
@@ -236,8 +237,9 @@ def _bench_nucb_sweep() -> ExperimentSpec:
 
 @register_preset("bench_zoo_sweep")
 def _bench_zoo_sweep() -> ExperimentSpec:
-    """Bench: the policy_zoo_sweep section's 5-policy × seed one-
-    dispatch workload."""
+    """Unused: nothing runs this preset since its CPU benchmark section
+    went. Kept only because ``test_pre_pr10_spec_hashes_unchanged`` pins
+    its spec hash (ROADMAP C8)."""
     return ExperimentSpec(
         name="bench_zoo_sweep",
         data=DataSpec(n_samples=1200, n_slices=8),

@@ -124,8 +124,8 @@ def _run_serving_cell(plan: ExperimentPlan, *,
         queue_capacity=sv.queue_capacity, decide_batch=sv.decide_batch,
         serve_batch=sv.serve_batch,
         fail_decide_calls=sv.fail_decide_calls,
-        train_every=sv.train_every, epochs=spec.train.epochs,
-        seed=sv.seed, engines=engines, max_new=max_new)
+        train_every=sv.train_every, seed=sv.seed, engines=engines,
+        max_new=max_new)
     if engines is not None:
         metrics["decode_steps"] = engine_decode_steps(engines)
 

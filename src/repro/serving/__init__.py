@@ -1,6 +1,5 @@
 from repro.serving.engine import ServingEngine
-from repro.serving.batcher import RequestBatcher, Request
-from repro.serving.routed import RoutedServingPool
+from repro.serving.batcher import Request
 from repro.serving.async_engine import AsyncRouterEngine
 from repro.serving.policy_router import DevicePolicyRouter
 from repro.serving.faults import DecideFault, ScriptedFaults
@@ -12,8 +11,7 @@ from repro.serving.traffic import (
 )
 
 __all__ = [
-    "ServingEngine", "RequestBatcher", "Request", "RoutedServingPool",
-    "AsyncRouterEngine", "DevicePolicyRouter", "DecideFault",
-    "ScriptedFaults", "run_storm", "TRAFFIC_PATTERNS",
+    "ServingEngine", "Request", "AsyncRouterEngine", "DevicePolicyRouter",
+    "DecideFault", "ScriptedFaults", "run_storm", "TRAFFIC_PATTERNS",
     "outages_from_scenario", "wave_sizes",
 ]

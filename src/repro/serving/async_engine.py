@@ -1,6 +1,10 @@
 """High-throughput async router serving engine (DESIGN.md §12).
 
-Continuous-batching front end for any bandit router:
+Continuous-batching front end for a device router
+(:class:`~repro.serving.policy_router.DevicePolicyRouter` or anything
+with its protocol: ``decide(sample_idx=, avail=)`` and
+``update_wave(decision, served, rewards, learn_mask=)``, plus
+``end_slice`` and, for snapshots, ``state_dict``/``load_state_dict``):
 
     submit -> [bounded admission queue] -> microbatched DECIDE (one
     batched jit call per microbatch) -> per-arm fallback chain ->
@@ -32,16 +36,15 @@ Graceful degradation (the CostSavingRouter pattern, SNIPPETS.md §1):
   shed at submit with a counted drop, never an unbounded backlog.
 * Every arm has a fallback chain (default: every other arm, cheapest
   first). A request decided onto a down arm walks its chain; only a
-  fully-down chain sheds (counted). Routers that accept the live
-  availability mask (``serving_v2``) never pick a down arm to begin
-  with.
+  fully-down chain sheds (counted). ``decide`` gets the live
+  availability mask, so an availability-aware policy never picks a
+  down arm to begin with.
 * A decide-path exception is caught and counted; the microbatch degrades
   to the cheapest healthy arm and is served WITHOUT a router update (the
   router never learns from decisions it did not make).
-* Fallback-remapped rows reach the router with the arm actually served:
-  routers exposing ``action_features`` get exact relearning (features
-  recomputed for the served arm); ``serving_v2`` routers exclude the
-  rows conservatively and count them.
+* Fallback-remapped rows reach the router with the arm actually served,
+  and the router excludes them from learning (the decide's aux
+  describes the decided arm); the engine counts them.
 
 Accounting invariant (asserted by tests/test_serving_faults.py): every
 submitted request is exactly one of completed / shed-at-admission /
@@ -72,11 +75,9 @@ class _Group:
     ``i`` is ``reqs[i]``; an arm queue holds its rows' positions."""
 
     __slots__ = ("decision", "reqs", "rid", "ids", "decided", "served",
-                 "reward", "quality", "cost", "depth", "remaining",
-                 "x_emb", "x_feat", "domain")
+                 "reward", "quality", "cost", "depth", "remaining")
 
-    def __init__(self, decision, reqs, rid, ids, decided, depth,
-                 x_emb=None, x_feat=None, domain=None):
+    def __init__(self, decision, reqs, rid, ids, decided, depth):
         n = len(reqs)
         self.decision = decision      # router decision dict, or None
         self.reqs = reqs
@@ -89,17 +90,15 @@ class _Group:
         self.cost = np.zeros(n, np.float32)
         self.depth = depth            # (n,) fallback-chain depth
         self.remaining = n
-        self.x_emb, self.x_feat, self.domain = x_emb, x_feat, domain
 
 
 class AsyncRouterEngine:
-    """See module docstring. ``router`` is either the host
-    `NeuralUCBRouter` interface (``decide(x_emb, x_feat, domain)`` /
-    ``update(x_emb, x_feat, domain, decision, rewards)``) or a
-    ``serving_v2`` router (`DevicePolicyRouter`: id-addressed decide with
-    live availability, ``update_wave``). Feedback is table-replay mode
-    when ``reward_table`` is given (requests carry ``sample_idx``),
-    otherwise the pool's Eq.-1 utility mode from per-token prices."""
+    """See module docstring. ``router`` speaks the device protocol
+    (id-addressed ``decide`` with live availability, ``update_wave``);
+    a router without ``decide`` or ``update_wave`` is refused here with
+    a ``TypeError``. Feedback is table-replay mode when ``reward_table``
+    is given (requests carry ``sample_idx``), otherwise the pool's Eq.-1
+    utility mode from per-token prices."""
 
     def __init__(self, router, num_arms: int, *,
                  engines: Optional[Sequence] = None,
@@ -118,6 +117,11 @@ class AsyncRouterEngine:
                  clock: Callable[[], float] = time.monotonic,
                  fault_hook: Optional[Callable[[int], None]] = None,
                  log_capacity: Optional[int] = 10_000):
+        for need in ("decide", "update_wave"):
+            if not callable(getattr(router, need, None)):
+                raise TypeError(f"router {type(router).__name__} has no "
+                                f"{need}(); the engine speaks the device "
+                                "router protocol (DevicePolicyRouter)")
         if queue_capacity < decide_batch:
             raise ValueError("queue_capacity must be >= decide_batch "
                              f"({queue_capacity} < {decide_batch})")
@@ -164,7 +168,6 @@ class AsyncRouterEngine:
         # any decide error as fatal can say what it was
         self.last_decide_error: Optional[str] = None
         self.log = deque(maxlen=log_capacity)
-        self._serving_v2 = bool(getattr(router, "serving_v2", False))
 
     # ------------------------------------------------------------ health --
     def _arm_cost_rank(self) -> np.ndarray:
@@ -264,9 +267,9 @@ class AsyncRouterEngine:
         raise RuntimeError(f"drain stalled with {self.in_flight} in flight; "
                            f"counters={self.counters}")
 
-    def end_slice(self, epochs: int = 1):
+    def end_slice(self):
         with span("engine.end_slice"):
-            return self.router.end_slice(epochs)
+            return self.router.end_slice()
 
     # ----------------------------------------------------------- decide --
     def _decide_due(self, force: bool) -> bool:
@@ -289,11 +292,6 @@ class AsyncRouterEngine:
         with span("engine.decide", gid=gid, rows=n, wait_us=wait_s * 1e6):
             rid = np.array([r.rid for r in reqs], np.int64)
             ids = np.array([r.sample_idx for r in reqs], np.int64)
-            x_emb = x_feat = domain = None
-            if not self._serving_v2:
-                x_emb = np.stack([r.x_emb for r in reqs])
-                x_feat = np.stack([r.x_feat for r in reqs])
-                domain = np.array([r.domain for r in reqs], np.int32)
             call_idx = self.counters["decide_calls"]
             self.counters["decide_calls"] += 1
             decision = None
@@ -301,12 +299,8 @@ class AsyncRouterEngine:
             try:
                 if self.fault_hook is not None:
                     self.fault_hook(call_idx)
-                if self._serving_v2:
-                    decision = self.router.decide(
-                        sample_idx=ids,
-                        avail=self.arm_up.astype(np.float32))
-                else:
-                    decision = self.router.decide(x_emb, x_feat, domain)
+                decision = self.router.decide(
+                    sample_idx=ids, avail=self.arm_up.astype(np.float32))
                 decided = np.asarray(decision["action"], np.int32).copy()
                 self.decide_wall_s.append(time.perf_counter() - t0)
             except Exception as e:
@@ -321,8 +315,7 @@ class AsyncRouterEngine:
             depth = self._depth_of[decided]
             if decided.min() < 0:
                 served[decided < 0] = -1     # no such arm: shed
-            g = _Group(decision, reqs, rid, ids, decided, depth, x_emb,
-                       x_feat, domain)
+            g = _Group(decision, reqs, rid, ids, decided, depth)
             self._groups[gid] = g
             # one stable sort groups the rows by served arm, the shed
             # rows (served -1) first, each arm's rows in microbatch order
@@ -407,15 +400,12 @@ class AsyncRouterEngine:
         n_ok = int(np.count_nonzero(ok))
         with span("engine.finalize", gid=gid, rows=n_ok):
             if g.decision is not None and n_ok:
-                if self._serving_v2:
-                    served = np.where(ok, g.served, g.decided)
-                    learned = self.router.update_wave(
-                        g.decision, served, g.reward, learn_mask=ok)
-                    self.counters["updates"] += 1
-                    self.counters["learned"] += int(learned)
-                    self.counters["skipped_learn"] += n_ok - int(learned)
-                else:
-                    self._update_legacy(g, ok)
+                served = np.where(ok, g.served, g.decided)
+                learned = self.router.update_wave(
+                    g.decision, served, g.reward, learn_mask=ok)
+                self.counters["updates"] += 1
+                self.counters["learned"] += int(learned)
+                self.counters["skipped_learn"] += n_ok - int(learned)
             elif g.decision is None:
                 self.counters["skipped_learn"] += n_ok
             self.counters["completed"] += n_ok   # shed rows: at dispatch
@@ -429,33 +419,6 @@ class AsyncRouterEngine:
                         g.cost[ok].tolist())]
             self._log_many(recs)
             out.extend(recs)
-
-    def _update_legacy(self, g: _Group, ok: np.ndarray):
-        """Host-router feedback: slice the decision to completed rows;
-        remapped rows relearn EXACTLY when the router can recompute
-        features for the served arm, otherwise they are excluded."""
-        rows = np.flatnonzero(ok)
-        served = g.served[rows]
-        changed = served != g.decided[rows]
-        dec = {k: np.asarray(v)[rows].copy() for k, v in g.decision.items()}
-        dec["action"] = served.astype(np.int32)
-        if changed.any():
-            if hasattr(self.router, "action_features"):
-                sub = rows[changed]
-                dec["g"][changed] = self.router.action_features(
-                    g.x_emb[sub], g.x_feat[sub], g.domain[sub],
-                    served[changed])
-            else:
-                keep = ~changed
-                self.counters["skipped_learn"] += int(changed.sum())
-                rows, served = rows[keep], served[keep]
-                dec = {k: v[keep] for k, v in dec.items()}
-                if rows.size == 0:
-                    return
-        self.router.update(g.x_emb[rows], g.x_feat[rows], g.domain[rows],
-                           dec, g.reward[rows])
-        self.counters["updates"] += 1
-        self.counters["learned"] += int(rows.size)
 
     # ------------------------------------------------------- accounting --
     def _log_many(self, recs: List[Dict]):

@@ -1,4 +1,4 @@
-"""Request batching: group pending requests per target model, pad to the
+"""Request batching: group decided rows per target model, pad to the
 engine's batch granularity, preserve submission order within a group.
 
 Flush timeouts: with ``flush_timeout`` set, a queue becomes *ready* when
@@ -11,13 +11,12 @@ undersized batch; deriving readiness from arrival timestamps makes an
 empty epoch leave nothing armed (see the regression test in
 tests/test_serving.py).
 
-The selection rule lives in :class:`ArmQueues` alone: per-target FIFO
-queues of row chunks, which ``RequestBatcher`` fills with ``Request``
-objects one at a time and the async engine fills with a decided
-microbatch's row indices, one chunk per arm. A batch's rows are
-right-padded to one width (:func:`pad_rows`); ``left_align`` moves each
-row's prompt to the right end for the decode engine, so that every
-prompt ends at the same step and no row replays pads as tokens."""
+The selection rule lives in :class:`ArmQueues`: per-target FIFO queues
+of row chunks, which the async engine fills with a decided microbatch's
+row indices, one chunk per arm. A batch's rows are right-padded to one
+width (:func:`pad_rows`); ``left_align`` moves each row's prompt to the
+right end for the decode engine, so that every prompt ends at the same
+step and no row replays pads as tokens."""
 from __future__ import annotations
 
 import dataclasses
@@ -34,9 +33,6 @@ _counter = itertools.count()
 @dataclasses.dataclass(slots=True)
 class Request:
     tokens: np.ndarray                      # (S,) prompt token ids
-    x_emb: Optional[np.ndarray] = None      # router features
-    x_feat: Optional[np.ndarray] = None
-    domain: int = 0
     sample_idx: int = -1                    # replay-table row (quality/cost)
     rid: int = dataclasses.field(default_factory=_counter.__next__)
 
@@ -56,13 +52,13 @@ def left_align(tokens: np.ndarray, lengths, pad_token: int = 0):
     return out, start.astype(np.int32)
 
 
-def pad_rows(prompts: Sequence[np.ndarray], pad_to_multiple: int = 4,
-             pad_token: int = 0) -> np.ndarray:
-    """Prompts -> one right-padded (B, S) int32 matrix, S the longest
-    prompt rounded up to ``pad_to_multiple``."""
+def pad_rows(prompts: Sequence[np.ndarray], pad_to_multiple: int = 4
+             ) -> np.ndarray:
+    """Prompts -> one right-padded (B, S) int32 matrix (pads 0), S the
+    longest prompt rounded up to ``pad_to_multiple``."""
     max_len = max(len(t) for t in prompts)
     max_len = -(-max_len // pad_to_multiple) * pad_to_multiple
-    toks = np.full((len(prompts), max_len), pad_token, np.int32)
+    toks = np.zeros((len(prompts), max_len), np.int32)
     for i, t in enumerate(prompts):
         toks[i, :len(t)] = t
     return toks
@@ -162,30 +158,3 @@ class ArmQueues:
             if t != target:
                 age[t] += 1
         return target, parts
-
-
-class RequestBatcher(ArmQueues):
-    """:class:`ArmQueues` over ``Request`` objects, each arriving on its
-    own ``clock()`` reading; a batch comes with its padded prompts."""
-
-    def __init__(self, max_batch: int = 8, pad_to_multiple: int = 4,
-                 pad_token: int = 0, max_starve: int = 4,
-                 flush_timeout: Optional[float] = None,
-                 clock: Callable[[], float] = time.monotonic):
-        super().__init__(max_batch, max_starve, flush_timeout, clock)
-        self.pad_to_multiple = pad_to_multiple
-        self.pad_token = pad_token
-
-    def submit(self, target: int, req: Request) -> None:
-        self.push(target, [req], self.clock())
-
-    def next_batch(self, force: bool = False):
-        """:meth:`ArmQueues.pop` as (target, requests, padded_tokens
-        (B, S)), or None."""
-        got = self.pop(force)
-        if got is None:
-            return None
-        target, parts = got
-        reqs = [r for _, rows in parts for r in rows]
-        return target, reqs, pad_rows([r.tokens for r in reqs],
-                                      self.pad_to_multiple, self.pad_token)

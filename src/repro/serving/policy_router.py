@@ -265,8 +265,6 @@ class DevicePolicyRouter:
     is never lost and decide staleness is bounded by
     ``train_epoch - committed_epoch <= max_train_lag``."""
 
-    serving_v2 = True
-
     def __init__(self, policy: BanditPolicy, hypers: Any, tables: Dict,
                  *, seed: int = 0, slice_width: int = 256,
                  capacity_slices: int = 256, batch_size: int = 256,
@@ -385,8 +383,7 @@ class DevicePolicyRouter:
             jax.block_until_ready(packed)
 
     # ----------------------------------------------------------- DECIDE --
-    def decide(self, x_emb=None, x_feat=None, domain=None, *,
-               sample_idx=None, avail=None) -> Dict:
+    def decide(self, *, sample_idx, avail=None) -> Dict:
         """Decide for a microbatch of replay sample ids. ``avail`` is the
         engine's live arm-health mask ((K,) float, 1 = up); None or
         all-up takes the stationary fast trace (bit-identical to the sim
@@ -500,9 +497,10 @@ class DevicePolicyRouter:
     # ------------------------------------------------- TRAIN + REBUILD --
     def end_slice(self, epochs: Optional[int] = None) -> None:
         """Replay-SGD + A^-1 rebuild over the ring (one jitted dispatch);
-        ends the warm phase. ``epochs`` is accepted for interface parity
-        with the host router — the SGD budget here is the constructor's
-        static ``train_chunks``.
+        ends the warm phase. The SGD budget is the constructor's static
+        ``train_chunks``; ``epochs`` is accepted and ignored (no caller in
+        ``src/`` passes it; the benchmark's ``RouterProxy`` forwards its
+        own ``None``, a debt in ROADMAP C2).
 
         ``max_train_lag == 0``: dispatch and BLOCK (the train pause owns
         its own wall time instead of bleeding into the next decide's

@@ -4,7 +4,7 @@ the async engine at scale.
 One call = one storm: a traffic pattern shapes the request budget into
 arrival waves, scripted (or scenario-derived) outage windows toggle arm
 health at wave boundaries, and the engine's decide-latency samples and
-counters roll up into the `BENCH_serving.json` / `serving_storm`-preset
+counters roll up into the `serving_storm` / `physical_pool` presets'
 metrics — p50/p99 decide latency, sustained requests/s, shed/fallback
 accounting, and the zero-lost-requests invariant.
 """
@@ -31,7 +31,7 @@ def run_storm(env, router, *, requests: int, waves: int,
               queue_capacity: int = 4096, decide_batch: int = 256,
               serve_batch: int = 256,
               fail_decide_calls: Sequence[int] = (),
-              train_every: int = 0, epochs: int = 1, seed: int = 0,
+              train_every: int = 0, seed: int = 0,
               log_capacity: Optional[int] = 1024,
               engines: Optional[Sequence] = None,
               max_new: int = 8) -> Dict:
@@ -44,7 +44,8 @@ def run_storm(env, router, *, requests: int, waves: int,
     ``engines`` (one per arm, the armpool's semi-real mode) makes the
     serve stage actually execute each request — ``max_new`` generated
     tokens per request — while feedback stays table-replay.
-    Returns the metrics dict (see `BENCH_serving.json` schema, README).
+    Returns the metrics dict (the keys below; the presets' artifacts
+    carry it under ``serving``).
     """
     reward = np.asarray(env.reward)
     quality = np.asarray(env.quality)
@@ -99,7 +100,7 @@ def run_storm(env, router, *, requests: int, waves: int,
                                 + engine.counters["shed_no_arm"]) - shed0
             if train_every and (w + 1) % train_every == 0:
                 ts = time.perf_counter()
-                engine.end_slice(epochs)
+                engine.end_slice()
                 stalls.append((len(engine.decide_wall_s),
                                time.perf_counter() - ts))
     wall = time.perf_counter() - t0

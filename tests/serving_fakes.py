@@ -1,11 +1,11 @@
 """Deterministic in-memory router fakes for the async-engine tests.
 
-`FakeRouter` implements the ``serving_v2`` protocol (id-addressed
+`FakeRouter` implements the engine's router protocol (id-addressed
 ``decide`` with a live availability mask, ``update_wave`` feedback) with
 pure numpy — no jit, no device state — so the engine/fault tests can
 exercise queueing, fallback, and accounting semantics in milliseconds.
-`BlindFakeRouter` ignores the availability mask (``availability_aware``
-off), forcing the engine's fallback chains to do the remapping.
+`BlindFakeRouter` ignores the availability mask, forcing the engine's
+fallback chains to do the remapping.
 """
 import numpy as np
 
@@ -14,16 +14,13 @@ class FakeRouter:
     """Always prefers ``prefer``; with an availability mask, falls back
     to the lowest-index healthy arm itself (availability-aware)."""
 
-    serving_v2 = True
-
     def __init__(self, num_arms: int, prefer: int = 0):
         self.num_actions = int(num_arms)
         self.prefer = int(prefer)
         self.update_calls = []          # learned count per update_wave
         self.slices = 0
 
-    def decide(self, x_emb=None, x_feat=None, domain=None, *,
-               sample_idx=None, avail=None):
+    def decide(self, *, sample_idx, avail=None):
         ids = np.asarray(sample_idx, np.int64).reshape(-1)
         a = np.full(ids.size, self.prefer, np.int32)
         if avail is not None:
@@ -41,7 +38,7 @@ class FakeRouter:
         self.update_calls.append(int(learn.sum()))
         return int(learn.sum())
 
-    def end_slice(self, epochs=None):
+    def end_slice(self):
         self.slices += 1
 
 
@@ -49,8 +46,7 @@ class BlindFakeRouter(FakeRouter):
     """Ignores the availability mask — decides onto ``prefer`` even when
     it is down, so the engine's fallback chain must remap."""
 
-    def decide(self, x_emb=None, x_feat=None, domain=None, *,
-               sample_idx=None, avail=None):
+    def decide(self, *, sample_idx, avail=None):
         ids = np.asarray(sample_idx, np.int64).reshape(-1)
         return {"action": np.full(ids.size, self.prefer, np.int32),
                 "ids": ids, "aux": {}, "n": ids.size}

@@ -1,50 +1,61 @@
-"""Serving substrate: batcher semantics (incl. the round-robin-aging
-starvation fix), engine generate, routed pool, and serving-vs-protocol
-parity: `RoutedServingPool.submit` over a full replay stream must
-reproduce `core.protocol.run_protocol` rewards and action histograms
-when given the same quality table and cost vector."""
+"""Serving substrate: the per-arm queues' selection rule (incl. the
+round-robin-aging starvation fix and arrival-armed flush deadlines),
+prompt padding, and engine generate."""
 import dataclasses
 
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.policy import NeuralUCBRouter
-from repro.core.protocol import run_protocol
-from repro.core.utilitynet import UtilityNetConfig
-from repro.data.routerbench import RouterBenchSim
-from repro.serving import Request, RequestBatcher, RoutedServingPool, ServingEngine
+from repro.serving import Request, ServingEngine
+from repro.serving.batcher import ArmQueues, pad_rows
+
+
+def _submit(q, target, req):
+    """One request into ``q`` as its own chunk, arriving now."""
+    q.push(target, [req], q.clock())
+
+
+def _next(q, force=False):
+    """``q.pop`` as (target, requests in arrival order), or None."""
+    got = q.pop(force)
+    if got is None:
+        return None
+    target, parts = got
+    return target, [r for _, rows in parts for r in rows]
 
 
 def test_batcher_groups_and_pads():
-    b = RequestBatcher(max_batch=2, pad_to_multiple=4)
+    b = ArmQueues(max_batch=2)
     r1 = Request(tokens=np.array([1, 2, 3]))
     r2 = Request(tokens=np.array([1, 2, 3, 4, 5]))
     r3 = Request(tokens=np.array([9]))
-    b.submit(0, r1)
-    b.submit(0, r2)
-    b.submit(1, r3)
+    _submit(b, 0, r1)
+    _submit(b, 0, r2)
+    _submit(b, 1, r3)
     assert b.pending() == 3
-    target, reqs, toks = b.next_batch()
-    assert target == 0 and len(reqs) == 2
+    target, reqs = _next(b)
+    assert target == 0 and reqs == [r1, r2]     # submission order kept
+    toks = pad_rows([r.tokens for r in reqs], 4)
     assert toks.shape == (2, 8)  # padded to multiple of 4 over max len 5
     assert list(toks[0][:3]) == [1, 2, 3] and toks[0][3] == 0
-    target2, reqs2, toks2 = b.next_batch()
-    assert target2 == 1 and toks2.shape == (1, 4)
-    assert b.next_batch() is None
+    target2, reqs2 = _next(b)
+    assert target2 == 1 and reqs2 == [r3]
+    assert pad_rows([r3.tokens], 4).shape == (1, 4)
+    assert _next(b) is None
 
 
 def test_batcher_minority_queue_never_starves():
-    """Regression: next_batch popped the fullest queue, so a minority
+    """Regression: the queues popped the fullest target, so a minority
     target starved indefinitely whenever a majority queue refilled above
     it every round. Round-robin aging bounds the wait."""
-    b = RequestBatcher(max_batch=2, pad_to_multiple=1)
-    b.submit(1, Request(tokens=np.array([7])))      # lone minority request
+    b = ArmQueues(max_batch=2)
+    _submit(b, 1, Request(tokens=np.array([7])))    # lone minority request
     served = []
     for _ in range(8):                              # steady majority load
         for _ in range(3):
-            b.submit(0, Request(tokens=np.array([1, 2])))
-        target, _, _ = b.next_batch()
+            _submit(b, 0, Request(tokens=np.array([1, 2])))
+        target, _ = _next(b)
         served.append(target)
     assert 1 in served, f"minority target starved: {served}"
     # the wait is bounded at max_starve rounds even under growing backlog
@@ -56,47 +67,46 @@ def test_batcher_minority_queue_never_starves():
 def test_batcher_age_resets_after_service():
     """A served queue's age resets — it cannot immediately leapfrog a
     fuller queue again on pure age."""
-    b = RequestBatcher(max_batch=1, pad_to_multiple=1)
-    b.submit(0, Request(tokens=np.array([1])))
-    b.submit(0, Request(tokens=np.array([1])))
-    b.submit(1, Request(tokens=np.array([2])))
-    assert b.next_batch()[0] == 0       # fullest first
-    assert b.next_batch()[0] == 1       # aged minority wins the tie
-    assert b.next_batch()[0] == 0
-    assert b.next_batch() is None
+    b = ArmQueues(max_batch=1)
+    _submit(b, 0, Request(tokens=np.array([1])))
+    _submit(b, 0, Request(tokens=np.array([1])))
+    _submit(b, 1, Request(tokens=np.array([2])))
+    assert _next(b)[0] == 0             # fullest first
+    assert _next(b)[0] == 1             # aged minority wins the tie
+    assert _next(b)[0] == 0
+    assert _next(b) is None
 
 
 def test_batcher_flush_deadline_armed_by_arrival_not_epoch():
-    """Regression (ISSUE satellite): the flush deadline used to be an
-    epoch timer armed at the last flush, so after an empty-then-burst
-    arrival the stale deadline had already expired and the first batch
-    flushed immediately, undersized. Deadlines must arm per request
-    from its OWN arrival time: an idle period leaves nothing armed."""
+    """Regression: the flush deadline used to be an epoch timer armed at
+    the last flush, so after an empty-then-burst arrival the stale
+    deadline had already expired and the first batch flushed
+    immediately, undersized. Deadlines must arm per request from its
+    OWN arrival time: an idle period leaves nothing armed."""
     now = [0.0]
-    b = RequestBatcher(max_batch=4, pad_to_multiple=1, flush_timeout=1.0,
-                       clock=lambda: now[0])
-    assert b.next_batch() is None
+    b = ArmQueues(max_batch=4, flush_timeout=1.0, clock=lambda: now[0])
+    assert _next(b) is None
     now[0] = 50.0                    # long idle gap, then a burst
-    b.submit(0, Request(tokens=np.array([1])))
-    b.submit(0, Request(tokens=np.array([2])))
+    _submit(b, 0, Request(tokens=np.array([1])))
+    _submit(b, 0, Request(tokens=np.array([2])))
     # stale-deadline bug: a deadline armed at t=0 expired long ago and
     # this pair would flush here, undersized
-    assert b.next_batch() is None
+    assert _next(b) is None
     now[0] = 50.4
-    b.submit(0, Request(tokens=np.array([3])))
-    b.submit(0, Request(tokens=np.array([4])))
-    target, reqs, _ = b.next_batch()         # full batch: always ready
+    _submit(b, 0, Request(tokens=np.array([3])))
+    _submit(b, 0, Request(tokens=np.array([4])))
+    target, reqs = _next(b)                  # full batch: always ready
     assert target == 0 and len(reqs) == 4
     # a straggler flushes when ITS OWN age crosses the window...
-    b.submit(0, Request(tokens=np.array([5])))
+    _submit(b, 0, Request(tokens=np.array([5])))
     now[0] = 51.3
-    assert b.next_batch() is None            # 0.9s old < 1.0s window
+    assert _next(b) is None                  # 0.9s old < 1.0s window
     now[0] = 51.5
-    _, reqs, _ = b.next_batch()
+    _, reqs = _next(b)
     assert len(reqs) == 1
     # ...and force (drain) overrides the window
-    b.submit(0, Request(tokens=np.array([6])))
-    _, reqs, _ = b.next_batch(force=True)
+    _submit(b, 0, Request(tokens=np.array([6])))
+    _, reqs = _next(b, force=True)
     assert len(reqs) == 1
     assert b.pending() == 0
 
@@ -105,16 +115,15 @@ def test_batcher_flush_timeout_selects_among_ready_queues_only():
     """A queue inside its flush window is waiting, not starving: it is
     skipped (without aging toward starvation service) until ready."""
     now = [0.0]
-    b = RequestBatcher(max_batch=4, pad_to_multiple=1, flush_timeout=1.0,
-                       clock=lambda: now[0])
-    b.submit(0, Request(tokens=np.array([1])))   # partial, in-window
+    b = ArmQueues(max_batch=4, flush_timeout=1.0, clock=lambda: now[0])
+    _submit(b, 0, Request(tokens=np.array([1])))   # partial, in-window
     for _ in range(4):
-        b.submit(1, Request(tokens=np.array([2])))
-    target, reqs, _ = b.next_batch()
+        _submit(b, 1, Request(tokens=np.array([2])))
+    target, reqs = _next(b)
     assert target == 1 and len(reqs) == 4        # the full queue wins
-    assert b.next_batch() is None                # 0 still inside window
+    assert _next(b) is None                      # 0 still inside window
     now[0] = 2.0
-    assert b.next_batch()[0] == 0
+    assert _next(b)[0] == 0
 
 
 @pytest.fixture(scope="module")
@@ -136,153 +145,3 @@ def test_engine_deterministic_greedy(tiny_engine):
     a, _ = tiny_engine.generate(toks, max_new=3)
     b, _ = tiny_engine.generate(toks, max_new=3)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_routed_pool_round_trip():
-    cfgs = [dataclasses.replace(get_config(a).reduced(), dtype="float32")
-            for a in ("llama3_2_3b", "mamba2_130m")]
-    engines = [ServingEngine(c, seed=i, max_seq=32)
-               for i, c in enumerate(cfgs)]
-    ucfg = UtilityNetConfig(emb_dim=16, num_actions=2, num_domains=3)
-    router = NeuralUCBRouter(ucfg, seed=0, batch_size=8)
-    qt = np.random.default_rng(0).uniform(0.3, 0.9, (50, 2)).astype(np.float32)
-    pool = RoutedServingPool(router, engines, [1e-4, 1e-6],
-                             quality_table=qt, c_max=0.05, max_batch=4)
-    rng = np.random.default_rng(1)
-    reqs = [Request(tokens=rng.integers(1, 50, size=5),
-                    x_emb=rng.normal(size=16).astype(np.float32),
-                    x_feat=rng.normal(size=4).astype(np.float32),
-                    domain=int(rng.integers(0, 3)), sample_idx=i)
-            for i in range(5)]
-    out = pool.submit(reqs)
-    assert len(out) == 5
-    for o in out:
-        assert 0 <= o["reward"] <= 1
-        assert o["action"] in (0, 1)
-        assert o["cost"] > 0
-    assert len(router.buffer) == 5
-
-
-def test_serving_pool_matches_protocol_replay():
-    """Serving-parity (ISSUE): a RoutedServingPool driven slice-by-slice
-    over a full replay stream must reproduce `run_protocol`'s NeuralUCB
-    rewards and action histograms, given the same quality table and a
-    cost table derived from the pool's own per-token prices. The cost
-    bridge: `generate(max_new=8)` always emits 8 tokens, so request cost
-    is cost_per_token * (prompt_len + 8) — the env's cost table is built
-    from exactly that expression."""
-    K, n, T = 2, 48, 3
-    rng = np.random.default_rng(0)
-    plen = rng.integers(4, 9, size=n)
-    cpt = np.array([2e-4, 1e-5])
-    cost = (cpt[None] * (plen[:, None] + 8)).astype(np.float32)
-    quality = rng.uniform(0.2, 0.95, size=(n, K)).astype(np.float32)
-    data = {
-        "domain": rng.integers(0, 3, size=n).astype(np.int32),
-        "topic": rng.normal(size=(n, 32)).astype(np.float32),
-        "difficulty": np.zeros(n, np.float32),
-        "prompt_tokens": plen.astype(np.float32),
-        "quality": quality,
-        "cost": cost,
-        "x_feat": rng.normal(size=(n, 4)).astype(np.float32),
-        "model_names": np.array(["a", "b"]),
-    }
-    henv = RouterBenchSim(seed=0, n_slices=T, cost_lambda=1.0, data=data)
-    ucfg = UtilityNetConfig(emb_dim=henv.x_emb.shape[1], num_actions=K,
-                            num_domains=3)
-
-    # reference: the protocol host loop
-    host = run_protocol(henv, {"nucb": NeuralUCBRouter(
-        ucfg, seed=0, batch_size=16)}, epochs=2, verbose=False)["nucb"]
-
-    # system under test: the serving pool over the identical stream
-    cfgs = [dataclasses.replace(get_config(a).reduced(), dtype="float32")
-            for a in ("llama3_2_3b", "mamba2_130m")]
-    engines = [ServingEngine(c, seed=i, max_seq=32)
-               for i, c in enumerate(cfgs)]
-    pool = RoutedServingPool(
-        NeuralUCBRouter(ucfg, seed=0, batch_size=16), engines, cpt,
-        quality_table=quality, c_max=henv.c_max, cost_lambda=1.0,
-        max_batch=8)
-    tok_rng = np.random.default_rng(1)
-    for t in range(T):
-        b = henv.slice_batch(t)
-        reqs = [Request(tokens=tok_rng.integers(1, 50, size=int(plen[i])),
-                        x_emb=henv.x_emb[i], x_feat=data["x_feat"][i],
-                        domain=int(data["domain"][i]), sample_idx=int(i))
-                for i in b["idx"]]
-        recs = pool.submit(reqs)
-        pool.end_slice(epochs=2)
-        # per-slice parity: rewards and the action histogram
-        np.testing.assert_allclose(
-            np.mean([r["reward"] for r in recs]),
-            host["avg_reward"][t], rtol=1e-5, atol=1e-5,
-            err_msg=f"slice {t} avg reward")
-        hist = np.bincount([r["action"] for r in recs], minlength=K)
-        np.testing.assert_array_equal(hist, host["action_hist"][t],
-                                      err_msg=f"slice {t} action hist")
-        np.testing.assert_allclose(
-            np.mean([r["cost"] for r in recs]), host["avg_cost"][t],
-            rtol=1e-5, err_msg=f"slice {t} avg cost")
-
-
-def test_pool_default_c_max_uses_actual_max_seq():
-    """Regression (ISSUE satellite): the default c_max normalized by a
-    fixed 4096-token horizon while the engines cap sequences at
-    max_seq — every realizable cost then normalized to < max_seq/4096
-    of the range, compressing rewards toward quality-only and erasing
-    cost discrimination between arms. The default must derive from the
-    pool's actual max_seq (explicit c_max still wins)."""
-    import types
-    engines = [types.SimpleNamespace(max_seq=256),
-               types.SimpleNamespace(max_seq=64)]
-    cpt = [1e-4, 1e-6]
-    pool = RoutedServingPool(object(), engines, cpt)
-    assert pool.c_max == pytest.approx(1e-4 * 256)
-    explicit = RoutedServingPool(object(), engines, cpt, c_max=0.05)
-    assert explicit.c_max == 0.05
-    # realizable cost at the cap now reaches the top of the normalized
-    # range instead of 256/4096 of it
-    assert 1e-4 * max(e.max_seq for e in engines) / pool.c_max == \
-        pytest.approx(1.0)
-
-
-def test_routed_pool_log_is_bounded():
-    """Regression (PR-5 ISSUE): ``pool.log`` grew without bound under
-    sustained traffic. It must be a capped deque keeping the most
-    recent records, counting evictions, with ``log_capacity=None`` as
-    the explicit unbounded opt-out."""
-    import types
-    engines = [types.SimpleNamespace(max_seq=64)]
-    pool = RoutedServingPool(object(), engines, [1e-4], log_capacity=8)
-    assert pool.log.maxlen == 8
-    assert pool.dropped_log_records == 0
-
-    unbounded = RoutedServingPool(object(), engines, [1e-4],
-                                  log_capacity=None)
-    assert unbounded.log.maxlen is None
-    with pytest.raises(ValueError, match="log_capacity"):
-        RoutedServingPool(object(), engines, [1e-4], log_capacity=0)
-
-
-def test_routed_pool_submit_counts_dropped_records():
-    """End-to-end: submit() itself maintains the eviction counter."""
-    cfgs = [dataclasses.replace(get_config(a).reduced(), dtype="float32")
-            for a in ("llama3_2_3b",)]
-    engines = [ServingEngine(cfgs[0], seed=0, max_seq=32)]
-    ucfg = UtilityNetConfig(emb_dim=16, num_actions=1, num_domains=3)
-    router = NeuralUCBRouter(ucfg, seed=0, batch_size=8)
-    pool = RoutedServingPool(router, engines, [1e-4], c_max=0.05,
-                             max_batch=4, log_capacity=3)
-    rng = np.random.default_rng(2)
-    reqs = [Request(tokens=rng.integers(1, 50, size=5),
-                    x_emb=rng.normal(size=16).astype(np.float32),
-                    x_feat=rng.normal(size=4).astype(np.float32),
-                    domain=int(rng.integers(0, 3)), sample_idx=-1)
-            for i in range(5)]
-    pool.submit(reqs)
-    assert len(pool.log) == 3
-    assert pool.dropped_log_records == 2
-    pool.submit(reqs)
-    assert len(pool.log) == 3
-    assert pool.dropped_log_records == 7

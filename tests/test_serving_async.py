@@ -1,24 +1,23 @@
-"""Async serving engine correctness (ISSUE tentpole): microbatched
-continuous batching, admission control, and the two parity pins —
+"""Async serving engine correctness: microbatched continuous batching,
+admission control, the engine's construction rules (router protocol,
+default ``c_max``, bounded log), and the two parity pins —
 
 * `DevicePolicyRouter` driven one wave per slice reproduces
   `run_policy_device` BIT-EXACTLY (same PRNG discipline, same jitted
   policy callbacks, state device-resident throughout), and
-* the microbatched async engine over the host `NeuralUCBRouter`
-  reproduces the synchronous `RoutedServingPool` decision-for-decision
-  on the same request stream.
+* the same holds with `AsyncRouterEngine` in front of the router, for
+  four members of the policy zoo.
 
 Plus snapshot/restore round-trips: serve N, snapshot, kill, restore,
 serve N more — identical to the uninterrupted run."""
 import collections
-import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config
 from repro.core.policy import NeuralUCBRouter
 from repro.core.utilitynet import UtilityNetConfig
 from repro.data.routerbench import RouterBenchSim
@@ -26,8 +25,6 @@ from repro.serving import (
     AsyncRouterEngine,
     DevicePolicyRouter,
     Request,
-    RoutedServingPool,
-    ServingEngine,
 )
 from repro.serving import policy_router as pr
 from repro.sim import DeviceReplayEnv, make_policy, run_policy_device
@@ -105,7 +102,74 @@ def test_decide_flush_holds_partial_microbatches():
     assert eng.check_accounting()["lost"] == 0
 
 
+def test_engine_refuses_router_without_update_wave():
+    """The engine speaks one router protocol; a host router (``decide``
+    over features, ``update``) is refused at construction, naming the
+    missing method, not mid-pump."""
+    ucfg = UtilityNetConfig(emb_dim=16, num_actions=2, num_domains=3)
+    with pytest.raises(TypeError, match="update_wave"):
+        AsyncRouterEngine(NeuralUCBRouter(ucfg, seed=0), 2,
+                          reward_table=np.zeros((4, 2), np.float32))
+
+
+def test_engine_default_c_max_uses_actual_max_seq():
+    """Regression: the default c_max normalized by a fixed 4096-token
+    horizon while the engines cap sequences at max_seq — every
+    realizable cost then normalized to < max_seq/4096 of the range,
+    compressing rewards toward quality-only and erasing cost
+    discrimination between arms. The default must derive from the
+    engines' actual max_seq (explicit c_max still wins)."""
+    engines = [types.SimpleNamespace(max_seq=256),
+               types.SimpleNamespace(max_seq=64)]
+    cpt = [1e-4, 1e-6]
+    eng = AsyncRouterEngine(FakeRouter(2), 2, engines=engines,
+                            cost_per_token=cpt)
+    assert eng.c_max == pytest.approx(1e-4 * 256)
+    explicit = AsyncRouterEngine(FakeRouter(2), 2, engines=engines,
+                                 cost_per_token=cpt, c_max=0.05)
+    assert explicit.c_max == 0.05
+    # realizable cost at the cap reaches the top of the normalized
+    # range instead of 256/4096 of it
+    assert 1e-4 * max(e.max_seq for e in engines) / eng.c_max == \
+        pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("cap", [8, None], ids=["cap8", "unbounded"])
+def test_engine_log_keeps_newest_records(cap):
+    """Regression: a serving log grew without bound under sustained
+    traffic. ``log`` is a capped deque of the newest records (queue-full
+    sheds included), ``dropped_log_records`` counts every eviction
+    exactly, and ``log_capacity=None`` keeps everything."""
+    _, eng = _fake_engine(log_capacity=cap, queue_capacity=32)
+    for n in (40, 20):                  # 60 records; the first wave sheds 8
+        admitted, _ = eng.submit(
+            [Request(tokens=TOK, sample_idx=i) for i in range(n)])
+        last = eng.pump() + eng.drain()
+        assert len(last) == admitted
+    assert eng.counters["shed_queue_full"] == 8
+    assert eng.log.maxlen == cap
+    if cap is None:
+        assert len(eng.log) == 60
+        assert eng.counters["dropped_log_records"] == 0
+    else:
+        assert list(eng.log) == last[-cap:]
+        assert eng.counters["dropped_log_records"] == 60 - cap
+
+
 # ---------------------------------------------------- sim bit-parity --
+def _assert_scan_state(router, state, key):
+    """The router's PRNG key and every state leaf equal the scan's, bit
+    for bit."""
+    np.testing.assert_array_equal(np.asarray(router._key),
+                                  np.asarray(key), err_msg="PRNG key")
+    ref = jax.tree_util.tree_leaves(state)
+    got = jax.tree_util.tree_leaves(router.state)
+    assert len(ref) == len(got)
+    for i, (a, b) in enumerate(zip(ref, got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"state leaf {i}")
+
+
 def test_device_router_bit_parity_with_sim_scan():
     """One serving wave per slice through `DevicePolicyRouter` ==
     `run_policy_device`: identical per-slice action histograms and
@@ -134,15 +198,49 @@ def test_device_router_bit_parity_with_sim_scan():
         router.update_wave(dec, dec["action"], reward[ids, dec["action"]])
         router.end_slice()
 
-    np.testing.assert_array_equal(np.asarray(router._key),
-                                  np.asarray(key), err_msg="PRNG key")
-    ref = jax.tree_util.tree_leaves(state)
-    got = jax.tree_util.tree_leaves(router.state)
-    assert len(ref) == len(got)
-    for i, (a, b) in enumerate(zip(ref, got)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=f"state leaf {i}")
+    _assert_scan_state(router, state, key)
 
+
+@pytest.mark.parametrize("name", ["neuralucb", "linucb", "neural_ts",
+                                  "eps_greedy"])
+def test_engine_over_device_router_matches_sim_scan(name):
+    """The served path end to end: `AsyncRouterEngine` in front of a
+    `DevicePolicyRouter`, one wave per slice (``decide_batch`` = the
+    slice width, ``end_slice`` after each wave), reproduces
+    `run_policy_device` — identical per-slice action histograms and
+    BIT-IDENTICAL final state and PRNG key. The engine's columns, serve
+    grouping and table write-back must hand the router exactly the rows,
+    arms and rewards the scan feeds itself."""
+    henv, env = _replay_env()
+    T, S = henv.n_slices, 16
+    cfg = UtilityNetConfig(emb_dim=env.x_emb.shape[1], num_actions=env.K)
+    pol, hyp = make_policy(name, env, cfg)
+    res, state, key = run_policy_device(
+        env, pol, hyp, seed=0, train_steps=32, batch_size=16,
+        return_state=True)
+
+    router = DevicePolicyRouter(pol, hyp, _tables(env), seed=0,
+                                slice_width=S, capacity_slices=T,
+                                batch_size=16, train_chunks=1)
+    eng = AsyncRouterEngine(router, env.K,
+                            reward_table=np.asarray(env.reward),
+                            quality_table=np.asarray(env.quality),
+                            cost_table=np.asarray(env.cost),
+                            decide_batch=S, serve_batch=S)
+    for t in range(T):
+        ids = henv.slice_batch(t)["idx"]
+        assert ids.size == S
+        eng.submit([Request(tokens=TOK, sample_idx=int(i)) for i in ids])
+        recs = [r for r in eng.pump() + eng.drain() if r["status"] == "ok"]
+        assert len(recs) == S and eng.counters["decide_calls"] == t + 1
+        np.testing.assert_array_equal(
+            np.bincount([r["action"] for r in recs], minlength=env.K),
+            res["action_hist"][t], err_msg=f"slice {t} actions")
+        eng.end_slice()
+    assert eng.counters["learned"] == T * S
+    assert eng.check_accounting()["lost"] == 0
+
+    _assert_scan_state(router, state, key)
 
 
 # ------------------------------------------ one crossing each way --
@@ -302,58 +400,6 @@ def test_async_train_overlap_staleness_bounded():
     assert dec["action"].shape == ids.shape
 
 
-# ----------------------------------------------------- pool parity --
-def test_async_engine_matches_sync_pool_decisions():
-    """The microbatched async engine over the host router reproduces the
-    synchronous `RoutedServingPool` decision-for-decision: same request
-    stream, same seeds, decide_batch == wave size (so both consume the
-    router's numpy PRNG stream in identical draws)."""
-    K, n, waves, per = 2, 64, 3, 16
-    rng = np.random.default_rng(0)
-    qt = rng.uniform(0.3, 0.9, (n, K)).astype(np.float32)
-    cpt = [1e-4, 1e-6]
-    ucfg = UtilityNetConfig(emb_dim=16, num_actions=K, num_domains=3)
-
-    cfgs = [dataclasses.replace(get_config(a).reduced(), dtype="float32")
-            for a in ("llama3_2_3b", "mamba2_130m")]
-    engines = [ServingEngine(c, seed=i, max_seq=32)
-               for i, c in enumerate(cfgs)]
-    pool = RoutedServingPool(NeuralUCBRouter(ucfg, seed=0, batch_size=16),
-                             engines, cpt, quality_table=qt, c_max=0.05,
-                             max_batch=8)
-    eng = AsyncRouterEngine(NeuralUCBRouter(ucfg, seed=0, batch_size=16),
-                            K, cost_per_token=cpt, quality_table=qt,
-                            c_max=0.05, decide_batch=per, serve_batch=8,
-                            max_new=8)
-
-    feat_rng = np.random.default_rng(1)
-    for w in range(waves):
-        feats = [(feat_rng.normal(size=16).astype(np.float32),
-                  feat_rng.normal(size=4).astype(np.float32),
-                  int(feat_rng.integers(0, 3)),
-                  int(feat_rng.integers(0, n)),
-                  feat_rng.integers(1, 50, size=5))
-                 for _ in range(per)]
-        mk = lambda: [Request(tokens=t, x_emb=e, x_feat=f, domain=d,  # noqa: E731
-                              sample_idx=s) for e, f, d, s, t in feats]
-        pool_recs = pool.submit(mk())
-        eng.submit(mk())
-        async_recs = [r for r in eng.pump() + eng.drain()
-                      if r["status"] == "ok"]
-        assert len(async_recs) == per
-        np.testing.assert_array_equal(
-            [r["action"] for r in async_recs],
-            [r["action"] for r in pool_recs],
-            err_msg=f"wave {w} decisions diverge")
-        np.testing.assert_allclose(
-            [r["reward"] for r in async_recs],
-            [r["reward"] for r in pool_recs], rtol=1e-6,
-            err_msg=f"wave {w} rewards diverge")
-        pool.end_slice(epochs=2)
-        eng.end_slice(epochs=2)
-    assert eng.check_accounting()["lost"] == 0
-
-
 # -------------------------------------------------- snapshot/restore --
 def _drive(eng, wave_ids):
     recs = []
@@ -422,51 +468,3 @@ def test_snapshot_requires_drained_engine(tmp_path):
     eng.submit([Request(tokens=TOK, sample_idx=0)])
     with pytest.raises(RuntimeError, match="in flight"):
         eng.snapshot(str(tmp_path / "bad"))
-
-
-def test_snapshot_restore_round_trip_host_router(tmp_path):
-    """Same round-trip through the host `NeuralUCBRouter`: its replay
-    buffer, optimizer, and numpy bit-generator state must all survive
-    the snapshot (the RNG is what makes post-restore warm-phase draws
-    reproduce)."""
-    K, n = 2, 64
-    rng = np.random.default_rng(3)
-    qt = rng.uniform(0.3, 0.9, (n, K)).astype(np.float32)
-    rw = rng.uniform(0.1, 0.9, (n, K)).astype(np.float32)
-    ucfg = UtilityNetConfig(emb_dim=16, num_actions=K, num_domains=3)
-    emb = rng.normal(size=(n, 16)).astype(np.float32)
-    feat = rng.normal(size=(n, 4)).astype(np.float32)
-    dom = rng.integers(0, 3, n).astype(np.int32)
-
-    def build():
-        return AsyncRouterEngine(
-            NeuralUCBRouter(ucfg, seed=0, batch_size=16), K,
-            reward_table=rw, quality_table=qt, decide_batch=16,
-            serve_batch=16)
-
-    def drive(eng, wave_ids):
-        recs = []
-        for ids in wave_ids:
-            eng.submit([Request(tokens=TOK, x_emb=emb[i], x_feat=feat[i],
-                                domain=int(dom[i]), sample_idx=int(i))
-                        for i in ids])
-            recs.extend(r for r in eng.pump() + eng.drain()
-                        if r["status"] == "ok")
-        return recs
-
-    ids = _wave_ids(n, 4, 16, seed=9)
-    path = str(tmp_path / "host-snap")
-    eng_a = build()
-    drive(eng_a, ids[:2])
-    eng_a.end_slice()
-    eng_a.snapshot(path)
-    recs_a = drive(eng_a, ids[2:])
-
-    eng_b = build()
-    eng_b.restore(path)
-    recs_b = drive(eng_b, ids[2:])
-    np.testing.assert_array_equal([r["action"] for r in recs_a],
-                                  [r["action"] for r in recs_b])
-    np.testing.assert_allclose([r["reward"] for r in recs_a],
-                               [r["reward"] for r in recs_b], rtol=1e-6)
-    assert eng_a.counters == eng_b.counters

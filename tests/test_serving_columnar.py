@@ -5,19 +5,27 @@ hold it to the per-row engine it replaced, kept below as a reference
 One seeded scenario runs through both: an uneven 11-arm mix (minority
 queues age), an arm taken down and brought back (fallbacks at depth 1
 and 2), a fully-down chain, every arm down, a queue-full burst, injected
-decide errors, with and without flush windows, over the ``serving_v2``
-and the legacy router paths, table and utility feedback, with and
-without decode engines. Every record, counter, log entry, router call
-and serve batch (arm, rows, tokens, lengths) must match."""
+decide errors, a train between waves, with and without flush windows,
+table and utility feedback, with and without decode engines — over a
+router blind to the availability mask (the fallback chains remap), one
+that honours it, and a real `DevicePolicyRouter`. Every record, counter,
+log entry, router call or final router state, and serve batch (arm,
+rows, tokens, lengths) must match."""
+import functools
 from collections import defaultdict, deque
 
+import jax
 import numpy as np
 import pytest
 
 from repro.armpool.serving import RooflineArmEngine
 from repro.core.reward import utility_reward
-from repro.serving import AsyncRouterEngine, Request
+from repro.core.utilitynet import UtilityNetConfig
+from repro.data.routerbench import RouterBenchSim
+from repro.serving import AsyncRouterEngine, DevicePolicyRouter, Request
 from repro.serving.batcher import pad_rows
+from repro.sim import DeviceReplayEnv, make_policy
+from repro.sim.engine import _tables
 
 K, N = 11, 6000
 MIX = 0.6 ** np.arange(K)
@@ -83,7 +91,7 @@ class _RowBatcher:
 
 
 class _RowGroup:
-    def __init__(self, decision, reqs, decided, x_emb, x_feat, domain):
+    def __init__(self, decision, reqs, decided):
         n = len(reqs)
         self.decision, self.reqs, self.decided = decision, reqs, decided
         self.served = np.full(n, -1, np.int32)
@@ -92,7 +100,6 @@ class _RowGroup:
         self.cost = np.zeros(n, np.float32)
         self.depth = np.zeros(n, np.int32)
         self.remaining = n
-        self.x_emb, self.x_feat, self.domain = x_emb, x_feat, domain
 
 
 class _RowEngine:
@@ -120,7 +127,6 @@ class _RowEngine:
         self.groups, self.slot, self.next_gid = {}, {}, 0
         self.counters = defaultdict(int)
         self.log = deque(maxlen=log_capacity)
-        self.v2 = bool(getattr(router, "serving_v2", False))
 
     def set_arm_health(self, arm, up):
         self.arm_up[arm] = up
@@ -192,6 +198,9 @@ class _RowEngine:
             out.extend(self.pump(force=True))
         return out
 
+    def end_slice(self):
+        self.router.end_slice()
+
     def _decide_and_dispatch(self, reqs, out):
         n = len(reqs)
         if not self.arm_up.any():
@@ -204,28 +213,19 @@ class _RowEngine:
             return
         gid = self.next_gid
         self.next_gid += 1
-        x_emb = x_feat = domain = None
-        if not self.v2:
-            x_emb = np.stack([r.x_emb for r in reqs])
-            x_feat = np.stack([r.x_feat for r in reqs])
-            domain = np.array([r.domain for r in reqs], np.int32)
         call_idx = self.counters["decide_calls"]
         self.counters["decide_calls"] += 1
         try:
             self.fault_hook(call_idx)
-            if self.v2:
-                decision = self.router.decide(
-                    sample_idx=np.array([r.sample_idx for r in reqs],
-                                        np.int64),
-                    avail=self.arm_up.astype(np.float32))
-            else:
-                decision = self.router.decide(x_emb, x_feat, domain)
+            decision = self.router.decide(
+                sample_idx=np.array([r.sample_idx for r in reqs], np.int64),
+                avail=self.arm_up.astype(np.float32))
             decided = np.asarray(decision["action"], np.int32).copy()
         except Exception:
             self.counters["decide_errors"] += 1
             decision = None
             decided = np.full(n, self._safe_arm(), np.int32)
-        g = _RowGroup(decision, reqs, decided, x_emb, x_feat, domain)
+        g = _RowGroup(decision, reqs, decided)
         self.groups[gid] = g
         for i, r in enumerate(reqs):
             served, depth = self._resolve(int(decided[i]))
@@ -281,15 +281,12 @@ class _RowEngine:
         g = self.groups.pop(gid)
         ok = g.served >= 0
         if g.decision is not None and ok.any():
-            if self.v2:
-                served = np.where(ok, g.served, g.decided)
-                learned = self.router.update_wave(g.decision, served,
-                                                  g.reward, learn_mask=ok)
-                self.counters["updates"] += 1
-                self.counters["learned"] += int(learned)
-                self.counters["skipped_learn"] += int(ok.sum()) - learned
-            else:
-                self._update_legacy(g, ok)
+            served = np.where(ok, g.served, g.decided)
+            learned = self.router.update_wave(g.decision, served, g.reward,
+                                              learn_mask=ok)
+            self.counters["updates"] += 1
+            self.counters["learned"] += int(learned)
+            self.counters["skipped_learn"] += int(ok.sum()) - learned
         elif g.decision is None:
             self.counters["skipped_learn"] += int(ok.sum())
         for i, r in enumerate(g.reqs):
@@ -306,45 +303,22 @@ class _RowEngine:
             self._log(rec)
             out.append(rec)
 
-    def _update_legacy(self, g, ok):
-        rows = np.flatnonzero(ok)
-        served = g.served[rows]
-        changed = served != g.decided[rows]
-        dec = {k: np.asarray(v)[rows].copy() for k, v in g.decision.items()}
-        dec["action"] = served.astype(np.int32)
-        if changed.any():
-            if hasattr(self.router, "action_features"):
-                sub = rows[changed]
-                dec["g"][changed] = self.router.action_features(
-                    g.x_emb[sub], g.x_feat[sub], g.domain[sub],
-                    served[changed])
-            else:
-                keep = ~changed
-                self.counters["skipped_learn"] += int(changed.sum())
-                rows, served = rows[keep], served[keep]
-                dec = {k: v[keep] for k, v in dec.items()}
-                if rows.size == 0:
-                    return
-        self.router.update(g.x_emb[rows], g.x_feat[rows], g.domain[rows],
-                           dec, g.reward[rows])
-        self.counters["updates"] += 1
-        self.counters["learned"] += int(rows.size)
-
 
 # ---------------------------------------------------------- fakes --
-class _V2Router:
-    """``serving_v2`` router, blind to the availability mask, drawing
-    arms from an uneven mix; records every call's arguments."""
-
-    serving_v2 = True
+class _BlindRouter:
+    """Blind to the availability mask, drawing arms from an uneven mix;
+    records every call's arguments."""
 
     def __init__(self, seed):
         self.rng = np.random.default_rng(seed)
         self.calls = []
 
+    def _draw(self, n, avail):
+        return self.rng.choice(K, size=n, p=MIX)
+
     def decide(self, *, sample_idx, avail):
         self.calls.append(("decide", sample_idx.copy(), avail.copy()))
-        a = self.rng.choice(K, size=sample_idx.size, p=MIX)
+        a = self._draw(sample_idx.size, avail)
         return {"action": a.astype(np.int32), "n": len(self.calls)}
 
     def update_wave(self, decision, served, rewards, learn_mask):
@@ -354,43 +328,55 @@ class _V2Router:
         return int(np.count_nonzero(learn_mask
                                     & (served == decision["action"])))
 
-
-class _LegacyRouter:
-    """Host-router interface (``decide(x_emb, x_feat, domain)``,
-    ``update``) with a per-row feature ``g``."""
-
-    def __init__(self, seed):
-        self.rng = np.random.default_rng(seed)
-        self.calls = []
-
-    def decide(self, x_emb, x_feat, domain):
-        self.calls.append(("decide", x_emb.copy(), x_feat.copy(),
-                           domain.copy()))
-        a = self.rng.choice(K, size=len(domain), p=MIX).astype(np.int32)
-        return {"action": a, "g": self._g(x_feat, a),
-                "p": self.rng.uniform(size=len(a)).astype(np.float32)}
-
-    @staticmethod
-    def _g(x_feat, a):
-        return (x_feat * (1.0 + np.asarray(a, np.float32))[:, None]
-                ).astype(np.float32)
-
-    def update(self, x_emb, x_feat, domain, dec, rewards):
-        self.calls.append(("update", x_emb.copy(), x_feat.copy(),
-                           domain.copy(),
-                           {k: v.copy() for k, v in dec.items()},
-                           rewards.copy()))
+    def end_slice(self):
+        self.calls.append(("end_slice",))
 
 
-class _RelearningRouter(_LegacyRouter):
-    """Recomputes features for the arm actually served."""
+class _AwareRouter(_BlindRouter):
+    """Honours the availability mask, as `DevicePolicyRouter`'s
+    policies do: draws from the mix over the healthy arms only."""
 
-    def action_features(self, x_emb, x_feat, domain, actions):
-        return self._g(x_feat, actions)
+    def _draw(self, n, avail):
+        p = MIX * (avail > 0)
+        return self.rng.choice(K, size=n, p=p / p.sum())
 
 
-ROUTERS = {"v2": _V2Router, "legacy": _LegacyRouter,
-           "legacy_relearn": _RelearningRouter}
+@functools.lru_cache(maxsize=None)
+def _device_policy():
+    """A neuralucb policy at test widths and its N-row resident tables,
+    built once so that every router shares the compiles."""
+    rng = np.random.default_rng(5)
+    plen = rng.integers(4, 14, size=N)
+    data = {
+        "domain": rng.integers(0, 5, size=N).astype(np.int32),
+        "topic": rng.normal(size=(N, 32)).astype(np.float32),
+        "difficulty": np.zeros(N, np.float32),
+        "prompt_tokens": plen.astype(np.float32),
+        "quality": rng.uniform(0.1, 1.0, (N, K)).astype(np.float32),
+        "cost": (np.arange(1, K + 1, dtype=np.float32)[None]
+                 * plen[:, None] * 1e-4).astype(np.float32),
+        "x_feat": rng.normal(size=(N, 4)).astype(np.float32),
+        "model_names": np.array([f"m{a}" for a in range(K)]),
+    }
+    env = DeviceReplayEnv.from_host(
+        RouterBenchSim(seed=0, n_slices=4, cost_lambda=1.0, data=data))
+    cfg = UtilityNetConfig(emb_dim=env.x_emb.shape[1], num_actions=K,
+                           num_domains=5, d_text=16, d_hidden=16, d_last=8)
+    pol, hyp = make_policy("neuralucb", env, cfg)
+    return pol, hyp, _tables(env)
+
+
+def _device_router(seed):
+    """A `DevicePolicyRouter` at test widths over the N-row table."""
+    pol, hyp, tables = _device_policy()
+    return DevicePolicyRouter(pol, hyp, tables, seed=seed, slice_width=48,
+                              capacity_slices=4, batch_size=16,
+                              train_chunks=1)
+
+
+# "v2" is the mask-blind router (the id its test cases have always had)
+ROUTERS = {"v2": _BlindRouter, "aware": _AwareRouter,
+           "device": _device_router}
 
 
 class _Tap:
@@ -457,10 +443,7 @@ def _requests(rng, start, n):
     out = []
     for i in range(start, start + n):
         toks = rng.integers(1, 1000, size=int(rng.integers(1, 14)))
-        out.append(Request(tokens=toks.astype(np.int32),
-                           x_emb=rng.normal(size=4).astype(np.float32),
-                           x_feat=rng.normal(size=3).astype(np.float32),
-                           domain=int(rng.integers(0, 5)), sample_idx=i))
+        out.append(Request(tokens=toks.astype(np.int32), sample_idx=i))
     return out
 
 
@@ -472,6 +455,7 @@ HEALTH = {8: [(0, False)],                   # depth-1 fallbacks
           30: [(2, True), (3, True)] + [(a, False) for a in range(K)],
           31: [(a, True) for a in range(K)]}
 BURST = 22                                   # a queue-full shed
+ALL_DOWN = 30                # drained: the whole backlog sheds no-arm
 
 
 def _typed(recs):
@@ -495,6 +479,23 @@ def _same(a, b, what):
         assert type(a) is type(b) and a == b, what
 
 
+def _same_router(a, b):
+    """The two engines' routers saw the same calls: a fake's call log,
+    or a `DevicePolicyRouter`'s whole state, bit for bit."""
+    if not isinstance(a, DevicePolicyRouter):
+        _same(a.calls, b.calls, "router calls")
+        return
+    assert (a.wave, a.slices) == (b.wave, b.slices)
+    _same(a._counts, b._counts, "ring counts")
+    for what, x, y in (("key", a._key, b._key),
+                       ("env_idx", a._env_idx, b._env_idx)):
+        _same(np.asarray(x), np.asarray(y), what)
+    xs, ys = (jax.tree_util.tree_leaves(r.state) for r in (a, b))
+    assert len(xs) == len(ys)
+    for i, (x, y) in enumerate(zip(xs, ys)):
+        _same(np.asarray(x), np.asarray(y), f"state leaf {i}")
+
+
 @pytest.mark.parametrize("feedback", ["table", "engines", "utility"])
 @pytest.mark.parametrize("flush", [False, True], ids=["no_flush", "flush"])
 @pytest.mark.parametrize("router", sorted(ROUTERS))
@@ -505,6 +506,9 @@ def test_columnar_engine_matches_per_row_reference(router, feedback,
     r_ref, kw_ref, rec_ref = _world(router, feedback, flush, now)
     new = AsyncRouterEngine(r_new, K, fallback_chains=_chains(), **kw)
     ref = _RowEngine(r_ref, chains=_chains(), **kw_ref)
+    # a router that honours the mask never decides onto a down arm, so
+    # only the blind one walks the fallback chains
+    blind = router == "v2"
 
     rng = np.random.default_rng(7)
     start, seen = 0, []
@@ -519,7 +523,7 @@ def test_columnar_engine_matches_per_row_reference(router, feedback,
         admitted, shed = new.submit(reqs)
         ref.submit(reqs)
         assert admitted + shed == n
-        if step % 7 == 6:
+        if step % 7 == 6 or step == ALL_DOWN:
             got, want = new.drain(), ref.drain()
         else:
             got, want = new.pump(), ref.pump()
@@ -530,21 +534,28 @@ def test_columnar_engine_matches_per_row_reference(router, feedback,
                 if v and k != "padded_batches"} \
             == {k: v for k, v in ref.counters.items() if v}, \
             f"step {step} counters"
+        if step % 7 == 6:   # drained: train between waves
+            new.end_slice()
+            ref.end_slice()
         if step == 29:      # before every arm goes down
-            assert new.counters["shed_no_arm"], "no chain ran out"
+            assert bool(new.counters["shed_no_arm"]) == blind, \
+                "a chain ran out" if not blind else "no chain ran out"
     _same(new.drain(), ref.drain(), "drain")
     assert new.in_flight == 0
     assert new.check_accounting()["lost"] == 0
     assert _typed(new.log) == _typed(ref.log)
-    _same(r_new.calls, r_ref.calls, "router calls")
+    _same_router(r_new, r_ref)
     _same(rec_new, rec_ref, "serve batches")
 
     # the scenario did reach every branch it names
     c = new.counters
-    assert c["shed_queue_full"] and c["shed_no_arm"] and c["fallbacks"]
+    assert c["shed_queue_full"] and c["shed_no_arm"] and c["learned"]
     assert c["decide_errors"] == 2 and c["dropped_log_records"]
     depths = {x["fallback_depth"] for x in seen if x["status"] == "ok"}
-    assert {1, 2} <= depths
+    if blind:
+        assert c["fallbacks"] and {1, 2} <= depths
+    else:
+        assert c["fallbacks"] == 0 and depths == {0}
     assert c["padded_batches"] == (0 if feedback == "table"
                                    else len(rec_new["arms"]))
 
@@ -573,7 +584,7 @@ def test_padded_batches_only_for_engines_that_read_prompts(pool):
         engines = [_ClockedArm(clocked) for _ in range(K)]
     if pool == "mixed":
         engines[0] = _StubArm(0, read)
-    eng = AsyncRouterEngine(_V2Router(1), K, engines=engines,
+    eng = AsyncRouterEngine(_BlindRouter(1), K, engines=engines,
                             reward_table=reward, decide_batch=64,
                             serve_batch=16, pad_to_multiple=4)
     reqs = _requests(rng, 0, 500)

@@ -82,7 +82,7 @@ def test_fallback_chain_fires_on_outage():
 
 
 def test_availability_aware_router_never_needs_fallback():
-    """A serving_v2 router gets the live mask in decide — it routes
+    """The router gets the live mask in decide — an aware one routes
     around the outage itself, so the chain never fires and every row
     learns."""
     eng = _engine(FakeRouter(K, prefer=0))

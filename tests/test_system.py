@@ -1,6 +1,6 @@
 """End-to-end behaviour tests for the paper's system (reward ordering on a
-reduced stream — the full-scale Figure-2/3/4 reproduction lives in
-benchmarks/ and EXPERIMENTS.md)."""
+reduced stream — the full-scale reproduction is the `paper_table1` and
+`fig2_beta_sweep` presets and examples/encoder_ablation.py)."""
 import numpy as np
 import pytest
 
